@@ -14,8 +14,8 @@ Workloads:
 Usage:  python bench_scaling.py [--workload cifar_resnet56] [--device_data 1]
                                 [--points 8,32,128,256] [--spans 1]
 Prints one JSON line per point (bench.py remains the single-line driver
-benchmark; this script is the scaling study). A point that fails (e.g. a
-remote-compile drop) prints an error line and the sweep continues.
+benchmark; this script is the scaling study). A point that fails (e.g. out
+of device memory) prints an error line and the sweep continues.
 --spans 1 adds a host-side span breakdown (pack vs device compute vs eval,
 utils/tracing.RoundTracer) to each point — where round time goes.
 """
@@ -168,11 +168,6 @@ def main():
     from fedml_tpu.utils.metrics import enable_compile_cache
 
     enable_compile_cache()
-    # a timeout(1)-TERMed sweep must release the accelerator grant (raw
-    # SIGTERM would skip PJRT teardown and wedge it, like bench.py's child)
-    import signal
-
-    signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", type=str, default="femnist_cnn",
                     choices=["femnist_cnn", "cifar_resnet56"])

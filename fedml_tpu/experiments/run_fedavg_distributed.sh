@@ -11,9 +11,11 @@ MODEL=${4:-lr}
 WORLD=$((CLIENTS + 1))
 PORT=${BASE_PORT:-50000}
 
+# one process per chip: the server rank keeps the accelerator this box has;
+# the client ranks run on the CPU (they would otherwise fight over the chip)
 pids=()
 for rank in $(seq 1 "$CLIENTS"); do
-  python -m fedml_tpu.experiments.distributed_launch \
+  JAX_PLATFORMS=cpu python -m fedml_tpu.experiments.distributed_launch \
     --rank "$rank" --world_size "$WORLD" --backend grpc --base_port "$PORT" \
     --dataset "$DATASET" --model "$MODEL" --comm_round "$ROUNDS" &
   pids+=($!)

@@ -86,9 +86,8 @@ def provenance(date: str | None = None,
 def stamp(blob: dict, date: str | None = None,
           dataset_source: str | None = None) -> dict:
     """Attach the provenance block to a BENCH blob in place (and return
-    it). Never overwrites an existing block — a relay (bench.py's parent
-    re-emitting a child's line) must not clobber the measuring process's
-    stamp."""
+    it). Never overwrites an existing block — code that re-emits a measured
+    line must not clobber the measuring process's stamp."""
     if "provenance" not in blob:
         blob["provenance"] = provenance(date=date,
                                         dataset_source=dataset_source)

@@ -15,13 +15,30 @@ standard flash recurrence (recompute P from the saved logsumexp, then
 dV = P^T dO, dS = P*(dP - delta), dQ/dK via dS) as two kernels gridded over
 q-tiles (dQ) and k-tiles (dK/dV).
 
-Runs in interpreter mode off-TPU (tests exercise it on CPU); on TPU the
-kernels compile with Mosaic.
+The per-row statistics (logsumexp, delta, the lse cotangent) travel between
+the kernels lane-dense as [BH, Tpad/block_q, 1, block_q]: every block's last
+two dimensions equal the array's, which is what the TPU lowering requires of
+a block that is not a multiple of (8, 128). Inside a kernel a statistic is
+a (block_q, 1) column next to the (block_q, block_k) score tile, transposed
+on the way in and out.
+
+``_mode`` is the one place that decides what serves a call: the Mosaic
+kernels on a TPU (a refusal by the compiler is an error, nothing stands in
+for the kernel), the Pallas interpreter or the jnp twin off-TPU (how the CPU
+tests see the kernel).
+
+K and V (forward, dQ) or Q and dO (dK/dV) stay whole in VMEM for one
+(batch, head), double-buffered, and must fit the 16 MiB a v5e kernel may
+use: T <= 15360 in bf16 and T <= 7680 in f32 at D <= 128 (``_check_resident``,
+measured against the v5e compiler). Above that the call raises before
+lowering; longer sequences go through parallel/ring_attention, which hands
+each device a slice.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -62,8 +79,30 @@ def _mode(x) -> str:
     return "interpret"
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# Whole-sequence residency limit, found by asking the v5e compiler (libtpu
+# 0.0.34, 16 heads): a kernel may use 16 MiB of VMEM, the two whole operands
+# are double-buffered with D padded to 128 lanes, and the tiles and
+# temporaries took up to 128 KiB more. Forward, dQ and dK/dV all compiled at
+# 15 MiB (bf16 T=15360, f32 T=7680, D <= 128) and were refused at 16 MiB.
+MAX_RESIDENT_BYTES = 15 * 1024 * 1024
+
+
+def _check_resident(Tpad, D, dtype):
+    lanes = -(-D // 128) * 128
+    need = 4 * Tpad * lanes * jnp.dtype(dtype).itemsize
+    if need > MAX_RESIDENT_BYTES:
+        raise ValueError(
+            f"flash_attention keeps two whole [T, D] operands of one head in "
+            f"VMEM: padded T={Tpad}, D={D}, {jnp.dtype(dtype).name} needs "
+            f"{need} bytes double-buffered, above the {MAX_RESIDENT_BYTES} "
+            "the v5e compiler accepts (bf16: T <= 15360, f32: T <= 7680 at "
+            "D <= 128); shard the sequence (parallel/ring_attention) instead")
+
+
+def _rows(x, block_q):
+    """[BH, Tpad] -> the kernels' lane-dense [BH, Tpad/block_q, 1, block_q]."""
+    BH, Tpad = x.shape
+    return x.reshape(BH, Tpad // block_q, 1, block_q)
 
 
 def _mask(scores, q0, k0, bq, bk, seq_len, causal):
@@ -83,29 +122,30 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_len,
     q0 = qi * bq
     q = q_ref[0].astype(jnp.float32)
 
-    nk = pl.cdiv(k_ref.shape[1], block_k)
+    nk = k_ref.shape[1] // block_k
 
     def body(j, carry):
-        o, l, m = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        o, l, m = carry  # (bq, d), (bq, 1), (bq, 1)
+        k0 = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[0, pl.ds(k0, block_k), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(k0, block_k), :].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        s = _mask(s, q0, j * block_k, bq, block_k, seq_len, causal)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        s = _mask(s, q0, k0, bq, block_k, seq_len, causal)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        o_new = o * corr[:, None] + jnp.dot(p, v, preferred_element_type=jnp.float32)
+        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        o_new = o * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
         return o_new, l_new, m_new
 
     o0 = jnp.zeros((bq, d), jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    m0 = jnp.full((bq,), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((bq, 1), jnp.float32)
+    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     o, l, m = jax.lax.fori_loop(0, nk, body, (o0, l0, m0))
 
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (o / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l_safe)
+    o_ref[0] = (o / l_safe).astype(o_ref.dtype)
+    lse_ref[0, 0] = (m + jnp.log(l_safe)).T
 
 
 def _dense_mask(s, seq_len, causal):
@@ -151,60 +191,43 @@ def _dense_bwd(qf, kf, vf, dof, lse, delta, glse, seq_len, causal, scale):
 def _flash_fwd(q, k, v, causal, block_q, block_k):
     B, T, H, D = q.shape
     scale = 1.0 / (D ** 0.5)
-    Tp = -(-T // block_q) * block_q
-    Tkp = -(-T // block_k) * block_k
-    Tpad = max(Tp, Tkp)
+    blk = math.lcm(block_q, block_k)
+    Tpad = -(-T // blk) * blk
 
     def prep(x):
         x = jnp.moveaxis(x, 2, 1).reshape(B * H, T, D)  # [BH, T, D]
         return jnp.pad(x, ((0, 0), (0, Tpad - T), (0, 0)))
 
     qf, kf, vf = prep(q), prep(k), prep(v)
-    BH = B * H
-    grid = (BH, Tpad // block_q)
-
-    if _mode(q) == "jnp":
+    mode = _mode(q)
+    if mode == "jnp":
         o, lse = _dense_fwd(qf, kf, vf, T, causal, scale)
-        return o, lse, (qf, kf, vf)
-
-    if _mode(q) == "pallas" and getattr(jax.typeof(q), "vma", None):
-        # TPU + strict shard_map: the kernels SHOULD pass with the vma-typed
-        # out_shapes (_sds), but that combination hasn't been provable
-        # off-hardware — if Mosaic's vma rule rejects it at trace time, fall
-        # back to the XLA-fused dense path rather than failing the engine.
-        try:
-            return _pallas_fwd(qf, kf, vf, T, Tpad, BH, D, grid, causal,
-                               scale, block_q, block_k)
-        except Exception:  # noqa: BLE001 — trace-time vma rejection
-            o, lse = _dense_fwd(qf, kf, vf, T, causal, scale)
-            return o, lse, (qf, kf, vf)
-
-    return _pallas_fwd(qf, kf, vf, T, Tpad, BH, D, grid, causal, scale,
-                       block_q, block_k)
+    else:
+        o, lse = _pallas_fwd(qf, kf, vf, T, causal, scale, block_q, block_k,
+                             interpret=mode == "interpret")
+    return o, lse
 
 
-def _pallas_fwd(qf, kf, vf, T, Tpad, BH, D, grid, causal, scale,
-                block_q, block_k):
+def _pallas_fwd(qf, kf, vf, T, causal, scale, block_q, block_k, interpret):
+    BH, Tpad, D = qf.shape
+    _check_resident(Tpad, D, kf.dtype)
+    nq = Tpad // block_q
+    tile = pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM)
+    whole = pl.BlockSpec((1, Tpad, D), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM)
+    row = pl.BlockSpec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0), memory_space=pltpu.VMEM)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, seq_len=T,
                           causal=causal, scale=scale),
         out_shape=(
             _sds((BH, Tpad, D), qf.dtype, qf),
-            _sds((BH, Tpad), jnp.float32, qf),
+            _sds((BH, nq, 1, block_q), jnp.float32, qf),
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, Tpad, D), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, Tpad, D), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i), memory_space=pltpu.VMEM),
-        ),
-        interpret=_use_interpret(),
+        grid=(BH, nq),
+        in_specs=[tile, whole, whole],
+        out_specs=(tile, row),
+        interpret=interpret,
     )(qf, kf, vf)
-    return o, lse, (qf, kf, vf)
+    return o, lse.reshape(BH, Tpad)
 
 
 # ---------------------------------------------------------------- backward
@@ -217,18 +240,19 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, glse_ref,
     q0 = qi * bq
     q = q_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    corr = glse_ref[0] - delta_ref[0]
-    nk = pl.cdiv(k_ref.shape[1], block_k)
+    lse = lse_ref[0, 0].T
+    corr = (glse_ref[0, 0] - delta_ref[0, 0]).T
+    nk = k_ref.shape[1] // block_k
 
     def body(j, dq):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        k0 = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[0, pl.ds(k0, block_k), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(k0, block_k), :].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        s = _mask(s, q0, j * block_k, bq, block_k, seq_len, causal)
-        p = jnp.exp(s - lse[:, None])
+        s = _mask(s, q0, k0, bq, block_k, seq_len, causal)
+        p = jnp.exp(s - lse)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp + corr[:, None])
+        ds = p * (dp + corr)
         return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32) * scale
 
     dq = jax.lax.fori_loop(0, nk, body, jnp.zeros_like(q))
@@ -242,21 +266,21 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, glse_ref,
     k0 = ki * bk
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
-    nq = pl.cdiv(q_ref.shape[1], block_q)
+    nq = q_ref.shape[1] // block_q
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * block_q, block_q)]
-        corr = (glse_ref[0, pl.ds(i * block_q, block_q)]
-                - delta_ref[0, pl.ds(i * block_q, block_q)])
+        q0 = pl.multiple_of(i * block_q, block_q)
+        q = q_ref[0, pl.ds(q0, block_q), :].astype(jnp.float32)
+        do = do_ref[0, pl.ds(q0, block_q), :].astype(jnp.float32)
+        lse = lse_ref[0, i].T
+        corr = (glse_ref[0, i] - delta_ref[0, i]).T
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        s = _mask(s, i * block_q, k0, block_q, bk, seq_len, causal)
-        p = jnp.exp(s - lse[:, None])
+        s = _mask(s, q0, k0, block_q, bk, seq_len, causal)
+        p = jnp.exp(s - lse)
         dv = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp + corr[:, None])
+        ds = p * (dp + corr)
         dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32) * scale
         return dk, dv
 
@@ -294,7 +318,7 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
 
 def _flash_call(q, k, v, causal, block_q, block_k):
     B, T, H, D = q.shape
-    o, lse, _ = _flash_fwd(q, k, v, causal, block_q, block_k)
+    o, lse = _flash_fwd(q, k, v, causal, block_q, block_k)
     out = jnp.moveaxis(o[:, :T].reshape(B, H, T, D), 1, 2)
     return out, (o, lse)
 
@@ -325,53 +349,39 @@ def _bwd_rule(causal, block_q, block_k, res, gs):
     glse = jnp.pad(g_lse.astype(jnp.float32).reshape(BH, T),
                    ((0, 0), (0, Tpad - T)))
 
-    def dense():
-        dqf, dkf, dvf = _dense_bwd(qf, kf, vf, dof, lse, delta, glse,
-                                   T, causal, scale)
-        up = lambda x: jnp.moveaxis(x[:, :T].reshape(B, H, T, D), 1, 2)
-        return up(dqf), up(dkf), up(dvf)
-
     mode = _mode(q)
     if mode == "jnp":
-        return dense()
-    if mode == "pallas" and getattr(jax.typeof(q), "vma", None):
-        try:  # same trace-time fallback as _flash_fwd
-            return _pallas_bwd(qf, kf, vf, dof, lse, delta, glse, B, T, H, D,
-                               Tpad, BH, causal, scale, block_q, block_k)
-        except Exception:  # noqa: BLE001 — trace-time vma rejection
-            return dense()
-    return _pallas_bwd(qf, kf, vf, dof, lse, delta, glse, B, T, H, D,
-                       Tpad, BH, causal, scale, block_q, block_k)
+        grads = _dense_bwd(qf, kf, vf, dof, lse, delta, glse, T, causal, scale)
+    else:
+        grads = _pallas_bwd(qf, kf, vf, dof, lse, delta, glse, T, causal,
+                            scale, block_q, block_k,
+                            interpret=mode == "interpret")
+    return tuple(jnp.moveaxis(x[:, :T].reshape(B, H, T, D), 1, 2)
+                 for x in grads)
 
 
-def _pallas_bwd(qf, kf, vf, dof, lse, delta, glse, B, T, H, D, Tpad, BH,
-                causal, scale, block_q, block_k):
-    common_in = [
-        pl.BlockSpec((1, Tpad, D), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tpad, D), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tpad, D), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tpad, D), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tpad), lambda b, i: (b, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tpad), lambda b, i: (b, 0), memory_space=pltpu.VMEM),
-    ]
+def _pallas_bwd(qf, kf, vf, dof, lse, delta, glse, T, causal, scale,
+                block_q, block_k, interpret):
+    BH, Tpad, D = qf.shape
+    _check_resident(Tpad, D, qf.dtype)
+    nq = Tpad // block_q
+    stats = [_rows(x, block_q) for x in (lse, delta, glse)]
+    vmem = pltpu.VMEM
+    whole = pl.BlockSpec((1, Tpad, D), lambda b, i: (b, 0, 0), memory_space=vmem)
+    q_tile = pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0), memory_space=vmem)
+    k_tile = pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0), memory_space=vmem)
+    row = pl.BlockSpec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0), memory_space=vmem)
+    rows = pl.BlockSpec((1, nq, 1, block_q), lambda b, i: (b, 0, 0, 0), memory_space=vmem)
 
     dqf = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_k=block_k, seq_len=T,
                           causal=causal, scale=scale),
         out_shape=_sds((BH, Tpad, D), qf.dtype, qf),
-        grid=(BH, Tpad // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            common_in[1], common_in[2],
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0),
-                               memory_space=pltpu.VMEM),
-        interpret=_use_interpret(),
-    )(qf, kf, vf, dof, lse, delta, glse)
+        grid=(BH, nq),
+        in_specs=[q_tile, whole, whole, q_tile, row, row, row],
+        out_specs=q_tile,
+        interpret=interpret,
+    )(qf, kf, vf, dof, *stats)
 
     dkf, dvf = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, seq_len=T,
@@ -381,23 +391,11 @@ def _pallas_bwd(qf, kf, vf, dof, lse, delta, glse, B, T, H, D, Tpad, BH,
             _sds((BH, Tpad, D), vf.dtype, vf),
         ),
         grid=(BH, Tpad // block_k),
-        in_specs=[
-            common_in[0],
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            common_in[3], common_in[4], common_in[5], common_in[5],
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-        ),
-        interpret=_use_interpret(),
-    )(qf, kf, vf, dof, lse, delta, glse)
-
-    def unprep(x):
-        return jnp.moveaxis(x[:, :T].reshape(B, H, T, D), 1, 2)
-
-    return unprep(dqf), unprep(dkf), unprep(dvf)
+        in_specs=[whole, k_tile, k_tile, whole, rows, rows, rows],
+        out_specs=(k_tile, k_tile),
+        interpret=interpret,
+    )(qf, kf, vf, dof, *stats)
+    return dqf, dkf, dvf
 
 
 flash_attention_with_lse.defvjp(_fwd_rule, _bwd_rule)

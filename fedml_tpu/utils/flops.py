@@ -25,8 +25,6 @@ def compiled_flops(fn, *args) -> float | None:
 
         c = jax.jit(fn).lower(*args).compile()
         ca = c.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax: one dict per program
-            ca = ca[0] if ca else {}
         f = float(ca.get("flops", 0.0))
         return f if f > 0 else None
     except Exception:  # noqa: BLE001

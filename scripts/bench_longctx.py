@@ -15,14 +15,13 @@ an error line and the sweep continues.
 
 Usage: python scripts/bench_longctx.py [--seqs 1024,2048,4096,8192]
        [--flash 1] [--batch 2] [--dim 256] [--depth 4] [--steps 8]
-tpu_smoke step 6 runs flash and dense side by side on the real chip.
+--flash 2 runs flash and dense side by side.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import signal
 import sys
 import time
 
@@ -100,8 +99,6 @@ def main():
     from fedml_tpu.utils.metrics import enable_compile_cache
 
     enable_compile_cache()
-    # release the accelerator grant on a timeout(1) TERM (tpu_smoke battery)
-    signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
     ap = argparse.ArgumentParser()
     ap.add_argument("--seqs", type=str, default="1024,2048,4096,8192")
     ap.add_argument("--flash", type=int, default=1,

@@ -157,6 +157,12 @@ def pytest_collection_modifyitems(config, items):
         raise pytest.UsageError(
             "_SMOKE_TESTS entries match no collected test (renamed or "
             f"removed?): {sorted(stale)}")
+    # longest file first. Under `-n N --dist loadfile` a file is one work
+    # unit, handed out in collection order, and this one (DARTS supernets,
+    # ~12 min on one worker, a fifth of the suite's CPU time) sets the wall
+    # clock by when it starts: queued mid-alphabet behind other units it
+    # pushed a cold six-worker run to its 1470 s limit.
+    items.sort(key=lambda it: "test_nas_affinity_condense.py" not in it.nodeid)
 
 
 @pytest.fixture(scope="session")

@@ -1,0 +1,151 @@
+"""Compile the main path's programs for a described TPU v5e, without a chip.
+
+The chip's compiler is installed next to the CPU backend and compiles for a
+topology that is described, not attached (the on-chip-measurement guide,
+section 2): what it refuses here it refuses on the chip, at no chip time.
+Nothing runs, so these say nothing about results or speed.
+
+Code that asks ``jax.default_backend()`` sees the CPU here, so the flash
+kernel's ``_mode`` is steered to 'pallas' by the test. The topology is
+described inside a fixture (never at import: only one process may load the
+TPU library), and the persistent compile cache is off around the compiles —
+an entry written for a described chip cannot be read back without one.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+fa = importlib.import_module("fedml_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _on_chip(tree, one_chip):
+    """Shapes of ``tree`` placed on the described chip."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x),
+                                       sharding=one_chip), tree)
+
+
+# the three shapes ops/flash_attention.py promises the v5e compiler accepts
+FLASH_SHAPES = [((2, 1024, 8, 64), jnp.float32),
+                ((2, 2048, 8, 64), jnp.bfloat16),
+                ((1, 8192, 8, 128), jnp.bfloat16)]
+
+
+@pytest.mark.parametrize("direction,kernels", [("fwd", 1), ("bwd", 3)])
+@pytest.mark.parametrize(
+    "shape,dtype", FLASH_SHAPES,
+    ids=[f"T{s[1]}_D{s[3]}_{jnp.dtype(d).name}" for s, d in FLASH_SHAPES])
+def test_flash_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
+                                       monkeypatch, shape, dtype, direction,
+                                       kernels):
+    monkeypatch.setattr(fa, "_mode", lambda x: "pallas")
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return fa.flash_attention(q, k, v, True)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32) ** 2)
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    # forward kernel; backward = recomputed forward + dQ + dK/dV
+    assert text.count("tpu_custom_call") == kernels
+
+
+def test_flash_kernel_compiles_under_vmap_for_v5e(one_chip,
+                                                  no_persistent_cache,
+                                                  monkeypatch):
+    """The round engine vmaps the local fit over clients, so the kernels are
+    lowered with a leading client axis (chip_smoke's flash_lm shape)."""
+    monkeypatch.setattr(fa, "_mode", lambda x: "pallas")
+    x = jax.ShapeDtypeStruct((4, 2, 1024, 8, 64), jnp.float32,
+                             sharding=one_chip)
+    grad = jax.grad(
+        lambda q, k, v: jnp.sum(fa.flash_attention(q, k, v, True) ** 2),
+        argnums=(0, 1, 2))
+    text = jax.jit(jax.vmap(grad)).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_flash_above_vmem_limit_raises_before_lowering(monkeypatch):
+    monkeypatch.setattr(fa, "_mode", lambda x: "pallas")
+    x = jax.ShapeDtypeStruct((1, 32768, 8, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="shard the sequence"):
+        jax.eval_shape(lambda q, k, v: fa.flash_attention(q, k, v, True),
+                       x, x, x)
+
+
+def test_femnist_cnn_round_step_compiles_for_v5e(one_chip,
+                                                 no_persistent_cache):
+    """The flagship per-round program at full width: CNNOriginalFedAvg,
+    62 classes, 10 clients/round, bs 20, uint8 pixels."""
+    from fedml_tpu.algorithms.fedavg import FedAvgAPI, FedAvgConfig
+    from fedml_tpu.core.tasks import classification_task
+    from fedml_tpu.data.registry import load_dataset
+    from fedml_tpu.models.cnn import CNNOriginalFedAvg
+
+    data = load_dataset("femnist", client_num=20, uint8_pixels=True)
+    cfg = FedAvgConfig(comm_round=1, client_num_in_total=20,
+                       client_num_per_round=10, batch_size=20, lr=0.1,
+                       max_batches=28)
+    api = FedAvgAPI(data, classification_task(
+        CNNOriginalFedAvg(only_digits=False)), cfg, donate=True)
+    args = (jax.random.PRNGKey(0), api.net, api.server_opt_state,
+            api._warmup_batch(api.num_batches), jnp.int32(0),
+            jnp.zeros((10,), jnp.int32))
+    compiled = api.round_fn.lower(*_on_chip(args, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_resnet56_local_fit_compiles_for_v5e(one_chip, no_persistent_cache):
+    """One silo's local fit of the cross-silo cell: ResNet-56, group norm,
+    CIFAR-10 shapes, 8 batches of 64 (bench_scaling's cifar_resnet56)."""
+    import optax
+
+    from fedml_tpu.core.local import LocalSpec, make_local_update
+    from fedml_tpu.core.tasks import classification_task
+    from fedml_tpu.models.resnet import ResNetCIFAR
+
+    task = classification_task(ResNetCIFAR(depth=56, num_classes=10,
+                                           norm_type="group"))
+    x = jnp.zeros((8, 64, 32, 32, 3), jnp.uint8)
+    net = jax.eval_shape(task.init, jax.random.PRNGKey(0), x[0])
+    fit = make_local_update(task, LocalSpec(optimizer=optax.sgd(0.01)))
+    args = (jax.random.PRNGKey(0), net, x, jnp.zeros((8, 64), jnp.int32),
+            jnp.ones((8, 64), jnp.float32))
+    compiled = jax.jit(fit).lower(*_on_chip(args, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0

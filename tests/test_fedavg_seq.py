@@ -14,17 +14,7 @@ from fedml_tpu.algorithms.fedavg_seq import FedAvgSeqAPI
 from fedml_tpu.core.tasks import sequence_task
 from fedml_tpu.data.synthetic import synthetic_sequences
 from fedml_tpu.models.transformer import TransformerLM
-from fedml_tpu.utils.jax_compat import seq_oracle_unsupported_reason
 from fedml_tpu.utils.tree import tree_global_norm, tree_sub
-
-# the ≡-single-device oracles need the jax>=0.5 vma psum-transpose
-# semantics; on older runtimes the compat shard_map's psum->psum transpose
-# leaves a ~1e-2 systematic grad deviation (engine-behavior tests — learns,
-# validates, checkpoints — still run there)
-_requires_vma_transpose = pytest.mark.skipif(
-    seq_oracle_unsupported_reason() is not None,
-    reason=str(seq_oracle_unsupported_reason()))
-
 
 def _rel(a, b):
     """Relative parameter distance ||a - b|| / ||a|| between two nets."""
@@ -51,7 +41,6 @@ def seq_data():
                                samples_per_client=12, test_samples=40, seed=2)
 
 
-@_requires_vma_transpose
 def test_seq_parallel_fedavg_equals_single_device(seq_data):
     cfg = FedAvgConfig(comm_round=3, client_num_in_total=8,
                        client_num_per_round=4, epochs=1, batch_size=6,
@@ -70,7 +59,6 @@ def test_seq_parallel_fedavg_equals_single_device(seq_data):
                                rtol=1e-4)
 
 
-@_requires_vma_transpose
 def test_seq_size_weighted_equals_single_device(seq_data):
     """--sampling size_weighted on the long-context engine: same sampler +
     forced-uniform aggregate as FedAvgAPI, so mesh ≡ single device holds
@@ -123,7 +111,6 @@ def test_seq_mesh_validation(seq_data):
         FedAvgSeqAPI(seq_data, _model_ctor, cfg, mesh=_mesh(1, 3))
 
 
-@_requires_vma_transpose
 def test_seq_parallel_ulysses_equals_single_device(seq_data):
     """Ulysses (all-to-all head scatter) as the seq impl: same mesh ==
     single-device equivalence as the ring path (heads % seq shards == 0)."""
@@ -181,7 +168,6 @@ def test_seq_run_rounds_block_equals_sequential(seq_data):
     assert rel < 1e-6, rel
 
 
-@_requires_vma_transpose
 def test_seq_parallel_fedprox_equals_single_device(seq_data):
     """FedProx on long context: the proximal term is over seq-INVARIANT
     params (computed identically on every shard, no collective), so the
@@ -239,7 +225,6 @@ def test_seq_load_state_roundtrips_checkpoint(seq_data, tmp_path):
                for v in jax.tree.leaves(jax.device_get(api2.net.params)))
 
 
-@_requires_vma_transpose
 def test_seq_parallel_flash_equals_single_device(seq_data):
     """use_flash inside the FL engine under the strict (check_vma=True)
     grad transpose: flash ring attention ≡ dense ring ≡ single-device

@@ -173,3 +173,19 @@ def test_flash_gradients_under_strict_vma_shard_map():
     for a, b in zip(gs, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-3)
+
+
+def test_forced_pallas_mode_off_tpu_raises_instead_of_substituting(monkeypatch):
+    """``_mode`` is the one decision of what serves a call. Forced to the
+    Mosaic kernels where they cannot lower, the op must raise — never hand
+    back the dense twin's answer (the fallback that once let a TPU run
+    'work' without running the kernel)."""
+    import importlib
+
+    fa = importlib.import_module("fedml_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_mode", lambda x: "pallas")
+    q, k, v = _rand_qkv(jax.random.PRNGKey(9), B=1, T=64, H=2, D=16)
+    with pytest.raises(Exception, match="interpret mode"):
+        fa.flash_attention(q, k, v, True, 32, 32)
+    with pytest.raises(Exception, match="interpret mode"):
+        jax.grad(lambda q: jnp.sum(fa.flash_attention(q, k, v, True, 32, 32)))(q)

@@ -454,7 +454,7 @@ def test_committed_ci_gate_file_parses():
 
 
 # ------------------------------------------------------------ provenance
-def test_provenance_stamp_and_relay_safety(tmp_path):
+def test_provenance_stamp_never_overwrites(tmp_path):
     prov = provenance(date="2026-08-07", dataset_source="synthetic")
     assert prov["date"] == "2026-08-07"
     assert prov["dataset_source"] == "synthetic"
@@ -462,7 +462,7 @@ def test_provenance_stamp_and_relay_safety(tmp_path):
     blob = {"metric": "x", "value": 1.0}
     stamp(blob, date="2026-08-07")
     assert blob["provenance"]["date"] == "2026-08-07"
-    # relay safety: a second stamp NEVER overwrites the child's header
+    # a second stamp NEVER overwrites the measuring process's header
     stamp(blob, date="1999-01-01")
     assert blob["provenance"]["date"] == "2026-08-07"
 

@@ -6,6 +6,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from fedml_tpu.algorithms.fedavg import FedAvgAPI, FedAvgConfig
 from fedml_tpu.centralized import CentralizedConfig, CentralizedTrainer
@@ -291,3 +292,50 @@ def test_cli_fedseg_split_gkt_vfl_smoke(tmp_path):
           "--run_dir", str(tmp_path)])
     main(["--algo", "vfl", "--dataset", "uci_susy", "--comm_round", "2",
           "--batch_size", "64", "--lr", "0.05", "--run_dir", str(tmp_path)])
+
+
+@pytest.mark.parametrize("case", ["env_set", "env_unset", "two_cwds"])
+def test_compile_cache_is_placed_from_outside(case, tmp_path):
+    """enable_compile_cache: where JAX_COMPILATION_CACHE_DIR is set the code
+    sets no directory at all; unset, it is one fixed path inside the
+    checkout whatever the working directory. Fresh interpreters: the
+    directory is process-global jax config (conftest has set it here)."""
+    import subprocess
+    import sys
+
+    from fedml_tpu.utils.metrics import COMPILE_CACHE_DIR
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    code = ("import jax\n"
+            "from fedml_tpu.utils.metrics import enable_compile_cache\n"
+            "seen = []\n"
+            "upd = jax.config.update\n"
+            "jax.config.update = lambda k, v: (seen.append(k), upd(k, v))\n"
+            "d = enable_compile_cache()\n"
+            "assert d == jax.config.jax_compilation_cache_dir\n"
+            "print(d, 'jax_compilation_cache_dir' in seen,\n"
+            "      jax.config.jax_persistent_cache_min_compile_time_secs)\n")
+
+    def run(cwd, cache_env):
+        env = {k: v for k, v in os.environ.items()
+               if k not in ("JAX_COMPILATION_CACHE_DIR", "FEDML_COMPILE_CACHE")}
+        env["PYTHONPATH"] = repo
+        env["HOME"] = str(tmp_path / "home")  # must not matter
+        if cache_env:
+            env["JAX_COMPILATION_CACHE_DIR"] = cache_env
+        out = subprocess.run([sys.executable, "-c", code], cwd=cwd, env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return out.stdout.split()
+
+    if case == "env_set":
+        placed = str(tmp_path / "placed")
+        assert run(repo, placed) == [placed, "False", "1.0"]
+    elif case == "env_unset":
+        assert run(repo, None) == [COMPILE_CACHE_DIR, "True", "1.0"]
+    else:
+        other = tmp_path / "elsewhere"
+        other.mkdir()
+        assert run(repo, None)[0] == run(str(other), None)[0] \
+            == COMPILE_CACHE_DIR

@@ -110,8 +110,8 @@ def test_batchnorm_models_train_in_fedavg():
 def test_resnet_bf16_compute_dtype():
     """Cross-silo HBM knob (both GN and BN variants): dtype=bfloat16 keeps
     PARAMS and norm scales f32, returns f32 logits, trains finite through
-    the engine with remat on — the combination tpu_smoke's cross-silo step
-    falls back to if the full-precision 10-client program doesn't fit."""
+    the engine with remat on — the combination the cross-silo cell can
+    use if the full-precision 10-client program doesn't fit."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -195,10 +195,6 @@ def test_ep_moe_training_equals_single_device(mesh_dp_tp):
     """Expert parallelism: switch-MoE transformer with the expert-stacked
     kernels sharded over 'model' == single device, exactly. Dense one-hot
     dispatch means no capacity dropping, so the oracle is tight."""
-    from fedml_tpu.utils.jax_compat import tp_oracle_unsupported_reason
-
-    if tp_oracle_unsupported_reason():
-        pytest.skip(tp_oracle_unsupported_reason())
     x, y = _seq_data(n=128)
     lm = TransformerLM(vocab_size=64, dim=32, depth=1, num_heads=4,
                        max_len=16, moe_experts=4)
@@ -224,11 +220,6 @@ def test_federated_tensor_parallel_equals_single_device():
     client's vmapped local fit is GSPMD-partitioned over the model axis,
     aggregation stays a weighted psum over 'clients'. Exactly the
     single-device engine's math."""
-    from fedml_tpu.utils.jax_compat import fed_tp_unsupported_reason
-
-    reason = fed_tp_unsupported_reason()
-    if reason:  # old-jax native SIGABRT at compile: must skip, can't catch
-        pytest.skip(reason)
     from fedml_tpu.algorithms.fedavg import FedAvgAPI, FedAvgConfig
     from fedml_tpu.comm.message import pack_pytree
     from fedml_tpu.core.tasks import classification_task
@@ -266,10 +257,6 @@ def test_federated_tensor_parallel_equals_single_device():
 def test_tp_training_equals_single_device(mesh_dp_tp):
     """2x4 ('data','model') DP x TP == single device, exactly (same math,
     different layout): the whole point of compiler-inserted collectives."""
-    from fedml_tpu.utils.jax_compat import tp_oracle_unsupported_reason
-
-    if tp_oracle_unsupported_reason():
-        pytest.skip(tp_oracle_unsupported_reason())
     x, y = _seq_data()
     task = sequence_task(_lm())
     cfg = CentralizedConfig(epochs=2, lr=0.1, batch_size=32, momentum=0.9)
