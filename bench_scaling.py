@@ -17,7 +17,7 @@ Prints one JSON line per point (bench.py remains the single-line driver
 benchmark; this script is the scaling study). A point that fails (e.g. out
 of device memory) prints an error line and the sweep continues.
 --spans 1 adds a host-side span breakdown (pack vs device compute vs eval,
-utils/tracing.RoundTracer) to each point — where round time goes.
+obs/tracing.RoundTracer) to each point — where round time goes.
 """
 
 from __future__ import annotations

@@ -33,7 +33,7 @@ parallel    ring / Ulysses sequence parallelism
 ops         Pallas TPU kernels (flash attention)
 native      C++ host data plane (ctypes)
 experiments unified CLI + multi-process launcher              (L5)
-utils       pytree ops, metrics, tracing, condensation
+utils       pytree ops, metrics, condensation
 """
 
 __version__ = "0.1.0"

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax import lax
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fedml_tpu.core.client_data import (
@@ -72,6 +73,14 @@ from fedml_tpu.utils.tree import tree_weighted_mean
 log = logging.getLogger("fedml_tpu.fedavg")
 
 
+# The round program's three scopes (fed_gather, fed_aggregate,
+# fed_server_update) are each put once, on the shared function every driver
+# calls. Single tokens without '/', so a trace reduction finds them through
+# vmap(..), jvp(..) and transpose(..) wrappers in an op's name; metadata
+# only: the lowered program computes the same bits. The local fit has no
+# scope of its own (a frame or a ``with`` under its trace slowed set-up on
+# the v5e host, PERF.md section 6): it is the device time outside the three.
+@jax.named_scope("fed_gather")
 def _gather_rows(dev_x, dev_y, idx, mask):
     """Row gather for the device-resident data plane (single-device and
     per-shard SPMD paths share this). Padded slots (mask==0) carry idx 0, so
@@ -164,6 +173,7 @@ def _mesh_drift_stats(net_params, avg_params, nsamp, axis) -> dict:
     }
 
 
+@jax.named_scope("fed_aggregate")
 def _shard_aggregate(nets, metrics, nsamp, axis):
     """Per-shard weighted aggregation under shard_map: weighted psum of the
     client nets (numerator+denominator over the mesh axis) and psum-med
@@ -340,289 +350,295 @@ class FedAvgAPI:
         shard_server_state: bool = False,
         partition_rules=None,
     ):
-        self.data = dataset
-        self.task = task
-        self.cfg = config
-        self.mesh = mesh
-        # Streamed client state (core/client_source.py, docs/PERFORMANCE.md
-        # §Streaming & cohort bucketing): a ClientDataSource keeps per-client
-        # payload OUT of host memory — packing reads only the sampled
-        # cohort's rows, so host RSS stays flat in population size (the
-        # memwatch fed_host_rss_bytes gauge is the live evidence). The
-        # device-resident planes require the full train set in HBM, which is
-        # exactly what a streamed population cannot afford — refuse loudly.
-        self._source = dataset if isinstance(dataset, ClientDataSource) \
-            else None
-        if self._source is not None and (device_data or block_working_set):
-            raise ValueError(
-                "device_data/block_working_set park the FULL train set on "
-                "device — incompatible with a streamed ClientDataSource "
-                "(pass the host-packed plane, or materialize the dataset)")
-        if self._source is not None \
-                and config.local_test_on_all_clients == "on":
-            # 'auto' already degrades to the global test set (sources carry
-            # no per-client test splits); a FORCED per-client eval would
-            # die mid-run in evaluate_per_client — refuse at construction
-            raise ValueError(
-                "local_test_on_all_clients='on' iterates every client's "
-                "own split — not available on a streamed ClientDataSource "
-                "(use 'auto'/'off': the global test split is evaluated)")
-        # Pipelined round execution (core/pipeline.py, docs/PERFORMANCE.md):
-        # ``prefetch`` > 0 arms the double-buffered host->device prefetch —
-        # a packer thread prepares round r+1's batch and issues its
-        # device_put while round r executes, with up to ``prefetch`` batches
-        # staged ahead (2 = classic double buffering). ``drain_lag`` is how
-        # many rounds behind dispatch the metrics/quarantine drain trails,
-        # so JAX async dispatch stays that deep. Bit-identical to the
-        # synchronous driver (packing is a pure function of (seed, round);
-        # test-enforced); prefetch=0 (default) changes nothing.
-        if prefetch < 0:
-            raise ValueError(f"prefetch must be >= 0, got {prefetch}")
-        if drain_lag < 0:
-            raise ValueError(f"drain_lag must be >= 0, got {drain_lag}")
-        self.prefetch = int(prefetch)
-        self.drain_lag = int(drain_lag)
-        # test/instrumentation hook: a callable observing the pipeline's
-        # ("produced"/"got"/"drained", key) events — the overlap oracle
-        self._pipe_on_event = None
-        # Byzantine-robust aggregation (core/robust_agg.py). ``aggregator``
-        # replaces the weighted mean with a robust estimator over the
-        # stacked client updates: 'mean' | 'median' | 'trimmed_mean' |
-        # 'krum' | 'multi_krum' | 'geometric_median', or a callable
-        # ``(stacked, weights) -> (tree, info)``. ``sanitize`` fronts it
-        # with the non-finite/norm-outlier gate (True = default norm_mult,
-        # a float = that multiple, False = off; None = on iff an
-        # aggregator is set). The default (None/None) keeps the round
-        # program bit-identical to the plain weighted-mean build.
-        if aggregator is None:
-            self._robust_agg = None
-        elif callable(aggregator):
-            self._robust_agg = aggregator
-        else:
-            self._robust_agg = make_robust_aggregator(
-                aggregator, n=config.client_num_per_round,
-                **(aggregator_params or {}))
-        if sanitize is None:
-            sanitize = self._robust_agg is not None
-        self._sanitize_mult = (
-            None if sanitize is False
-            else DEFAULT_NORM_MULT if sanitize is True else float(sanitize))
-        self._needs_stacked = (self._robust_agg is not None
-                               or self._sanitize_mult is not None)
-        # per-round gate/aggregator verdicts (suspected/rejected ranks);
-        # rank = stacked slot + 1, matching the loopback runtime's worker
-        # ranks so the two ledgers are comparable entry-for-entry
-        self.quarantine = QuarantineLedger()
-        # model-space adversary injection (chaos/adversary.py): perturb the
-        # stacked client nets INSIDE the jitted round program, per the
-        # plan's (round-window, rank) schedule — the standalone twin of a
-        # Byzantine client lying on the wire.
-        self._adversary = None
-        if adversary_plan is not None:
-            if mesh is not None:
-                raise ValueError(
-                    "adversary_plan is a standalone-simulation feature "
-                    "(single device); on a mesh run the cross-process "
-                    "runtime with per-client adversaries instead")
-            from fedml_tpu.chaos.adversary import make_in_graph_injector
-
-            self._adversary = make_in_graph_injector(
-                adversary_plan, config.client_num_per_round)
-            self.adversary_plan = adversary_plan
-        # telemetry: an obs.Telemetry bundle. None (default) keeps the round
-        # program bit-identical to the untelemetered build — the stats below
-        # are extra jit OUTPUTS, so the off path has zero overhead and the
-        # on path adds no device sync beyond the metrics it already returns.
-        self.telemetry = telemetry
-        self._emit_stats = telemetry is not None and telemetry.round_stats
-        # uniform_avg: aggregate with weight 1 per REAL client (0 for
-        # zero-sample padding) instead of sample counts. DP-FedAvg needs
-        # this: with sample-weighted averaging a clipped update's influence
-        # is (n_k/Σn)·C, unbounded by C/m on unbalanced data, which
-        # invalidates the sensitivity the DP noise is calibrated for.
-        # size_weighted sampling FORCES it: P(k) ∝ n_k + uniform average
-        # is the unbiased pairing (sampling twice — by probability AND by
-        # weight — would double-count data-rich clients).
-        self.uniform_avg = uniform_avg or config.sampling == "size_weighted"
-        if getattr(config, "churn_trace", None) is not None \
-                and mesh is not None:
-            raise ValueError(
-                "churn_trace varies the per-round cohort size, which breaks "
-                "the mesh's static client-shard shapes — run churned "
-                "cohorts standalone or through the cross-process runtime "
-                "(rank-level scheduled availability)")
-        self._client_sizes = prepare_sampling(config, dataset)
-        self.rng = jax.random.PRNGKey(config.seed)
-
-        # device-resident data plane: park the whole train set in HBM once;
-        # each round ships only an IndexBatch (KBs) and the row gather runs
-        # on device. Batches are bit-identical to the host packer's.
-        # donate=True: the per-round program consumes the incoming net/opt
-        # buffers (XLA writes outputs in place — no second copy of the model
-        # in HBM). Opt-in because a caller may legitimately hold a reference
-        # to api.net across rounds (e.g. comparing against round-0 weights);
-        # the bench paths enable it. The R-round block fns always donate —
-        # their contract never exposed intermediate nets.
-        # block_working_set: do NOT park the whole train set in HBM. Each
-        # run_rounds block instead uploads only the UNIQUE rows its sampled
-        # clients touch (indices remapped into the compact array, row count
-        # padded to a bucket so jit re-uses one compiled executable across
-        # blocks). Batches stay bit-identical to the full-park plane
-        # (test-enforced); what changes is transfer: ~R*K*samples rows
-        # (tens of MB) per block instead of the full set (hundreds of MB)
-        # up front — the difference between dying and finishing on a slow
-        # host->device link. run_round falls back to the host-packed plane.
-        self.donate = donate
-        self.device_data = device_data
-        self.block_working_set = block_working_set
-        if block_working_set and not device_data:
-            raise ValueError("block_working_set is a device_data mode "
-                             "(pass device_data=True)")
-        if device_data and not block_working_set:
-            sh = NamedSharding(mesh, P()) if mesh is not None else None
-            put = (lambda a: jax.device_put(a, sh)) if sh else jax.device_put
-            self._dev_x = put(dataset.train_x)
-            self._dev_y = put(dataset.train_y)
-
-        # static per-client batch budget: fixed across rounds so the round
-        # program compiles once (see SURVEY.md §7 "hard parts" (1)).
-        # Streamed sources answer from size METADATA — no payload read.
-        if self._source is not None:
-            max_count = int(np.max(self._source.client_sizes))
-        else:
-            max_count = max(len(v) for v in dataset.train_idx_map.values())
-        b_needed = int(np.ceil(max_count / config.batch_size))
-        self.num_batches = min(config.max_batches or b_needed, b_needed)
-        # bucket_batches: shrink each round's (or block's) common batch
-        # depth to the max the SAMPLED clients actually need, rounded up a
-        # small static ladder. Trailing all-masked batch slots are exact
-        # state no-ops (local.py's has_data select; rng chains are
-        # position-based) — so this is bit-exact while skipping their full
-        # compute cost, at the price of one extra jit variant per bucket
-        # (<=4). On size-skewed natural partitions (FEMNIST lognormal)
-        # most rounds sample no near-maximal client, so the common depth
-        # drops well below num_batches.
-        self.bucket_batches = bucket_batches
-        ladder = sorted({-(-self.num_batches // d) for d in (8, 4, 2, 1)})
-        self._b_ladder = [b for b in ladder if b > 0]
-
-        self.local_spec = resolve_local_spec(local_spec, config)
-        self.local_update = make_local_update(task, self.local_spec)
-        self.eval_fn = make_eval_fn(task)
-
-        # server update hook: (net_old, net_avg, opt_state) -> (net_new, opt_state)
-        self.server_update = server_update or (lambda old, avg, s: (avg, s))
-        self.client_result_hook = client_result_hook  # (net_k, net_global, rng) -> net_k
-        self.post_aggregate_hook = post_aggregate_hook  # (net, rng) -> net
-
-        # init model
-        self.rng, init_key = jax.random.split(self.rng)
-        x_sample = jnp.asarray(
-            self._source.init_batch(config.batch_size)
-            if self._source is not None
-            else dataset.train_x[: config.batch_size])
-        self.net = task.init(init_key, x_sample)
-        # federated TENSOR parallelism: a ('clients','model') mesh shards
-        # each client's local fit over 'model' (Megatron specs, GSPMD
-        # collectives) while 'clients' stays the manual FL axis — the
-        # round program is shard_map(axis_names={'clients'}) so the model
-        # axis remains auto and the compiler partitions the vmapped local
-        # SGD. Params are placed TP-sharded up front.
-        self._tp = mesh is not None and "model" in mesh.axis_names
-        if self._tp:
-            from fedml_tpu.parallel.tensor_parallel import shard_params
-
-            params, self.tp_specs = shard_params(self.net.params, mesh)
-            rep = NamedSharding(mesh, P())
-            extra = jax.tree.map(lambda v: jax.device_put(v, rep),
-                                 self.net.extra)
-            self.net = self.net._replace(params=params, extra=extra)
-        # Mesh-sharded server state (core/partition_rules.py,
-        # docs/PERFORMANCE.md §Partitioned server state): the global model
-        # + server optimizer state live PARTITIONED over the client mesh
-        # axis per a regex partition-rule table; the round program
-        # constrains the aggregate and the updated state to that layout, so
-        # XLA reduce-scatters the weighted update sum into each device's
-        # shard, runs the server update shard-locally, and all-gathers only
-        # at the broadcast into the next round's local fits
-        # (arXiv:2004.13336). Bitwise-identical to the replicated mesh path
-        # (test-enforced: resharding moves bits, the psum aggregation math
-        # is byte-for-byte the same program).
-        self._sharded = bool(shard_server_state)
-        self.partitioner = None
-        self._agg_reshard = None
-        if self._sharded:
-            if mesh is None:
-                raise ValueError("shard_server_state partitions the server "
-                                 "plane over a mesh — pass mesh=")
-            if self._tp:
-                raise ValueError(
-                    "shard_server_state composes with the pure 'clients' "
-                    "mesh; a ('clients','model') TP mesh already shards "
-                    "params over 'model'")
-            from fedml_tpu.core.partition_rules import ServerStatePartitioner
-            from fedml_tpu.core.robust_agg import COORDINATEWISE
-
-            self.partitioner = ServerStatePartitioner(
-                mesh, rules=partition_rules)
-            self.net = self.partitioner.shard(self.net)
-            # coordinate-wise estimators run shard-local after an
-            # all-to-all to param-sharded stacked layout (specs derived
-            # from the NET template so custom rule tables apply);
-            # krum/geo-median keep the gathered path (COORDINATEWISE)
-            if isinstance(aggregator, str) and aggregator in COORDINATEWISE:
-                self._agg_reshard = self.partitioner.stacked_constrainer(
-                    self.net)
-        self.server_opt_state = server_opt_init(self.net.params) if server_opt_init else ()
-        if self._sharded and server_opt_init is not None:
-            # fedopt-style server optimizer state (momenta mirror the param
-            # tree) shards by the same rule table — the Adam moments are
-            # the 2x multiplier that makes sharding the server plane matter
-            self.server_opt_state = self.partitioner.shard(
-                self.server_opt_state)
-
-        self.round_fn = self._build_round_fn()
-        self._test_cache = None
-        self.history: list[dict] = []
-        # per-round pack/bucket accounting (docs/PERFORMANCE.md §Streaming
-        # & cohort bucketing): written at pack time (possibly on the
-        # prefetch thread — single-key dict writes are GIL-atomic), popped
-        # into the telemetry round record at emit time. Bounded by the
-        # prefetch depth.
-        self._pack_stats: dict[int, dict] = {}
-        # pack/compute/eval spans (SURVEY.md §5); with a tracing-enabled
-        # Telemetry bundle, the same spans also feed the distributed
-        # tracer's single-rank timeline (all host-side — nothing traced
-        # here touches the jitted round program)
+        # the engine's host spans (docs/OBSERVABILITY.md §Engine spans):
+        # init/pack/place/round/fetch/eval, each also an event of the jax
+        # profiler's trace; with a tracing-enabled Telemetry bundle the
+        # same spans feed the distributed tracer's single-rank timeline
+        # (all host-side: nothing here touches the jitted round program).
+        # The compile listeners go in before the first compile, so set-up
+        # is accounted where it happens (perf_instrument.setup_phases).
         self.tracer = RoundTracer(
             sink=telemetry.tracer if telemetry is not None else None)
-        # server-plane sizing + per-round aggregation-bytes accounting
-        # (obs/perf_instrument: fed_server_state_bytes{placement} /
-        # fed_agg_bytes_total{mode}) — the metrics the sharded-vs-
-        # replicated HBM claim is asserted on
-        # sized component-by-component: one (net, opt) tuple would prefix
-        # every leaf path with '0/'/'1/' and anchored custom rules would
-        # resolve differently here than they did in shard()
-        per_dev = (
-            self.partitioner.bytes_per_device(self.net)
-            + self.partitioner.bytes_per_device(self.server_opt_state)
-            if self._sharded
-            else _tree_bytes((self.net, self.server_opt_state)))
-        self._state_placement = "sharded" if self._sharded else "replicated"
-        self._agg_bytes_round = (_tree_bytes(self.net)
-                                 * config.client_num_per_round)
-        _perf.set_server_state_bytes(self._state_placement, per_dev)
-        # rides every telemetry round record (report.py renders srv_B/mode)
-        self._agg_record = {
-            "mode": self._state_placement,
-            "server_state_bytes_per_device": int(per_dev),
-            "bytes_per_round": int(self._agg_bytes_round),
-        }
-        # mixed-precision runs stamp the policy on every round record
-        # (report.py's `prec` column; absent = f32, so pre-policy logs
-        # render unchanged)
-        if self.local_spec.compute_dtype not in ("f32", "float32"):
-            self._agg_record["prec"] = self.local_spec.compute_dtype
+        _perf.install()
+        with self.tracer.span("init"):
+            self.data = dataset
+            self.task = task
+            self.cfg = config
+            self.mesh = mesh
+            # Streamed client state (core/client_source.py, docs/PERFORMANCE.md
+            # §Streaming & cohort bucketing): a ClientDataSource keeps per-client
+            # payload OUT of host memory — packing reads only the sampled
+            # cohort's rows, so host RSS stays flat in population size (the
+            # memwatch fed_host_rss_bytes gauge is the live evidence). The
+            # device-resident planes require the full train set in HBM, which is
+            # exactly what a streamed population cannot afford — refuse loudly.
+            self._source = dataset if isinstance(dataset, ClientDataSource) \
+                else None
+            if self._source is not None and (device_data or block_working_set):
+                raise ValueError(
+                    "device_data/block_working_set park the FULL train set on "
+                    "device — incompatible with a streamed ClientDataSource "
+                    "(pass the host-packed plane, or materialize the dataset)")
+            if self._source is not None \
+                    and config.local_test_on_all_clients == "on":
+                # 'auto' already degrades to the global test set (sources carry
+                # no per-client test splits); a FORCED per-client eval would
+                # die mid-run in evaluate_per_client — refuse at construction
+                raise ValueError(
+                    "local_test_on_all_clients='on' iterates every client's "
+                    "own split — not available on a streamed ClientDataSource "
+                    "(use 'auto'/'off': the global test split is evaluated)")
+            # Pipelined round execution (core/pipeline.py, docs/PERFORMANCE.md):
+            # ``prefetch`` > 0 arms the double-buffered host->device prefetch —
+            # a packer thread prepares round r+1's batch and issues its
+            # device_put while round r executes, with up to ``prefetch`` batches
+            # staged ahead (2 = classic double buffering). ``drain_lag`` is how
+            # many rounds behind dispatch the metrics/quarantine drain trails,
+            # so JAX async dispatch stays that deep. Bit-identical to the
+            # synchronous driver (packing is a pure function of (seed, round);
+            # test-enforced); prefetch=0 (default) changes nothing.
+            if prefetch < 0:
+                raise ValueError(f"prefetch must be >= 0, got {prefetch}")
+            if drain_lag < 0:
+                raise ValueError(f"drain_lag must be >= 0, got {drain_lag}")
+            self.prefetch = int(prefetch)
+            self.drain_lag = int(drain_lag)
+            # test/instrumentation hook: a callable observing the pipeline's
+            # ("produced"/"got"/"drained", key) events — the overlap oracle
+            self._pipe_on_event = None
+            # Byzantine-robust aggregation (core/robust_agg.py). ``aggregator``
+            # replaces the weighted mean with a robust estimator over the
+            # stacked client updates: 'mean' | 'median' | 'trimmed_mean' |
+            # 'krum' | 'multi_krum' | 'geometric_median', or a callable
+            # ``(stacked, weights) -> (tree, info)``. ``sanitize`` fronts it
+            # with the non-finite/norm-outlier gate (True = default norm_mult,
+            # a float = that multiple, False = off; None = on iff an
+            # aggregator is set). The default (None/None) keeps the round
+            # program bit-identical to the plain weighted-mean build.
+            if aggregator is None:
+                self._robust_agg = None
+            elif callable(aggregator):
+                self._robust_agg = aggregator
+            else:
+                self._robust_agg = make_robust_aggregator(
+                    aggregator, n=config.client_num_per_round,
+                    **(aggregator_params or {}))
+            if sanitize is None:
+                sanitize = self._robust_agg is not None
+            self._sanitize_mult = (
+                None if sanitize is False
+                else DEFAULT_NORM_MULT if sanitize is True else float(sanitize))
+            self._needs_stacked = (self._robust_agg is not None
+                                   or self._sanitize_mult is not None)
+            # per-round gate/aggregator verdicts (suspected/rejected ranks);
+            # rank = stacked slot + 1, matching the loopback runtime's worker
+            # ranks so the two ledgers are comparable entry-for-entry
+            self.quarantine = QuarantineLedger()
+            # model-space adversary injection (chaos/adversary.py): perturb the
+            # stacked client nets INSIDE the jitted round program, per the
+            # plan's (round-window, rank) schedule — the standalone twin of a
+            # Byzantine client lying on the wire.
+            self._adversary = None
+            if adversary_plan is not None:
+                if mesh is not None:
+                    raise ValueError(
+                        "adversary_plan is a standalone-simulation feature "
+                        "(single device); on a mesh run the cross-process "
+                        "runtime with per-client adversaries instead")
+                from fedml_tpu.chaos.adversary import make_in_graph_injector
+
+                self._adversary = make_in_graph_injector(
+                    adversary_plan, config.client_num_per_round)
+                self.adversary_plan = adversary_plan
+            # telemetry: an obs.Telemetry bundle. None (default) keeps the round
+            # program bit-identical to the untelemetered build — the stats below
+            # are extra jit OUTPUTS, so the off path has zero overhead and the
+            # on path adds no device sync beyond the metrics it already returns.
+            self.telemetry = telemetry
+            self._emit_stats = telemetry is not None and telemetry.round_stats
+            # uniform_avg: aggregate with weight 1 per REAL client (0 for
+            # zero-sample padding) instead of sample counts. DP-FedAvg needs
+            # this: with sample-weighted averaging a clipped update's influence
+            # is (n_k/Σn)·C, unbounded by C/m on unbalanced data, which
+            # invalidates the sensitivity the DP noise is calibrated for.
+            # size_weighted sampling FORCES it: P(k) ∝ n_k + uniform average
+            # is the unbiased pairing (sampling twice — by probability AND by
+            # weight — would double-count data-rich clients).
+            self.uniform_avg = uniform_avg or config.sampling == "size_weighted"
+            if getattr(config, "churn_trace", None) is not None \
+                    and mesh is not None:
+                raise ValueError(
+                    "churn_trace varies the per-round cohort size, which breaks "
+                    "the mesh's static client-shard shapes — run churned "
+                    "cohorts standalone or through the cross-process runtime "
+                    "(rank-level scheduled availability)")
+            self._client_sizes = prepare_sampling(config, dataset)
+            self.rng = jax.random.PRNGKey(config.seed)
+
+            # device-resident data plane: park the whole train set in HBM once;
+            # each round ships only an IndexBatch (KBs) and the row gather runs
+            # on device. Batches are bit-identical to the host packer's.
+            # donate=True: the per-round program consumes the incoming net/opt
+            # buffers (XLA writes outputs in place — no second copy of the model
+            # in HBM). Opt-in because a caller may legitimately hold a reference
+            # to api.net across rounds (e.g. comparing against round-0 weights);
+            # the bench paths enable it. The R-round block fns always donate —
+            # their contract never exposed intermediate nets.
+            # block_working_set: do NOT park the whole train set in HBM. Each
+            # run_rounds block instead uploads only the UNIQUE rows its sampled
+            # clients touch (indices remapped into the compact array, row count
+            # padded to a bucket so jit re-uses one compiled executable across
+            # blocks). Batches stay bit-identical to the full-park plane
+            # (test-enforced); what changes is transfer: ~R*K*samples rows
+            # (tens of MB) per block instead of the full set (hundreds of MB)
+            # up front — the difference between dying and finishing on a slow
+            # host->device link. run_round falls back to the host-packed plane.
+            self.donate = donate
+            self.device_data = device_data
+            self.block_working_set = block_working_set
+            if block_working_set and not device_data:
+                raise ValueError("block_working_set is a device_data mode "
+                                 "(pass device_data=True)")
+            if device_data and not block_working_set:
+                sh = NamedSharding(mesh, P()) if mesh is not None else None
+                put = (lambda a: jax.device_put(a, sh)) if sh else jax.device_put
+                self._dev_x = put(dataset.train_x)
+                self._dev_y = put(dataset.train_y)
+
+            # static per-client batch budget: fixed across rounds so the round
+            # program compiles once (see SURVEY.md §7 "hard parts" (1)).
+            # Streamed sources answer from size METADATA — no payload read.
+            if self._source is not None:
+                max_count = int(np.max(self._source.client_sizes))
+            else:
+                max_count = max(len(v) for v in dataset.train_idx_map.values())
+            b_needed = int(np.ceil(max_count / config.batch_size))
+            self.num_batches = min(config.max_batches or b_needed, b_needed)
+            # bucket_batches: shrink each round's (or block's) common batch
+            # depth to the max the SAMPLED clients actually need, rounded up a
+            # small static ladder. Trailing all-masked batch slots are exact
+            # state no-ops (local.py's has_data select; rng chains are
+            # position-based) — so this is bit-exact while skipping their full
+            # compute cost, at the price of one extra jit variant per bucket
+            # (<=4). On size-skewed natural partitions (FEMNIST lognormal)
+            # most rounds sample no near-maximal client, so the common depth
+            # drops well below num_batches.
+            self.bucket_batches = bucket_batches
+            ladder = sorted({-(-self.num_batches // d) for d in (8, 4, 2, 1)})
+            self._b_ladder = [b for b in ladder if b > 0]
+
+            self.local_spec = resolve_local_spec(local_spec, config)
+            self.local_update = make_local_update(task, self.local_spec)
+            self.eval_fn = make_eval_fn(task)
+
+            # server update hook: (net_old, net_avg, opt_state) -> (net_new, opt_state)
+            self.server_update = server_update or (lambda old, avg, s: (avg, s))
+            self.client_result_hook = client_result_hook  # (net_k, net_global, rng) -> net_k
+            self.post_aggregate_hook = post_aggregate_hook  # (net, rng) -> net
+
+            # init model
+            self.rng, init_key = jax.random.split(self.rng)
+            x_sample = jnp.asarray(
+                self._source.init_batch(config.batch_size)
+                if self._source is not None
+                else dataset.train_x[: config.batch_size])
+            with _perf.attribute_compiles(_perf.INIT_VARIANT):
+                self.net = task.init(init_key, x_sample)
+            # federated TENSOR parallelism: a ('clients','model') mesh shards
+            # each client's local fit over 'model' (Megatron specs, GSPMD
+            # collectives) while 'clients' stays the manual FL axis — the
+            # round program is shard_map(axis_names={'clients'}) so the model
+            # axis remains auto and the compiler partitions the vmapped local
+            # SGD. Params are placed TP-sharded up front.
+            self._tp = mesh is not None and "model" in mesh.axis_names
+            if self._tp:
+                from fedml_tpu.parallel.tensor_parallel import shard_params
+
+                params, self.tp_specs = shard_params(self.net.params, mesh)
+                rep = NamedSharding(mesh, P())
+                extra = jax.tree.map(lambda v: jax.device_put(v, rep),
+                                     self.net.extra)
+                self.net = self.net._replace(params=params, extra=extra)
+            # Mesh-sharded server state (core/partition_rules.py,
+            # docs/PERFORMANCE.md §Partitioned server state): the global model
+            # + server optimizer state live PARTITIONED over the client mesh
+            # axis per a regex partition-rule table; the round program
+            # constrains the aggregate and the updated state to that layout, so
+            # XLA reduce-scatters the weighted update sum into each device's
+            # shard, runs the server update shard-locally, and all-gathers only
+            # at the broadcast into the next round's local fits
+            # (arXiv:2004.13336). Bitwise-identical to the replicated mesh path
+            # (test-enforced: resharding moves bits, the psum aggregation math
+            # is byte-for-byte the same program).
+            self._sharded = bool(shard_server_state)
+            self.partitioner = None
+            self._agg_reshard = None
+            if self._sharded:
+                if mesh is None:
+                    raise ValueError("shard_server_state partitions the server "
+                                     "plane over a mesh — pass mesh=")
+                if self._tp:
+                    raise ValueError(
+                        "shard_server_state composes with the pure 'clients' "
+                        "mesh; a ('clients','model') TP mesh already shards "
+                        "params over 'model'")
+                from fedml_tpu.core.partition_rules import ServerStatePartitioner
+                from fedml_tpu.core.robust_agg import COORDINATEWISE
+
+                self.partitioner = ServerStatePartitioner(
+                    mesh, rules=partition_rules)
+                self.net = self.partitioner.shard(self.net)
+                # coordinate-wise estimators run shard-local after an
+                # all-to-all to param-sharded stacked layout (specs derived
+                # from the NET template so custom rule tables apply);
+                # krum/geo-median keep the gathered path (COORDINATEWISE)
+                if isinstance(aggregator, str) and aggregator in COORDINATEWISE:
+                    self._agg_reshard = self.partitioner.stacked_constrainer(
+                        self.net)
+            self.server_opt_state = server_opt_init(self.net.params) if server_opt_init else ()
+            if self._sharded and server_opt_init is not None:
+                # fedopt-style server optimizer state (momenta mirror the param
+                # tree) shards by the same rule table — the Adam moments are
+                # the 2x multiplier that makes sharding the server plane matter
+                self.server_opt_state = self.partitioner.shard(
+                    self.server_opt_state)
+
+            self.round_fn = self._build_round_fn()
+            self._test_cache = None
+            self.history: list[dict] = []
+            # per-round pack/bucket accounting (docs/PERFORMANCE.md §Streaming
+            # & cohort bucketing): written at pack time (possibly on the
+            # prefetch thread — single-key dict writes are GIL-atomic), popped
+            # into the telemetry round record at emit time. Bounded by the
+            # prefetch depth.
+            self._pack_stats: dict[int, dict] = {}
+            # server-plane sizing + per-round aggregation-bytes accounting
+            # (obs/perf_instrument: fed_server_state_bytes{placement} /
+            # fed_agg_bytes_total{mode}) — the metrics the sharded-vs-
+            # replicated HBM claim is asserted on
+            # sized component-by-component: one (net, opt) tuple would prefix
+            # every leaf path with '0/'/'1/' and anchored custom rules would
+            # resolve differently here than they did in shard()
+            per_dev = (
+                self.partitioner.bytes_per_device(self.net)
+                + self.partitioner.bytes_per_device(self.server_opt_state)
+                if self._sharded
+                else _tree_bytes((self.net, self.server_opt_state)))
+            self._state_placement = "sharded" if self._sharded else "replicated"
+            self._agg_bytes_round = (_tree_bytes(self.net)
+                                     * config.client_num_per_round)
+            _perf.set_server_state_bytes(self._state_placement, per_dev)
+            # rides every telemetry round record (report.py renders srv_B/mode)
+            self._agg_record = {
+                "mode": self._state_placement,
+                "server_state_bytes_per_device": int(per_dev),
+                "bytes_per_round": int(self._agg_bytes_round),
+            }
+            # mixed-precision runs stamp the policy on every round record
+            # (report.py's `prec` column; absent = f32, so pre-policy logs
+            # render unchanged)
+            if self.local_spec.compute_dtype not in ("f32", "float32"):
+                self._agg_record["prec"] = self.local_spec.compute_dtype
 
     # ------------------------------------------------------------------ round
     def _round_body(self, keys, net, server_opt_state, x, y, mask, nsamp,
@@ -663,10 +679,12 @@ class FedAvgAPI:
             # (core/robust_agg.gated_aggregate). With a sharded server
             # state, coordinate-wise estimators get the partitioner's
             # stacked-layout constraint so their sorts run shard-local.
-            avg, _, reasons = gated_aggregate(
-                nets, net, self._agg_weights(nsamp),
-                robust_fn=self._robust_agg, norm_mult=self._sanitize_mult,
-                reshard_fn=self._agg_reshard)
+            with jax.named_scope("fed_aggregate"):
+                avg, _, reasons = gated_aggregate(
+                    nets, net, self._agg_weights(nsamp),
+                    robust_fn=self._robust_agg,
+                    norm_mult=self._sanitize_mult,
+                    reshard_fn=self._agg_reshard)
         else:
             avg = tree_weighted_mean(nets, self._agg_weights(nsamp))
             reasons = None
@@ -681,6 +699,7 @@ class FedAvgAPI:
             agg_metrics["__quarantine"] = reasons
         return new_net, new_opt, agg_metrics
 
+    @jax.named_scope("fed_server_update")
     def _update_from_aggregate(self, net, avg, server_opt_state, post_key):
         """constrain(aggregate) -> server_update -> post hook ->
         constrain(new state): the ONE server-side update composition every
@@ -1187,6 +1206,12 @@ class FedAvgAPI:
                 # over the R rounds, like the 'block' event record)
                 self.telemetry.tracer.begin_round(start_round)
 
+        # These two frames sit under the whole trace of a first dispatch,
+        # and on the v5e host that trace ran 15 to 40 % slower under every
+        # reshaping of them that PR 26 tried (a place span, round ids, the
+        # variant scope): PERF.md section 6. So the block path keeps its two
+        # spans as they were, and perf_instrument names the program's
+        # compile events by the jit function's name instead.
         with self.tracer.span("pack"):
             packed = self._pack_block_host(start_round, num_rounds)
             ids_l, placed = self._place_block(packed)
@@ -1303,7 +1328,8 @@ class FedAvgAPI:
             wait = self._goodput_wait(ms)
             wall = self._goodput_interval()
         ms = self._drain_quarantine_block(ms, start_round, ids_l)
-        ms_host = {k: np.asarray(v) for k, v in ms.items()}
+        with self.tracer.span("fetch", round=start_round):
+            ms_host = {k: np.asarray(v) for k, v in ms.items()}
         if self.telemetry is not None:
             self._emit_block_records(start_round, num_rounds, ids_l, ms_host,
                                      spans=spans, pipeline=pipeline,
@@ -1333,10 +1359,14 @@ class FedAvgAPI:
             self._block_fn = self._build_block_fn()
 
         def produce(s):
+            # packer thread: bare annotations, never self.tracer (see
+            # _pack_round_placed)
             t0 = time.perf_counter()
-            packed = self._pack_block_host(s, block_rounds)
+            with TraceAnnotation("fed:prefetch_pack", round=s):
+                packed = self._pack_block_host(s, block_rounds)
             t1 = time.perf_counter()
-            ids_l, placed = self._place_block(packed)
+            with TraceAnnotation("fed:h2d", round=s):
+                ids_l, placed = self._place_block(packed)
             h2d = time.perf_counter() - t1
             _perf.record_span("prefetch_pack", t1 - t0)
             _perf.record_h2d(h2d)
@@ -1424,17 +1454,17 @@ class FedAvgAPI:
                    else [self.num_batches])
         rng = jax.random.PRNGKey(0)
         r0, ids = jnp.int32(0), jnp.zeros((K,), jnp.int32)
-        # precision x bucket variant naming: a bf16 engine's warmed
-        # executables are DIFFERENT programs from the f32 engine's, and
-        # the report must say which ladder was precompiled
-        prec = ("" if self.local_spec.compute_dtype in ("f32", "float32")
-                else f"_{self.local_spec.compute_dtype}")
+        # precision x bucket variant naming (_variant_name): a bf16
+        # engine's warmed executables are DIFFERENT programs from the f32
+        # engine's, and the report must say which ladder was precompiled
         lowered = {}
         if per_round:
             for B in buckets:
-                lowered[f"round{prec}_b{B}"] = self.round_fn.lower(
-                    rng, self.net, self.server_opt_state,
-                    self._warmup_batch(B), r0, ids)
+                name = self._variant_name(B=B)
+                with _perf.attribute_compiles(name):
+                    lowered[name] = self.round_fn.lower(
+                        rng, self.net, self.server_opt_state,
+                        self._warmup_batch(B), r0, ids)
         if block_rounds and self.device_data and not self.block_working_set \
                 and not (self.mesh is not None and self._needs_stacked):
             if not hasattr(self, "_block_fn"):
@@ -1451,10 +1481,12 @@ class FedAvgAPI:
                                        P(None, self.mesh.axis_names[0]))
                     blocks = [jax.device_put(b, sh) for b in blocks]
                 blocks = [jnp.asarray(b) for b in blocks]
-                lowered[f"block{prec}_r{R}_b{B}"] = self._block_fn.lower(
-                    rng, self.net, self.server_opt_state,
-                    self._dev_x, self._dev_y, *blocks,
-                    jnp.asarray(np.arange(R, dtype=np.int32)))
+                name = self._variant_name(B=B, block_rounds=R)
+                with _perf.attribute_compiles(name):
+                    lowered[name] = self._block_fn.lower(
+                        rng, self.net, self.server_opt_state,
+                        self._dev_x, self._dev_y, *blocks,
+                        jnp.asarray(np.arange(R, dtype=np.int32)))
         rep = compile_concurrently(lowered, max_workers=max_workers)
         rep.pop("executables", None)
         rep["bucket_depths"] = buckets
@@ -1539,7 +1571,8 @@ class FedAvgAPI:
         if "__quarantine" not in metrics:
             return metrics
         metrics = dict(metrics)
-        codes = np.asarray(metrics.pop("__quarantine"))
+        with self.tracer.span("fetch", round=round_idx):
+            codes = np.asarray(metrics.pop("__quarantine"))
         self.quarantine.record_codes(round_idx, codes,
                                      clients=np.asarray(ids).tolist())
         return metrics
@@ -1548,7 +1581,8 @@ class FedAvgAPI:
         if "__quarantine" not in ms:
             return ms
         ms = dict(ms)
-        codes = np.asarray(ms.pop("__quarantine"))  # [R, K]
+        with self.tracer.span("fetch", round=start_round):
+            codes = np.asarray(ms.pop("__quarantine"))  # [R, K]
         for i in range(codes.shape[0]):
             self.quarantine.record_codes(start_round + i, codes[i],
                                          clients=ids_l[i].tolist())
@@ -1587,10 +1621,11 @@ class FedAvgAPI:
         anyway (emit floats them / drain np.asarray's them), so the off
         path stays bit-identical and sync-free."""
         t0 = time.perf_counter()
-        try:
-            jax.block_until_ready(metrics)
-        except Exception:  # noqa: BLE001 — non-array metrics: nothing to wait
-            pass
+        with self.tracer.span("fetch"):
+            try:
+                jax.block_until_ready(metrics)
+            except Exception:  # noqa: BLE001 — non-array metrics: nothing to wait
+                pass
         return time.perf_counter() - t0
 
     def _goodput_extra(self, wall_s, spans, *, pipelined: bool = False,
@@ -1628,8 +1663,13 @@ class FedAvgAPI:
         """Advance the rng chain and dispatch one round program — the ONE
         jit call site both the synchronous driver (run_round) and the
         pipelined drivers share, so their rng chains cannot diverge.
-        Returns the round's metrics as device arrays (no sync)."""
-        with self.tracer.span("round"):
+        The ``round`` span is the dispatch: trace, lower and
+        compile-or-load on a variant's first call (attributed to that
+        variant), the enqueue after. Returns the round's metrics as device
+        arrays (no sync)."""
+        B = jax.tree.leaves(cb.mask)[0].shape[1]
+        with self.tracer.span("round", round=round_idx), \
+                _perf.attribute_compiles(self._variant_name(B=B)):
             self.rng, rk = jax.random.split(self.rng)
             self.net, self.server_opt_state, metrics = self.round_fn(
                 rk, self.net, self.server_opt_state, cb,
@@ -1644,9 +1684,14 @@ class FedAvgAPI:
             spans_before = dict(self.tracer.rounds[-1])
             if self.telemetry.tracer is not None:
                 self.telemetry.tracer.begin_round(round_idx)
-        with self.tracer.span("pack"):
+        with self.tracer.span("pack", round=round_idx):
             ids = self._sampled_ids(round_idx)
             cb = self._pack_round(round_idx)
+            # issue the H2D here, where a span can see it, and not
+            # implicitly inside the dispatch (bit-identical: see
+            # _place_round_batch; on a mesh _pack_round has placed already)
+            with self.tracer.span("place", round=round_idx):
+                cb = self._place_round_batch(cb)
         metrics = self._dispatch_round(round_idx, ids, cb)
         metrics = self._drain_quarantine(metrics, round_idx, ids)
         if self.telemetry is not None:
@@ -1678,8 +1723,9 @@ class FedAvgAPI:
 
     # --------------------------------------------------------------- pipeline
     def _place_round_batch(self, batch):
-        """Issue the host->device transfer for a packed round batch NOW (on
-        the prefetch thread) instead of implicitly at jit dispatch. Leaves
+        """Issue the host->device transfer for a packed round batch NOW (in
+        run_round's ``place`` span, or on the prefetch thread) instead of
+        implicitly at jit dispatch. Leaves
         already on device (the mesh packer shards in _pack_round) pass
         through. Transfers are exact, so a placed batch is bit-identical to
         letting dispatch transfer it."""
@@ -1694,16 +1740,19 @@ class FedAvgAPI:
         the round batch into FRESH host buffers (every pack path allocates
         anew — donation-safe while earlier rounds are still in flight), and
         issue its device_put. Returns (ids, device batch, span dict)."""
-        t0 = time.perf_counter()
-        ids = self._sampled_ids(round_idx)
-        cb = self._pack_round(round_idx)
-        t1 = time.perf_counter()
-        cb = self._place_round_batch(cb)
-        h2d = time.perf_counter() - t1
         # the packer thread must not touch self.tracer (its per-round dict
         # belongs to the driver thread) — spans go straight to the
         # fed_span_seconds / fed_h2d_seconds histograms and ride the round
-        # record at drain time
+        # record at drain time; in a profiler trace the bare annotations
+        # show the packer thread as a host line of its own
+        t0 = time.perf_counter()
+        with TraceAnnotation("fed:prefetch_pack", round=round_idx):
+            ids = self._sampled_ids(round_idx)
+            cb = self._pack_round(round_idx)
+        t1 = time.perf_counter()
+        with TraceAnnotation("fed:h2d", round=round_idx):
+            cb = self._place_round_batch(cb)
+        h2d = time.perf_counter() - t1
         _perf.record_span("prefetch_pack", t1 - t0)
         _perf.record_h2d(h2d)
         return ids, cb, {"prefetch_pack": t1 - t0, "h2d": h2d}
@@ -1723,7 +1772,8 @@ class FedAvgAPI:
             wait = self._goodput_wait(metrics)
             wall = self._goodput_interval()
         metrics = self._drain_quarantine(metrics, round_idx, ids)
-        host = {k: np.asarray(v) for k, v in metrics.items()}
+        with self.tracer.span("fetch", round=round_idx):
+            host = {k: np.asarray(v) for k, v in metrics.items()}
         if self.telemetry is not None:
             pack_extra = self._pack_extra(round_idx)
             self.telemetry.emit_round(

@@ -549,13 +549,13 @@ def main(argv=None):
 
     import contextlib
 
+    import jax
+
     round_loop = args.algo not in ("centralized", "vfl", "split_nn")
     stack = contextlib.ExitStack()
     if args.trace_dir and not (round_loop and args.trace_rounds > 0):
         # whole-run trace: single-shot algos, or --trace_rounds 0
-        from fedml_tpu.utils.tracing import trace
-
-        stack.enter_context(trace(args.trace_dir))
+        stack.enter_context(jax.profiler.trace(args.trace_dir))
         log.info("capturing XLA trace to %s", args.trace_dir)
 
     try:
@@ -610,10 +610,8 @@ def main(argv=None):
                     log.info("resumed from round %d", start_round - 1)
             trace_ctx = None
             if args.trace_dir and args.trace_rounds > 0:
-                from fedml_tpu.utils.tracing import trace
-
                 trace_ctx = stack.enter_context(contextlib.ExitStack())
-                trace_ctx.enter_context(trace(args.trace_dir))
+                trace_ctx.enter_context(jax.profiler.trace(args.trace_dir))
                 log.info("tracing rounds %d..%d to %s", start_round,
                          start_round + args.trace_rounds - 1, args.trace_dir)
             ckptr = None  # AsyncCheckpointer, created on first save

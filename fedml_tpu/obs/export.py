@@ -6,8 +6,7 @@
   textfile-collector shape — drop it in a scrape directory);
 - ``bench_blob``: round records -> the BENCH_r*.json-compatible one-line
   summary (same keys as bench.py's ``_result``), so a telemetry run can
-  stand in for a bench run in dashboards;
-- ``profile_trace``: re-export of the jax.profiler bridge.
+  stand in for a bench run in dashboards.
 
 scripts/report.py is the CLI over these.
 """
@@ -17,7 +16,6 @@ from __future__ import annotations
 import csv
 
 from fedml_tpu.obs.metrics import MetricsRegistry
-from fedml_tpu.utils.tracing import trace as profile_trace  # noqa: F401
 
 
 def _flatten(rec: dict, prefix: str = "") -> dict:

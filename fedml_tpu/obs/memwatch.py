@@ -8,14 +8,23 @@ both visible while the run is still alive:
 - per-device stats from ``jax.local_devices()[i].memory_stats()`` — TPU
   and GPU backends report ``bytes_in_use`` / ``peak_bytes_in_use`` /
   ``bytes_limit``; the CPU backend returns ``None``, which degrades to a
-  graceful no-op (host RSS still reports);
+  graceful no-op (host RSS still reports). On the TPU runtime
+  ``peak_bytes_in_use`` counts live arrays alone; what the allocator set
+  aside for the loaded programs' temporaries is ``peak_bytes_reserved``, a
+  disjoint region ten times the size on a ResNet-56 block (0.4 GB beside
+  4.89 GB; PERF.md section 6, PR 22 and PR 25). The peak gauge is their sum;
 - host RSS from ``/proc/self/status`` (``VmRSS``), the same figure ``top``
   shows — absent on non-procfs hosts, again a graceful no-op.
 
 Gauges (process registry, scraped live via obs/httpd and dumped at close):
 
     fed_device_bytes_in_use{device}     current HBM bytes per local device
-    fed_device_peak_bytes{device}       high-water mark per local device
+    fed_device_peak_bytes{device}       high-water mark per local device:
+                                        live peak plus reserved peak
+    fed_device_peak_live_bytes{device}  its parts: ``peak_bytes_in_use``
+    fed_device_peak_reserved_bytes{device}  and ``peak_bytes_reserved``
+                                        (only where the backend reports
+                                        a reserved peak)
     fed_device_bytes_limit{device}      allocator capacity (feeds the
                                         health rule table's device_memory
                                         fraction, obs/health.py)
@@ -52,10 +61,13 @@ def host_rss_bytes() -> int | None:
 
 
 def device_memory_stats() -> dict[str, dict]:
-    """{device-label: {bytes_in_use, peak_bytes, bytes_limit}} over
-    ``jax.local_devices()``. Backends without allocator stats (CPU) return
-    None from ``memory_stats()`` and are skipped entirely — an empty dict
-    means 'nothing to report', never 'zero bytes'."""
+    """{device-label: {bytes_in_use, peak_bytes, peak_live_bytes,
+    bytes_limit}} over ``jax.local_devices()``, with
+    ``peak_reserved_bytes`` where the backend reports
+    ``peak_bytes_reserved``; ``peak_bytes`` is live peak plus reserved peak.
+    Backends without allocator stats (CPU) return None from
+    ``memory_stats()`` and are skipped entirely — an empty dict means
+    'nothing to report', never 'zero bytes'."""
     try:
         import jax
 
@@ -73,13 +85,17 @@ def device_memory_stats() -> dict[str, dict]:
             stats = None
         if not stats:
             continue
-        label = f"{d.platform}:{d.id}"
-        out[label] = {
+        live = int(stats.get("peak_bytes_in_use",
+                             stats.get("bytes_in_use", 0)))
+        entry = out[f"{d.platform}:{d.id}"] = {
             "bytes_in_use": int(stats.get("bytes_in_use", 0)),
-            "peak_bytes": int(stats.get("peak_bytes_in_use",
-                                        stats.get("bytes_in_use", 0))),
+            "peak_bytes": live,
+            "peak_live_bytes": live,
             "bytes_limit": int(stats.get("bytes_limit", 0)),
         }
+        if "peak_bytes_reserved" in stats:
+            entry["peak_reserved_bytes"] = int(stats["peak_bytes_reserved"])
+            entry["peak_bytes"] = live + entry["peak_reserved_bytes"]
     return out
 
 
@@ -114,6 +130,12 @@ class MemoryWatcher:
                                 device=label).set(st["bytes_in_use"])
             self.registry.gauge("fed_device_peak_bytes",
                                 device=label).set(st["peak_bytes"])
+            self.registry.gauge("fed_device_peak_live_bytes",
+                                device=label).set(st["peak_live_bytes"])
+            if "peak_reserved_bytes" in st:
+                self.registry.gauge(
+                    "fed_device_peak_reserved_bytes",
+                    device=label).set(st["peak_reserved_bytes"])
             if st["bytes_limit"]:
                 self.registry.gauge("fed_device_bytes_limit",
                                     device=label).set(st["bytes_limit"])

@@ -100,11 +100,53 @@ def _span_hist(name: str):
 # fns, ...) lands under the reserved ``variant="_other"`` child — which
 # also gives the families a pre-registerable zero child.
 #
-#     fed_xla_variant_compile_seconds_total{variant}   backend compile wall
+#     fed_xla_variant_compile_seconds_total{variant}   backend compile wall:
+#                                                      the compile, or on a
+#                                                      persistent-cache hit
+#                                                      the load in its place
 #     fed_xla_variant_compiles_total{variant}          compile passes
 #     fed_xla_variant_cache_hits_total{variant}        persistent-cache hits
 #     fed_xla_variant_cache_misses_total{variant}      fresh compiles
+#     fed_xla_variant_trace_seconds_total{variant}     python -> jaxpr
+#     fed_xla_variant_lower_seconds_total{variant}     jaxpr -> MLIR module
+#     fed_xla_variant_cache_retrieval_seconds_total{variant}
+#                                                      the part of the compile
+#                                                      wall a hit spent
+#                                                      reading the cache
+#
+# The engine (algorithms/fedavg.py) installs the listeners when it is built
+# and tags ``task.init`` with ``init``, every per-round dispatch and every
+# warmup lowering with its variant name. The scanned block's dispatch is
+# not tagged (its frames stay as they were: PERF.md section 6): jax hands
+# every duration event the jit function's name, and an event that no scope
+# claimed is named by it where it is one of the engine's round programs.
+# So a first call's trace, lower and compile-or-load land under the program
+# that paid them; :func:`setup_phases` is the read side.
 UNATTRIBUTED_VARIANT = "_other"
+INIT_VARIANT = "init"
+# the jit functions algorithms/fedavg.py builds its round programs as
+ROUND_PROGRAMS = frozenset({"block_fn", "sharded_block_fn", "round_fn",
+                            "robust_round_fn"})
+# family -> the key variant_compile_stats() reports it under
+_VARIANT_FAMILIES = {
+    "fed_xla_variant_compile_seconds_total": "seconds",
+    "fed_xla_variant_compiles_total": "compiles",
+    "fed_xla_variant_cache_hits_total": "cache_hits",
+    "fed_xla_variant_cache_misses_total": "cache_misses",
+    "fed_xla_variant_trace_seconds_total": "trace_seconds",
+    "fed_xla_variant_lower_seconds_total": "lower_seconds",
+    "fed_xla_variant_cache_retrieval_seconds_total":
+        "cache_retrieval_seconds",
+}
+# jax.monitoring duration event -> the per-variant seconds family it feeds
+_DURATION_FAMILIES = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        "fed_xla_variant_trace_seconds_total",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        "fed_xla_variant_lower_seconds_total",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "fed_xla_variant_cache_retrieval_seconds_total",
+}
 
 
 @lru_cache(maxsize=256)
@@ -113,8 +155,17 @@ def _variant_counter(name: str, variant: str):
     return REGISTRY.counter(name, variant=variant)  # fedlint: disable=metric-discipline
 
 
-def _compile_variant() -> str:
-    return getattr(_tls, "compile_variant", None) or UNATTRIBUTED_VARIANT
+def _compile_variant(fun_name: str = "") -> str:
+    """The variant an event belongs to: the enclosing
+    :func:`attribute_compiles` scope's, else the jit function's own name
+    (``fun_name`` as jax passes it: ``block_fn`` when tracing,
+    ``jit(block_fn)`` when lowering and compiling) where that is a round
+    program, else ``_other``."""
+    variant = getattr(_tls, "compile_variant", None)
+    if variant:
+        return variant
+    name = fun_name.removeprefix("jit(").removesuffix(")")
+    return name if name in ROUND_PROGRAMS else UNATTRIBUTED_VARIANT
 
 
 @contextlib.contextmanager
@@ -130,16 +181,14 @@ def attribute_compiles(variant: str):
 
 
 def variant_compile_stats() -> dict:
-    """{variant: {seconds, compiles, cache_hits, cache_misses}} from the
-    live registry — the compile observatory's read side (warmup reports,
-    report.py --compiles via the warmup event record, tests)."""
+    """{variant: {seconds, compiles, cache_hits, cache_misses,
+    trace_seconds, lower_seconds, cache_retrieval_seconds}} from the live
+    registry — the compile observatory's read side (warmup reports,
+    report.py --compiles via the warmup event record, setup_phases,
+    tests). A key is absent where no such event reached the variant."""
     out: dict[str, dict] = {}
-    fams = {"fed_xla_variant_compile_seconds_total": "seconds",
-            "fed_xla_variant_compiles_total": "compiles",
-            "fed_xla_variant_cache_hits_total": "cache_hits",
-            "fed_xla_variant_cache_misses_total": "cache_misses"}
     snap = REGISTRY.snapshot()
-    for fam_name, key in fams.items():
+    for fam_name, key in _VARIANT_FAMILIES.items():
         for label_s, value in (snap.get(fam_name) or {}).items():
             # snapshot() keys children as "k=v" strings (jsonable contract)
             if not label_s.startswith("variant="):
@@ -149,13 +198,33 @@ def variant_compile_stats() -> dict:
     return out
 
 
+def setup_phases() -> dict:
+    """Where set-up went, in seconds, from the spans and counters the
+    engine feeds: ``init_s`` is the ``init`` span (engine build,
+    ``task.init`` and its compiles included); ``trace_s``, ``lower_s`` and
+    ``compile_or_load_s`` are summed over the round-program variants,
+    which is every variant but ``_other`` (what no engine dispatch paid
+    for; the cache's hit, miss and retrieval events of an untagged block
+    dispatch too, which carry no function name) and ``init`` (already
+    inside ``init_s``). A jitted function
+    called while another is traced reports its own trace inside the outer
+    one's, so ``trace_s`` can overcount (PERF.md section 5 has the gap)."""
+    init = REGISTRY.histogram("fed_span_seconds", span="init").total
+    out = {"init_s": init, "trace_s": 0.0, "lower_s": 0.0,
+           "compile_or_load_s": 0.0}
+    for variant, st in variant_compile_stats().items():
+        if variant in (UNATTRIBUTED_VARIANT, INIT_VARIANT):
+            continue
+        out["trace_s"] += st.get("trace_seconds", 0.0)
+        out["lower_s"] += st.get("lower_seconds", 0.0)
+        out["compile_or_load_s"] += st.get("seconds", 0.0)
+    return out
+
+
 def ensure_compile_attr_families() -> None:
     """Pre-register the per-variant compile families at zero (under the
     reserved ``_other`` child) so a clean run's export carries them."""
-    for fam in ("fed_xla_variant_compile_seconds_total",
-                "fed_xla_variant_compiles_total",
-                "fed_xla_variant_cache_hits_total",
-                "fed_xla_variant_cache_misses_total"):
+    for fam in _VARIANT_FAMILIES:
         _variant_counter(fam, UNATTRIBUTED_VARIANT)
 
 
@@ -173,14 +242,17 @@ def _on_event(name: str, **kw) -> None:
         _counter("fed_xla_cache_requests_total").inc()
 
 
-def _on_duration(name: str, secs: float, **kw) -> None:
+def _on_duration(name: str, secs: float, fun_name: str = "", **kw) -> None:
     if name.endswith("/backend_compile_duration"):
         _counter("fed_xla_compiles_total").inc()
         _hist("fed_xla_compile_seconds").observe(secs)
-        variant = _compile_variant()
+        variant = _compile_variant(fun_name)
         _variant_counter("fed_xla_variant_compiles_total", variant).inc()
         _variant_counter("fed_xla_variant_compile_seconds_total",
                          variant).inc(secs)
+    elif name in _DURATION_FAMILIES:
+        _variant_counter(_DURATION_FAMILIES[name],
+                         _compile_variant(fun_name)).inc(secs)
 
 
 def install() -> bool:
@@ -240,7 +312,7 @@ def set_dispatch_depth(n: int) -> None:
 def record_span(name: str, seconds: float) -> None:
     """A host span observed off the engine's RoundTracer (the prefetch
     thread must not touch the tracer's per-round dict — see
-    docs/PERFORMANCE.md §Tracing caveat)."""
+    docs/PERFORMANCE.md §Caveats, Tracing)."""
     _span_hist(name).observe(seconds)
 
 

@@ -7,7 +7,7 @@ One object ties together the three obs primitives:
 - an ``EventLog`` over a rotating JSONL file (``log_dir/events.jsonl``) or
   an in-memory sink (tests);
 - the ``jax.profiler`` bridge (``profile(logdir)`` — the opt-in XLA trace,
-  reusing utils.tracing.trace);
+  ``jax.profiler.trace``);
 - optionally a ``DistributedTracer`` (``trace_dir=``/``trace=True``) — the
   cross-rank per-round trace stitcher (obs/tracing.py); ``close()`` writes
   its Chrome trace-event JSON next to the event log;
@@ -251,11 +251,12 @@ class Telemetry:
     # ------------------------------------------------------------ profiler
     def profile(self, logdir: str):
         """Opt-in jax.profiler bridge: context manager writing an XLA/TPU
-        trace (TensorBoard 'profile' plugin / Perfetto) to ``logdir`` —
-        utils.tracing.trace under the obs roof."""
-        from fedml_tpu.utils.tracing import trace
+        trace (TensorBoard 'profile' plugin / Perfetto) to ``logdir``; the
+        engine's ``RoundTracer`` spans show in it as ``fed:`` host events.
+        Wrap a handful of rounds, not a whole run."""
+        import jax
 
-        return trace(logdir)
+        return jax.profiler.trace(logdir)
 
     # ------------------------------------------------------------- teardown
     def close(self) -> None:

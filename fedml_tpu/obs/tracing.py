@@ -93,14 +93,17 @@ def _span_hist(name: str):
 class RoundTracer:
     """Per-round named span timing with aggregate statistics.
 
-    The seed-era host-side span timer (was ``utils/tracing.py``), absorbed
-    into the obs tracing path: every ``span()`` observation now also feeds
-    the process-wide ``fed_span_seconds{span=...}`` histogram (so
-    ``summary()`` totals and the Prometheus export agree — the histogram
-    counts observations, ``summary()`` aggregates per round), and an
-    optional ``sink`` (a :class:`DistributedTracer`) receives each span's
-    wall-clock interval for the stitched per-round timeline. With
-    ``sink=None`` the extra cost is one histogram observe per span.
+    One span path, three readers: every ``span()`` observation lands in
+    this tracer's per-round totals (``summary()`` / ``totals()``: what the
+    benchmark diffs into ``spans_s``), in the process-wide
+    ``fed_span_seconds{span=...}`` histogram (so the totals and the
+    Prometheus export agree; the histogram counts observations,
+    ``summary()`` aggregates per round), and in the jax profiler's trace as
+    the host event ``fed:<name>``: same clock as the device ops, real
+    start and end, parent by nesting on its thread. With no profiler
+    session the annotation is a flag test. An optional ``sink`` (a
+    :class:`DistributedTracer`) receives each span's wall-clock interval
+    for the stitched per-round timeline.
     """
 
     def __init__(self, sink: "DistributedTracer | None" = None):
@@ -108,11 +111,16 @@ class RoundTracer:
         self._sink = sink
 
     @contextlib.contextmanager
-    def span(self, name: str):
+    def span(self, name: str, **ids):
+        """Time ``name``; ``ids`` (the dispatch unit's ``round=``) become
+        stats of the profiler event."""
+        from jax.profiler import TraceAnnotation  # lazy: obs imports no jax
+
+        w0 = time.time() if self._sink is not None else 0.0
         t0 = time.perf_counter()
-        w0 = time.time()
         try:
-            yield
+            with TraceAnnotation(f"fed:{name}", **ids):
+                yield
         finally:
             dt = time.perf_counter() - t0
             cur = self.rounds[-1]

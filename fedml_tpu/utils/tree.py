@@ -39,6 +39,7 @@ def tree_unstack(tree, n):
     return [jax.tree.map(lambda x, i=i: x[i], tree) for i in range(n)]
 
 
+@jax.named_scope("fed_aggregate")
 def tree_weighted_mean(stacked, weights):
     """Weighted mean over the leading axis of a stacked pytree.
 

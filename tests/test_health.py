@@ -285,6 +285,44 @@ def test_memwatch_gauges_and_mem_block_graceful_on_cpu():
     w.stop()  # never started: stop() is a harmless no-op
 
 
+class _StubDevice:
+    platform = "tpu"
+
+    def __init__(self, dev_id, stats):
+        self.id, self._stats = dev_id, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_memwatch_peak_is_live_peak_plus_reserved_peak(monkeypatch):
+    """On the TPU runtime ``peak_bytes_in_use`` counts live arrays alone and
+    the programs' temporaries sit under ``peak_bytes_reserved`` (the numbers
+    are the ResNet-56 block's, PERF.md): the peak gauge is the sum, with
+    both parts exported; a backend without the field keeps the live peak."""
+    import jax
+
+    devs = [_StubDevice(0, {"bytes_in_use": 300_000_000,
+                            "peak_bytes_in_use": 400_000_000,
+                            "peak_bytes_reserved": 4_890_000_000,
+                            "bytes_limit": 17_000_000_000}),
+            _StubDevice(1, {"bytes_in_use": 5, "peak_bytes_in_use": 7,
+                            "bytes_limit": 100}),
+            _StubDevice(2, None)]
+    monkeypatch.setattr(jax, "local_devices", lambda: devs)
+    reg = MetricsRegistry()
+    block = MemoryWatcher(registry=reg).sample()
+    snap = reg.snapshot()
+    assert snap["fed_device_peak_bytes"] == {
+        "device=tpu:0": 5_290_000_000, "device=tpu:1": 7}
+    assert snap["fed_device_peak_live_bytes"] == {
+        "device=tpu:0": 400_000_000, "device=tpu:1": 7}
+    assert snap["fed_device_peak_reserved_bytes"] == {
+        "device=tpu:0": 4_890_000_000}
+    assert block["device_peak_bytes"] == 5_290_000_000
+    assert block["device_bytes_in_use"] == 300_000_005
+
+
 def test_telemetry_memwatch_attaches_mem_block():
     tel = Telemetry(registry=MetricsRegistry(), memwatch=True)
     rec = tel.emit_round(0, metrics={"loss_sum": 1.0})
