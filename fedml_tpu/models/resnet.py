@@ -1,8 +1,14 @@
 """CIFAR ResNets — resnet56/resnet110 (reference: fedml_api/model/cv/resnet.py:1-268).
 
 The reference uses the classic 3-stage basic-block CIFAR ResNet (He et al.)
-with BatchNorm. TPU notes: NHWC layout, bfloat16-friendly conv widths
-(16/32/64 channels), BatchNorm running stats live in the 'batch_stats'
+with BatchNorm. TPU notes: NHWC layout; the conv widths (16/32/64 channels)
+fill an eighth to a half of the MXU's 128 output columns, so every
+``nn.Conv`` here is handed ``ops/packed_conv.conv_general_dilated``, which on
+a TPU packs 8, 4 or 2 adjacent output pixels into one matmul row in the
+kernel gradient of the stride-1 3x3 convolutions (53 of ResNet-56's 57; at
+64 channels in the forward pass too; same parameters, same arithmetic) and
+is ``lax.conv_general_dilated`` for the rest and everywhere
+else. BatchNorm running stats live in the 'batch_stats'
 collection and are federated-averaged with the params (the reference
 averages the full state_dict including BN buffers, FedAVGAggregator.py:72-80).
 ``norm='group'`` swaps in GroupNorm — BN-free variant for non-IID robustness.
@@ -21,6 +27,11 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax.numpy as jnp
 
+from fedml_tpu.ops.packed_conv import conv_general_dilated
+
+# every convolution of this file: nn.Conv with the packed-or-plain dispatch
+Conv = partial(nn.Conv, conv_general_dilated=conv_general_dilated)
+
 
 class BasicBlock(nn.Module):
     filters: int
@@ -31,16 +42,16 @@ class BasicBlock(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False):
         residual = x
-        y = nn.Conv(self.filters, (3, 3), self.strides, padding="SAME",
-                    use_bias=False, dtype=self.dtype)(x)
+        y = Conv(self.filters, (3, 3), self.strides, padding="SAME",
+                 use_bias=False, dtype=self.dtype)(x)
         y = self.norm(use_running_average=not train)(y)
         y = nn.relu(y)
-        y = nn.Conv(self.filters, (3, 3), padding="SAME", use_bias=False,
-                    dtype=self.dtype)(y)
+        y = Conv(self.filters, (3, 3), padding="SAME", use_bias=False,
+                 dtype=self.dtype)(y)
         y = self.norm(use_running_average=not train)(y)
         if residual.shape != y.shape:
-            residual = nn.Conv(self.filters, (1, 1), self.strides,
-                               use_bias=False, dtype=self.dtype)(residual)
+            residual = Conv(self.filters, (1, 1), self.strides,
+                            use_bias=False, dtype=self.dtype)(residual)
             residual = self.norm(use_running_average=not train)(residual)
         return nn.relu(y + residual)
 
@@ -70,8 +81,8 @@ class ResNetCIFAR(nn.Module):
         else:
             norm = partial(_GN, num_groups=8, dtype=dt)
 
-        y = nn.Conv(16, (3, 3), padding="SAME",
-                    use_bias=(self.norm_type == "none"), dtype=dt)(x)
+        y = Conv(16, (3, 3), padding="SAME",
+                 use_bias=(self.norm_type == "none"), dtype=dt)(x)
         if self.norm_type == "batch":
             y = norm(use_running_average=not train)(y)
         elif self.norm_type == "group":
@@ -121,18 +132,18 @@ class _FixupBasicBlock(nn.Module):
         cd = self.dtype or x.dtype
         residual = x
         b1 = self.param("bias1", nn.initializers.zeros, (1,))
-        y = nn.Conv(self.filters, (3, 3), self.strides, padding="SAME",
-                    use_bias=True, dtype=self.dtype)(x + b1.astype(cd))
+        y = Conv(self.filters, (3, 3), self.strides, padding="SAME",
+                 use_bias=True, dtype=self.dtype)(x + b1.astype(cd))
         y = nn.relu(y)
         b2 = self.param("bias2", nn.initializers.zeros, (1,))
-        y = nn.Conv(self.filters, (3, 3), padding="SAME", use_bias=True,
-                    kernel_init=nn.initializers.zeros,
-                    dtype=self.dtype)(y + b2.astype(cd))
+        y = Conv(self.filters, (3, 3), padding="SAME", use_bias=True,
+                 kernel_init=nn.initializers.zeros,
+                 dtype=self.dtype)(y + b2.astype(cd))
         scale = self.param("scale", nn.initializers.ones, (1,))
         y = y * scale.astype(cd)
         if residual.shape != y.shape:
-            residual = nn.Conv(self.filters, (1, 1), self.strides,
-                               use_bias=True, dtype=self.dtype)(residual)
+            residual = Conv(self.filters, (1, 1), self.strides,
+                            use_bias=True, dtype=self.dtype)(residual)
         return nn.relu(y + residual)
 
 
@@ -145,15 +156,15 @@ class _GNBasicBlock(nn.Module):
     def __call__(self, x, train: bool = False):
         gn = lambda c: nn.GroupNorm(num_groups=min(8, c), dtype=self.dtype)
         residual = x
-        y = nn.Conv(self.filters, (3, 3), self.strides, padding="SAME",
-                    use_bias=False, dtype=self.dtype)(x)
+        y = Conv(self.filters, (3, 3), self.strides, padding="SAME",
+                 use_bias=False, dtype=self.dtype)(x)
         y = gn(self.filters)(y)
         y = nn.relu(y)
-        y = nn.Conv(self.filters, (3, 3), padding="SAME", use_bias=False,
-                    dtype=self.dtype)(y)
+        y = Conv(self.filters, (3, 3), padding="SAME", use_bias=False,
+                 dtype=self.dtype)(y)
         y = gn(self.filters)(y)
         if residual.shape != y.shape:
-            residual = nn.Conv(self.filters, (1, 1), self.strides,
-                               use_bias=False, dtype=self.dtype)(residual)
+            residual = Conv(self.filters, (1, 1), self.strides,
+                            use_bias=False, dtype=self.dtype)(residual)
             residual = gn(self.filters)(residual)
         return nn.relu(y + residual)

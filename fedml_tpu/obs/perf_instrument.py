@@ -228,6 +228,37 @@ def ensure_compile_attr_families() -> None:
         _variant_counter(fam, UNATTRIBUTED_VARIANT)
 
 
+# ------------------------------------------------ convolution call sites
+# docs/PERFORMANCE.md §Width-packed convolutions. Fed at TRACE time by
+# ops/packed_conv.conv_general_dilated, one inc for each nn.Conv call it is
+# handed while a program is traced (so a model traced twice counts twice:
+# read the two paths as a share of one another, not as absolutes):
+#
+#     fed_conv_sites_total{path,p}      path=packed: the kernel gradient
+#                                       (by pack_factor's rule the forward
+#                                       pass too) is a width-packed
+#                                       convolution at pack factor p;
+#                                       path=plain (p=1): handed on to
+#                                       lax.conv_general_dilated
+@lru_cache(maxsize=16)
+def _conv_sites(p: int):
+    return REGISTRY.counter("fed_conv_sites_total",
+                            path="packed" if p > 1 else "plain", p=p)
+
+
+def record_conv_site(p: int) -> None:
+    _conv_sites(p).inc()
+
+
+def conv_sites() -> dict:
+    """{"packed": n, "plain": n}: convolution call sites traced so far, by
+    the path that served them."""
+    fam = REGISTRY.snapshot().get("fed_conv_sites_total") or {}
+    return {path: sum(v for labels, v in fam.items()
+                      if f"path={path}" in labels.split(","))
+            for path in ("packed", "plain")}
+
+
 # ------------------------------------------------------ compile accounting
 def _on_event(name: str, **kw) -> None:
     if name == "/jax/compilation_cache/cache_hits":

@@ -1,0 +1,99 @@
+"""By hand: the global model after a benchmark cell's first dispatch units,
+leaf by leaf, to set two checkouts side by side on one seed. What the cell's
+``correct`` cannot see (PERF.md section 7, item 1) shows here: the limits
+compare norms of a leaf's change against the reference's, this compares the
+change itself against another checkout's.
+
+    # on the chip, from the root of each checkout (the program and the
+    # benchmark are the working directory's, whichever file this is):
+    python3 <path to>/chip_model_leaves.py dump --workload <cell> --seed <n> \\
+        --out chiprun_out/leaves_<tag>.npz
+    # anywhere:
+    python3 scripts/chip_model_leaves.py compare <a.npz> <b.npz>
+
+``compare`` prints one JSON line: ``compared`` (the cell's own ``dparam`` and
+``dparam_med`` with b in the reference's place), ``leaf_rel`` (for each leaf
+the norm of a's model minus b's over the norm of b's change from the shared
+weights: worst leaf, its name, the median leaf), the five losses' relative
+gaps, and whether the two started from the same weights.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+import numpy as np
+
+sys.path.insert(0, os.getcwd())
+
+
+def dump(workload: str, seed: int, out: str) -> None:
+    from benchmark import cells, check, run
+
+    cell = cells.load_cell(cells.load_benchmark(), workload)
+    run.find_chips(cell["chips"])
+    data, params, init = run.prepare(cell, seed)
+    _, prog = run.first_units(cell, data, params)
+    # float32 as the program holds them (check._leaves widens to float64)
+    arrays = {"losses": np.asarray(prog["losses"], np.float64)}
+    arrays.update({f"init{k}": v.astype(np.float32)
+                   for k, v in check._leaves(init)})
+    arrays.update({f"model{k}": v.astype(np.float32) for k, v in
+                   check._leaves(prog["models"][max(prog["models"])])})
+    os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
+    np.savez(out, **arrays)
+    print(json.dumps({"out": out, "losses": prog["losses"]}), flush=True)
+
+
+def compare(path_a: str, path_b: str) -> dict:
+    from benchmark import check
+
+    a, b = np.load(path_a), np.load(path_b)
+
+    def tree(z, prefix):
+        return {k[len(prefix):]: z[k] for k in z.files
+                if k.startswith(prefix)}
+
+    init, model_a, model_b = (
+        {k: v.astype(np.float64) for k, v in t.items()}
+        for t in (tree(b, "init"), tree(a, "model"), tree(b, "model")))
+    worst, med = check.leaf_gaps(model_a, model_b, init)
+    rel = {k: float(np.linalg.norm(model_a[k] - model_b[k])
+                    / max(np.linalg.norm(model_b[k] - init[k]), 1e-30))
+           for k in model_b}
+    name = max(rel, key=rel.get)
+    return {
+        "same_init": all(np.array_equal(v, a[f"init{k}"])
+                         for k, v in init.items()),
+        "compared": {"dparam": worst, "dparam_med": med},
+        "leaf_rel": {"worst": rel[name], "worst_leaf": name,
+                     "median": float(np.median(list(rel.values()))),
+                     "leaves": len(rel)},
+        "loss_rel": [float(abs(x - y) / abs(y))
+                     for x, y in zip(a["losses"], b["losses"])],
+    }
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    sub = ap.add_subparsers(dest="cmd", required=True)
+    d = sub.add_parser("dump")
+    d.add_argument("--workload", required=True)
+    d.add_argument("--seed", type=int, required=True)
+    d.add_argument("--out", required=True)
+    c = sub.add_parser("compare")
+    c.add_argument("a")
+    c.add_argument("b")
+    args = ap.parse_args()
+    if args.cmd == "dump":
+        dump(args.workload, args.seed, args.out)
+    else:
+        print(json.dumps(compare(args.a, args.b)))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -131,15 +131,22 @@ def test_femnist_cnn_round_step_compiles_for_v5e(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes > 0
 
 
-def test_resnet56_local_fit_compiles_for_v5e(one_chip, no_persistent_cache):
+@pytest.mark.parametrize("path", ["plain", "packed"])
+def test_resnet56_local_fit_compiles_for_v5e(one_chip, no_persistent_cache,
+                                             monkeypatch, path):
     """One silo's local fit of the cross-silo cell: ResNet-56, group norm,
-    CIFAR-10 shapes, 8 batches of 64 (bench_scaling's cifar_resnet56)."""
+    CIFAR-10 shapes, 8 batches of 64 (bench_scaling's cifar_resnet56).
+    ``ops/packed_conv.py`` asks ``jax.default_backend()`` which path serves
+    the 3x3 convolutions; told it is a TPU, the width-packed one is what the
+    chip's compiler gets, as on the chip."""
     import optax
 
     from fedml_tpu.core.local import LocalSpec, make_local_update
     from fedml_tpu.core.tasks import classification_task
     from fedml_tpu.models.resnet import ResNetCIFAR
 
+    if path == "packed":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     task = classification_task(ResNetCIFAR(depth=56, num_classes=10,
                                            norm_type="group"))
     x = jnp.zeros((8, 64, 32, 32, 3), jnp.uint8)
@@ -149,3 +156,4 @@ def test_resnet56_local_fit_compiles_for_v5e(one_chip, no_persistent_cache):
             jnp.ones((8, 64), jnp.float32))
     compiled = jax.jit(fit).lower(*_on_chip(args, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+    assert ("fed_conv_packed" in compiled.as_text()) == (path == "packed")
