@@ -234,20 +234,25 @@ def ensure_compile_attr_families() -> None:
 # handed while a program is traced (so a model traced twice counts twice:
 # read the two paths as a share of one another, not as absolutes):
 #
-#     fed_conv_sites_total{path,p}      path=packed: the kernel gradient
+#     fed_conv_sites_total{path,p,lays_out}
+#                                       path=packed: the kernel gradient
 #                                       (by pack_factor's rule the forward
 #                                       pass too) is a width-packed
-#                                       convolution at pack factor p;
-#                                       path=plain (p=1): handed on to
-#                                       lax.conv_general_dilated
+#                                       convolution at pack factor p, for
+#                                       which lays_out=x|dy, the saved input
+#                                       or the incoming gradient, is laid
+#                                       out again (grad_lays_out);
+#                                       path=plain (p=1, lays_out=none):
+#                                       handed on to lax.conv_general_dilated
 @lru_cache(maxsize=16)
-def _conv_sites(p: int):
+def _conv_sites(p: int, lays_out: str):
     return REGISTRY.counter("fed_conv_sites_total",
-                            path="packed" if p > 1 else "plain", p=p)
+                            path="packed" if p > 1 else "plain", p=p,
+                            lays_out=lays_out)
 
 
-def record_conv_site(p: int) -> None:
-    _conv_sites(p).inc()
+def record_conv_site(p: int, lays_out: str) -> None:
+    _conv_sites(p, lays_out).inc()
 
 
 def conv_sites() -> dict:

@@ -25,6 +25,12 @@ already runs well at 16 channels, gain only where the zeros are few
 (P = 2: 4/3 of the products). ``pack_factor`` is that rule and the only
 place that holds it.
 
+The packed kernel gradient needs one of its two operands as
+``[.., W/P, P*C]``, which under ``vmap`` is a copy and no bitcast. In the
+ResNet's stages 1 and 2 that operand is the saved input and not ``dy``
+(``grad_lays_out``), so that the backward of whatever wrote ``dy`` stays
+fused into the gradient convolutions (PERF.md section 6, PR 30).
+
 ``conv_general_dilated`` has ``lax.conv_general_dilated``'s signature and is
 handed to ``nn.Conv(conv_general_dilated=...)``; calls the rule does not
 pack go to ``lax.conv_general_dilated`` as they came. The call forwards the
@@ -69,6 +75,32 @@ def pack_factor(kernel_shape, strides, width: int, platform: str,
     return p if grad or (p == 2 and full) else 1
 
 
+def grad_lays_out(kernel_shape, p: int, p_grad: int) -> str:
+    """The operand that a site's kernel gradient lays out again as
+    ``[.., W/P, P*C]``: ``"none"`` where it is not packed, else ``"x"``, the
+    saved input, where ``Cin == Cout`` and the forward pass is plain
+    (``p == 1``), and ``"dy"`` elsewhere.
+
+    The kernel gradient is symmetric in its operands::
+
+        dW[kh,kw,ci,co] = sum x[b,h+kh-1,w+kw-1,ci] * dy[b,h,w,co]
+                        = G[2-kh,2-kw,co,ci]
+
+    with ``G`` the kernel gradient of ``conv(dy, K)``, ``K`` of shape
+    ``[3,3,Cout,Cin]``, at the cotangent ``x``. Taken so, the packed call
+    reshapes ``x``, which is in memory anyway, and fills ``P*Cin`` columns,
+    and ``dy`` reaches both gradient convolutions as the layer above wrote
+    it, so XLA fuses that layer's backward (a norm's) into them as it does
+    for a plain convolution; with ``dy`` reshaped that backward is a pass
+    of its own. ``p_grad`` is sized by ``Cout``, so the stem (3 to 16
+    channels: 24 columns from ``x``) keeps ``dy``; and where the forward
+    pass and the input gradient are packed too (64 channels) the v5e ran
+    the ``dy`` form faster (PERF.md section 6, PR 30)."""
+    if p_grad == 1:
+        return "none"
+    return "x" if kernel_shape[2] == kernel_shape[3] and p == 1 else "dy"
+
+
 def pack_kernel(w, p: int):
     """``[3, 3, Cin, Cout]`` to ``[3, P+2, Cin, P*Cout]``: output pixel q of
     a pack sees the kernel shifted q columns to the right."""
@@ -109,16 +141,21 @@ def _bwd(p, p_grad, precision, res, dy):
     """The input gradient of a 3x3 stride-1 convolution is the same kind of
     convolution of ``dy`` with the flipped, transposed kernel, so it goes
     through the same call under the same rule (autodiff's form of a packed
-    call dilates ``dy`` by P instead); the kernel gradient is autodiff's of
-    the call packed by ``p_grad``, which contracts over the pixels with
-    ``p_grad * Cout`` columns filled and reaches ``w`` through the
-    pad-and-concatenate."""
+    call dilates ``dy`` by P instead). The kernel gradient is autodiff's of
+    a call packed by ``p_grad``, which contracts over the pixels with 128
+    columns filled and reaches the kernel through the pad-and-concatenate;
+    ``grad_lays_out`` says which of the two calls it is taken of, and so
+    which operand is reshaped to ``[.., W/P, P*C]`` for it."""
     x, w = res
     wt = jnp.flip(w, (0, 1)).swapaxes(2, 3)
     pt = pack_factor(wt.shape, (1, 1), dy.shape[2], "tpu")
     with jax.named_scope(SCOPE):
         dx = _packed(dy, wt, pt, precision)
-        dw, = jax.vjp(lambda k: _packed(x, k, p_grad, precision), w)[1](dy)
+        if grad_lays_out(w.shape, p, p_grad) == "x":
+            g, = jax.vjp(lambda k: _packed(dy, k, p_grad, precision), wt)[1](x)
+            dw = jnp.flip(g, (0, 1)).swapaxes(2, 3)
+        else:
+            dw, = jax.vjp(lambda k: _packed(x, k, p_grad, precision), w)[1](dy)
     return dx, dw
 
 
@@ -145,7 +182,8 @@ def conv_general_dilated(lhs, rhs, window_strides, padding,
         p = pack_factor(rhs.shape, window_strides, lhs.shape[2], platform)
         p_grad = pack_factor(rhs.shape, window_strides, lhs.shape[2],
                              platform, grad=True)
-    perf_instrument.record_conv_site(p_grad)
+    perf_instrument.record_conv_site(
+        p_grad, grad_lays_out(rhs.shape, p, p_grad))
     if p_grad == 1:
         return lax.conv_general_dilated(
             lhs, rhs, window_strides, padding, lhs_dilation=lhs_dilation,

@@ -12,13 +12,20 @@ client, float32, matmul precision ``highest``):
                 gradient alone: ``plain`` (``lax.conv_general_dilated``),
                 ``packed`` (``ops/packed_conv.py``), and for dx also
                 ``autodiff`` (the packed call's own transpose, which
-                dilates ``dy`` by P)
+                dilates ``dy`` by P); ``dw_packed`` lays ``dy`` out again,
+                ``dw_swapped`` the saved input ``x`` (``grad_lays_out``)
   chain         value and gradients of conv-relu-conv, so that what the
                 reshape between two packed calls costs shows; ``gradonly``
                 packs the kernel gradient alone
-  gap           max |packed - plain| / max |plain| of output and both
-                gradients at ``highest``, and of the same packed call at the
-                TPU's default precision (one bf16 pass)
+  normchain     what the model runs: value and three gradients of
+                conv - group norm - relu - conv - group norm, so that the
+                norm's backward is what hands each convolution its ``dy``;
+                ``plain``, and the rule's packing with the kernel gradient
+                in each form, ``x`` and ``dy``
+  gap           max |packed - plain| / max |plain| of output, input gradient
+                and the kernel gradient in both forms at ``highest``, and of
+                the same packed call at the TPU's default precision (one
+                bf16 pass)
 
 ``--pack`` times the packed programs at each of the given pack factors in
 place of the rule's (how the rule was narrowed: PERF.md section 6, PR 27);
@@ -52,10 +59,27 @@ STAGES = {"stem": (3, 16, 32), "s1": (16, 16, 32), "s2": (32, 32, 16),
 TOP = 12
 
 
+def forced(lays_out: str, fn):
+    """``fn``, traced with every packed kernel gradient in the form that
+    lays ``lays_out`` out again, whatever the rule says of the shape."""
+    from fedml_tpu.ops import packed_conv as pc
+
+    def traced(*args):
+        rule = pc.grad_lays_out
+        pc.grad_lays_out = lambda kernel_shape, p, p_grad: lays_out
+        try:
+            return fn(*args)
+        finally:
+            pc.grad_lays_out = rule
+    return traced
+
+
 def programs(cin: int, cout: int, p: int):
     """{name: function} for one shape at pack factor ``p``, each of one
     client's arrays (``_args`` says which)."""
+    import flax.linen as nn
     import jax
+    import jax.numpy as jnp
     from jax import lax
     from fedml_tpu.ops import packed_conv as pc
 
@@ -83,29 +107,48 @@ def programs(cin: int, cout: int, p: int):
             return (fn(jax.nn.relu(fn(x, w)), w2) * dy).sum()
         return jax.value_and_grad(loss, (0, 1, 2))
 
+    def normchain_of(fn):
+        norm = nn.GroupNorm(num_groups=min(8, cout))
+        affine = {"params": {"scale": jnp.full((cout,), 0.5),
+                             "bias": jnp.full((cout,), 0.1)}}
+
+        def loss(x, w, w2, dy):
+            y = jax.nn.relu(norm.apply(affine, fn(x, w)))
+            return (norm.apply(affine, fn(y, w2)) * dy).sum()
+        return jax.value_and_grad(loss, (0, 1, 2))
+
+    def by_rule(x, w):
+        return pc.packed_conv3x3(
+            x, w, pc.pack_factor(w.shape, (1, 1), x.shape[2], "tpu"), p, None)
+
     out = {"fwd_plain": plain, "fwd_packed": packed,
-           "dw_plain": dw_of(plain), "dw_packed": dw_of(raw)}
+           "dw_plain": dw_of(plain), "dw_packed": dw_of(raw),
+           "dw_swapped": forced("x", dw_of(grad_only))}
     if cin == cout:
         out.update({"dx_plain": dx_of(plain), "dx_autodiff": dx_of(raw),
                     "dx_packed": dx_of(packed),
                     "chain_plain": chain_of(plain),
                     "chain_autodiff": chain_of(raw),
                     "chain_packed": chain_of(packed),
-                    "chain_gradonly": chain_of(grad_only)})
+                    "chain_gradonly": chain_of(grad_only),
+                    "normchain_plain": normchain_of(plain),
+                    "normchain_x": forced("x", normchain_of(by_rule)),
+                    "normchain_dy": forced("dy", normchain_of(by_rule))})
     return out
 
 
 def _args(name: str, x, w, w2, dy):
     if name.startswith("fwd"):
         return x, w
-    if name.startswith("chain"):
+    if name.startswith(("chain", "normchain")):
         return x, w, w2, dy
     return x, w, dy
 
 
 def gaps(p, x, w, dy):
     """Max relative gap of the packed call against the plain one at
-    ``highest``, and of the packed call at default precision."""
+    ``highest``, and of the packed call at default precision; the kernel
+    gradient in both forms (``dw_x``, ``dw_dy``)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -123,9 +166,13 @@ def gaps(p, x, w, dy):
     out = {}
     for label, prec in (("highest", lax.Precision.HIGHEST),
                         ("default", lax.Precision.DEFAULT)):
-        got = all3(lambda x, w: pc.packed_conv3x3(x, w, p, p, prec))
-        out[label] = {k: float(jnp.abs(g - r).max() / jnp.abs(r).max())
-                      for k, g, r in zip(("y", "dx", "dw"), got, ref)}
+        out[label] = {}
+        for lays_out in ("x", "dy"):
+            got = forced(lays_out, all3)(
+                lambda x, w: pc.packed_conv3x3(x, w, p, p, prec))
+            out[label].update(
+                {k: float(jnp.abs(g - r).max() / jnp.abs(r).max())
+                 for k, g, r in zip(("y", "dx", f"dw_{lays_out}"), got, ref)})
     return out
 
 

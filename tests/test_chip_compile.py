@@ -13,6 +13,7 @@ an entry written for a described chip cannot be read back without one.
 """
 
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -157,3 +158,50 @@ def test_resnet56_local_fit_compiles_for_v5e(one_chip, no_persistent_cache,
     compiled = jax.jit(fit).lower(*_on_chip(args, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
     assert ("fed_conv_packed" in compiled.as_text()) == (path == "packed")
+
+
+@pytest.mark.parametrize("lays_out,fused", [("x", True), ("dy", False)])
+def test_norm_backward_fuses_into_packed_gradient_convs_for_v5e(
+        one_chip, no_persistent_cache, monkeypatch, lays_out, fused):
+    """Two group-norm blocks at 16 channels with their gradients, ``vmap``
+    over 10 silos of 64 images at ``highest``, on the TPU path. With the
+    saved input laid out for the packed kernel gradient (the rule's ``x``)
+    no top-level loop fusion named ``GroupNorm_k/add_any`` writes a whole
+    activation: the norm's backward sits in the gradient convolutions.
+    With ``dy`` laid out instead (forced here; the form before PR 30) each
+    of the four sites has one, which is what this test looks for."""
+    import flax.linen as nn
+
+    from fedml_tpu.models.resnet import _GNBasicBlock
+    from fedml_tpu.ops import packed_conv as pc
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pc.grad_lays_out((3, 3, 16, 16), 1, 8) == "x"
+    monkeypatch.setattr(pc, "grad_lays_out", lambda shape, p, p_grad: lays_out)
+
+    class TwoBlocks(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return _GNBasicBlock(16)(_GNBasicBlock(16)(x))
+
+    model = TwoBlocks()
+    x = jnp.zeros((64, 32, 32, 16))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)
+
+    def loss(params, x):
+        return (model.apply(params, x) ** 2).sum()
+
+    silos = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((10,) + a.shape, a.dtype,
+                                       sharding=one_chip), (params, x))
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(jax.vmap(jax.value_and_grad(loss, (0, 1)))).lower(
+            *silos).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert entry.count("fed_conv_packed/transpose(jvp(jit(_packed)))"
+                       "/conv_general_dilated") >= 4
+    unfused = [line for line in entry.splitlines()
+               if "kind=kLoop" in line
+               and re.search(r"= f32\[10,64,32,32,16\]", line)
+               and re.search(r'op_name="[^"]*/GroupNorm_\d+/add_any"', line)]
+    assert (len(unfused) == 0) if fused else (len(unfused) == 4), unfused

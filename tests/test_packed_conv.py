@@ -1,8 +1,10 @@
 """The width-packed 3x3 convolution (ops/packed_conv.py) is the plain one:
 output and both gradients to float32 rounding, alone and under ``vmap`` over
-ten kernels; ``pack_factor`` is the whole rule; the CIFAR ResNet keeps its
-parameter tree and, with the packed path forced through ``nn.Conv``'s
-``conv_general_dilated=`` hook, its logits and gradient.
+ten kernels, with the kernel gradient taken either way round (the saved
+input or the incoming gradient laid out again); ``pack_factor`` and
+``grad_lays_out`` are the whole rule; the CIFAR ResNet keeps its parameter
+tree and, with the packed path forced through ``nn.Conv``'s
+``conv_general_dilated=`` hook, its logits and gradient under each norm.
 
 The rule picks the plain call on a CPU, so the cases call the packed
 function directly and the model test tells the dispatch it is on a TPU."""
@@ -15,9 +17,12 @@ from jax import lax
 
 from fedml_tpu.models.resnet import ResNetCIFAR
 from fedml_tpu.obs import perf_instrument
+from fedml_tpu.obs.metrics import REGISTRY
 from fedml_tpu.ops import packed_conv as pc
 
-SHAPES = [(3, 16, 32, 8), (16, 16, 32, 8), (32, 32, 16, 4), (64, 64, 8, 2)]
+# Cin, Cout, W, the kernel gradient's P, the operand the rule lays out for it
+SHAPES = [(3, 16, 32, 8, "dy"), (16, 16, 32, 8, "x"), (32, 32, 16, 4, "x"),
+          (64, 64, 8, 2, "dy")]
 TOL = 5e-6
 
 
@@ -36,14 +41,38 @@ def out_and_grads(fn, x, w, dy):
     return (y, *vjp(dy))
 
 
+def sites_by(label: str) -> dict:
+    """``fed_conv_sites_total`` summed by one label's values."""
+    out = {}
+    fam = REGISTRY.snapshot().get("fed_conv_sites_total") or {}
+    for labels, v in fam.items():
+        value = dict(kv.split("=") for kv in labels.split(","))[label]
+        out[value] = out.get(value, 0) + v
+    return out
+
+
+def gained(after: dict, before: dict) -> dict:
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
+
+
+@pytest.mark.parametrize("lays_out", ["x", "dy"])
 @pytest.mark.parametrize("forward", [True, False], ids=["all", "grad_only"])
 @pytest.mark.parametrize("vmapped", [False, True], ids=["alone", "vmap10"])
-@pytest.mark.parametrize("cin,cout,width,p", SHAPES)
-def test_packed_equals_plain(cin, cout, width, p, vmapped, forward):
+@pytest.mark.parametrize("cin,cout,width,p,rule", SHAPES)
+def test_packed_equals_plain(monkeypatch, cin, cout, width, p, rule, vmapped,
+                             forward, lays_out):
     """``all``: forward, input gradient and kernel gradient packed by P;
-    ``grad_only``: the kernel gradient alone, as the rule has it for P > 2."""
-    assert pc.pack_factor((3, 3, cin, cout), (1, 1), width, "tpu",
-                          grad=True) == p
+    ``grad_only``: the kernel gradient alone, as the rule has it for P > 2.
+    ``x``: the kernel gradient as that of ``conv(dy, K)`` at the cotangent
+    ``x``, flipped and transposed; ``dy``: autodiff's of the packed call.
+    Each shape runs both, the one the rule does not pick there too (the
+    stem's ``x`` form is the only one whose transposes change a shape)."""
+    shape = (3, 3, cin, cout)
+    assert pc.pack_factor(shape, (1, 1), width, "tpu", grad=True) == p
+    assert pc.grad_lays_out(
+        shape, pc.pack_factor(shape, (1, 1), width, "tpu"), p) == rule
+    monkeypatch.setattr(pc, "grad_lays_out", lambda shape, p, p_grad: lays_out)
     lead = (10,) if vmapped else ()
     k = jax.random.split(jax.random.PRNGKey(cin + width), 3)
     x = jax.random.normal(k[0], lead + (2, width, width, cin))
@@ -96,6 +125,52 @@ def test_pack_factor_is_the_rule(kernel, strides, width, platform, want):
                            grad=True)) == want
 
 
+@pytest.mark.parametrize("kernel,p,p_grad,want", [
+    ((3, 3, 16, 16), 1, 8, "x"),
+    ((3, 3, 32, 32), 1, 4, "x"),
+    ((3, 3, 64, 64), 2, 2, "dy"),      # forward pass packed too
+    ((3, 3, 64, 64), 1, 2, "x"),
+    ((3, 3, 3, 16), 1, 8, "dy"),       # the stem: 24 columns from x
+    ((3, 3, 16, 32), 1, 4, "dy"),
+    ((3, 3, 16, 16), 1, 1, "none"),    # not packed: neither
+    ((1, 1, 16, 32), 1, 1, "none"),
+])
+def test_grad_lays_out_is_the_rule(kernel, p, p_grad, want):
+    assert pc.grad_lays_out(kernel, p, p_grad) == want
+
+
+def test_swapped_kernel_gradient_reshapes_x_and_reads_dy_as_it_came(
+        monkeypatch):
+    """The operands of the backward pass's convolutions, at 16 to 32
+    channels so that the shapes tell ``x`` from ``dy``: in the ``x`` form
+    the kernel gradient's are ``dy`` as it came and ``x`` as
+    ``[B, H, W/P, P*Cin]``, and no convolution sees a reshaped ``dy``."""
+    x, dy = jnp.ones((2, 16, 16, 16)), jnp.ones((2, 16, 16, 32))
+    w = jnp.ones((3, 3, 16, 32))
+
+    def conv_operands(lays_out):
+        monkeypatch.setattr(pc, "grad_lays_out",
+                            lambda shape, p, p_grad: lays_out)
+        found = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "conv_general_dilated":
+                    found.append({v.aval.shape for v in eqn.invars})
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jax.make_jaxpr(
+            lambda dy: pc._bwd(1, 4, None, (x, w), dy))(dy).jaxpr)
+        return found
+
+    # (jax.vjp's own forward call is traced too, and dead)
+    packed_dy, packed_x = (2, 16, 4, 128), (2, 16, 4, 64)
+    assert {x.shape, packed_dy} in conv_operands("dy")
+    swapped = conv_operands("x")
+    assert {dy.shape, packed_x} in swapped
+    assert not any(packed_dy in operands for operands in swapped)
+
+
 def test_dispatch_hands_everything_else_on(monkeypatch):
     """Dilated, grouped, VALID-padded or NCHW calls reach
     ``lax.conv_general_dilated`` as they came, on a TPU too."""
@@ -103,6 +178,7 @@ def test_dispatch_hands_everything_else_on(monkeypatch):
     x = jnp.ones((2, 8, 8, 16))
     w = jnp.ones((3, 3, 16, 16))
     before = perf_instrument.conv_sites()
+    laid_out = sites_by("lays_out")
     for kw in ({"rhs_dilation": (2, 2)},
                {"lhs_dilation": (2, 2), "padding": ((1, 1), (1, 1))},
                {"padding": "VALID"},
@@ -123,6 +199,12 @@ def test_dispatch_hands_everything_else_on(monkeypatch):
                                 dimension_numbers=pc._NHWC)
     assert perf_instrument.conv_sites()["packed"] - after["packed"] == 1
     assert rel(y, plain(x, w)) < TOL
+    for cin, cout in ((3, 16), (64, 64)):
+        pc.conv_general_dilated(x[..., :1].repeat(cin, -1),
+                                jnp.ones((3, 3, cin, cout)), (1, 1), "SAME",
+                                dimension_numbers=pc._NHWC)
+    assert gained(sites_by("lays_out"), laid_out) == {"none": 5, "x": 1,
+                                                      "dy": 2}
 
 
 def _tree(model):
@@ -154,23 +236,28 @@ def test_resnet56_group_norm_tree_is_the_parents(monkeypatch):
     assert _tree(ResNetCIFAR(depth=56, norm_type="group")) == tree
 
 
-def test_resnet56_packed_matches_plain_and_counts_53_of_57(monkeypatch):
-    """Logits and gradient of the group-norm ResNet-56 with the packed path
+@pytest.mark.parametrize("norm", ["group", "batch", "none"])
+def test_resnet56_packed_matches_plain_and_counts_53_of_57(monkeypatch, norm):
+    """Logits and gradient of ResNet-56 under each norm with the packed path
     taken (the dispatch told it is on a TPU) against the plain model's, from
-    random weights so that every residual branch contributes."""
-    model = ResNetCIFAR(depth=56, norm_type="group")
+    random weights so that every residual branch contributes; the 35 packed
+    sites of stages 1 and 2 lay ``x`` out for the kernel gradient, stage 3's
+    17 and the stem ``dy``."""
+    model = ResNetCIFAR(depth=56, norm_type=norm)
     k = jax.random.split(jax.random.PRNGKey(56), 3)
     x = jax.random.normal(k[0], (4, 32, 32, 3))
     labels = jnp.arange(4) % 10
-    params = model.init(k[1], x)
-    leaves, treedef = jax.tree.flatten(params)
+    variables = model.init(k[1], x)
+    leaves, treedef = jax.tree.flatten(variables["params"])
     # zero-initialised leaves (norm biases) get values too
     params = treedef.unflatten([
         leaf + 0.05 * jax.random.normal(kk, leaf.shape)
         for leaf, kk in zip(leaves, jax.random.split(k[2], len(leaves)))])
+    rest = {c: v for c, v in variables.items() if c != "params"}
 
     def loss(params):
-        logits = model.apply(params, x, train=True)
+        logits, _ = model.apply({"params": params, **rest}, x, train=True,
+                                mutable=list(rest))
         logp = jax.nn.log_softmax(logits)
         return -jnp.take_along_axis(logp, labels[:, None], 1).mean(), logits
 
@@ -178,11 +265,14 @@ def test_resnet56_packed_matches_plain_and_counts_53_of_57(monkeypatch):
     with jax.default_matmul_precision("highest"):
         (want_loss, want_logits), want_grad = run()
         before = perf_instrument.conv_sites()
+        laid_out = sites_by("lays_out")
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         (got_loss, got_logits), got_grad = run()
     after = perf_instrument.conv_sites()
     assert after["packed"] - before["packed"] == 53
     assert after["plain"] - before["plain"] == 4
+    assert gained(sites_by("lays_out"), laid_out) == {"x": 35, "dy": 18,
+                                                      "none": 4}
     assert abs(float(got_loss) - float(want_loss)) < 1e-5 * abs(float(want_loss))
     assert rel(got_logits, want_logits) < 1e-4
     worst = max(jax.tree.leaves(jax.tree.map(rel, got_grad, want_grad)))
