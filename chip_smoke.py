@@ -177,7 +177,7 @@ def femnist_cnn(seed: int, platform: str, workdir: str) -> dict:
 
 def cifar_resnet56(seed: int, platform: str) -> dict:
     s = RESNET
-    # the workload bench_scaling.py --workload cifar_resnet56 builds
+    # the model, shapes and optimizer of benchmark/'s cifar_resnet56
     data = synthetic_images(
         num_clients=s["silos"], image_shape=(32, 32, 3), num_classes=10,
         samples_per_client=s["samples_per_client"], test_samples=512,

@@ -43,7 +43,7 @@ CONFIGS: dict[str, list[str]] = {
         "--batch_size", "10", "--lr", "0.03", "--epochs", "1",
         "--comm_round", "100", "--frequency_of_the_test", "10",
     ],
-    # benchmark/README.md:54 (the bench.py flagship)
+    # benchmark/README.md:54 (the FEMNIST CNN row)
     "femnist_cnn": [
         "--algo", "fedavg", "--dataset", "femnist", "--model", "cnn",
         "--client_num_in_total", "3400", "--client_num_per_round", "10",

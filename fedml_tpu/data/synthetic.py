@@ -343,32 +343,27 @@ def synthetic_sequences(
 
 def synthetic_packed_population(path: str, num_clients: int, dim: int = 16,
                                 num_classes: int = 5, seed: int = 0,
-                                test_rows: int = 512,
-                                size_lo: int = 6, size_hi: int = 25,
-                                tail_size: int = 96,
-                                tail_every: int = 200) -> str:
+                                test_rows: int = 512) -> str:
     """Write a deterministic SYNTHETIC packed-npy population straight to
     disk (core/client_source.PackedNpySource layout) without ever
     materializing it — the fixture for the flat-memory evidence (ci.sh
-    streamed smoke, bench.py FEDML_BENCH_STREAM): lognormal-ish ragged
-    client sizes with a heavy tail (the skew cohort bucketing exists
-    for), labels planted from ONE pass over the feature rows actually
-    written (x and y stream together — a second pass re-drawing x would
-    store uncorrelated labels), and a held-out test split from the same
-    planted mapping. Chunked writes keep the writer's RSS flat too."""
+    streamed smoke): lognormal-ish ragged client sizes with a heavy tail
+    (the skew cohort bucketing exists for), labels planted from ONE pass
+    over the feature rows actually written (x and y stream together — a
+    second pass re-drawing x would store uncorrelated labels), and a
+    held-out test split from the same planted mapping. Chunked writes keep
+    the writer's RSS flat too."""
     import json as _json
     import os as _os
 
     _os.makedirs(path, exist_ok=True)
     rs = np.random.RandomState(seed)
-    # size_lo/size_hi/tail_size parameterize the skew: the bf16+bucket
-    # bench (FEDML_BENCH_FUSED) stretches the tail so the static batch
-    # budget is priced by a client most cohorts never sample — the
-    # FEMNIST-lognormal shape the bucket ladder exists for. Defaults are
-    # the original fixture (byte-identical populations for old callers).
-    sizes = rs.randint(size_lo, size_hi, num_clients).astype(np.int64)
-    tail = max(num_clients // tail_every, 1)
-    sizes[rs.choice(num_clients, tail, replace=False)] = tail_size
+    # 6 to 24 rows a client and one in 200 with 96: a tail that prices the
+    # static batch budget by a client most cohorts never sample — the
+    # FEMNIST-lognormal shape the bucket ladder exists for
+    sizes = rs.randint(6, 25, num_clients).astype(np.int64)
+    tail = max(num_clients // 200, 1)
+    sizes[rs.choice(num_clients, tail, replace=False)] = 96
     offsets = np.zeros(num_clients + 1, np.int64)
     np.cumsum(sizes, out=offsets[1:])
     total = int(offsets[-1])

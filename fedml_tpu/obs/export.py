@@ -4,9 +4,9 @@
   record blocks are flattened to dotted columns);
 - ``write_prometheus``: registry -> text exposition file (node_exporter
   textfile-collector shape — drop it in a scrape directory);
-- ``bench_blob``: round records -> the BENCH_r*.json-compatible one-line
-  summary (same keys as bench.py's ``_result``), so a telemetry run can
-  stand in for a bench run in dashboards.
+- ``bench_blob``: round records -> a one-line summary blob (``metric``,
+  ``value``, ``unit``, ``platform``, ``mode``) that scripts/bench_gate.py
+  and scripts/runstore.py read.
 
 scripts/report.py is the CLI over these.
 """
@@ -56,12 +56,12 @@ def write_prometheus(registry: MetricsRegistry, path: str) -> None:
 
 def bench_blob(records: list[dict], metric: str = "fedavg_rounds_per_sec",
                platform: str | None = None) -> dict:
-    """BENCH-compatible summary from a run's round records.
+    """One-line summary blob from a run's round records.
 
     Throughput comes from the span timings when present (sum of per-round
-    'round' spans — host dispatch + device wait, the same thing bench.py's
-    per_round mode times), falling back to event-timestamp extent. Comm
-    totals ride along so a wire-heavy run is legible from the blob alone."""
+    'round' spans: host dispatch + device wait), falling back to
+    event-timestamp extent. Comm totals ride along so a wire-heavy run is
+    legible from the blob alone."""
     rounds = [r for r in records if r.get("kind") == "round"]
     if not rounds:
         raise ValueError("no round records in event log")

@@ -65,6 +65,7 @@ import threading
 from functools import lru_cache
 
 from fedml_tpu.obs.metrics import REGISTRY
+from fedml_tpu.utils.flops import peak_for_kind
 
 log = logging.getLogger("fedml_tpu.obs.goodput")
 
@@ -73,27 +74,12 @@ log = logging.getLogger("fedml_tpu.obs.goodput")
 BUCKETS = ("compute", "h2d", "prefetch_stall", "wire_wait", "agg_flush",
            "drain")
 
-# Per-chip bf16 peak FLOP/s by device-kind substring — same table and
-# matching rule as bench.py's MFU column (more-specific keys first; the
-# first substring hit of the lowercased device kind wins). Unknown kinds
-# return None and MFU reports 0 (relative-only goodput).
-PEAK_FLOPS_BF16 = {
-    "v5 lite": 1.97e14,
-    "v5e": 1.97e14,
-    "v5p": 4.59e14,
-    "v6 lite": 9.18e14,
-    "v6e": 9.18e14,
-    "v4": 2.75e14,
-    "v3": 1.23e14,
-    "v2": 4.5e13,
-}
-
 
 def device_peak_flops(device_kind: str | None = None) -> float | None:
-    """Per-chip peak FLOP/s for ``device_kind`` (defaults to the live
-    jax backend's device 0 when jax is already imported — never imports
-    jax itself). None when unknown: MFU then reads 0, goodput is
-    relative-only."""
+    """Per-chip bf16 peak FLOP/s for ``device_kind``, from the package's one
+    table (``utils/flops.PEAK_BF16``; defaults to the live jax backend's
+    device 0 when jax is already imported — never imports jax itself).
+    None when unknown: MFU then reads 0, goodput is relative-only."""
     if device_kind is None:
         jax_mod = sys.modules.get("jax")
         if jax_mod is None:
@@ -104,11 +90,7 @@ def device_peak_flops(device_kind: str | None = None) -> float | None:
             log.debug("device-kind detection failed; MFU is relative-only",
                       exc_info=True)
             return None
-    kind = str(device_kind).lower()
-    for key, peak in PEAK_FLOPS_BF16.items():
-        if key in kind:
-            return peak
-    return None
+    return peak_for_kind(device_kind)
 
 
 # ------------------------------------------------------- cost-model cache

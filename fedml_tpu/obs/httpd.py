@@ -23,10 +23,9 @@ live view: a stdlib ``ThreadingHTTPServer`` per rank serving
 
 Opt-in like every obs feature: ``Telemetry(http_port=...)`` (port 0 binds
 an ephemeral port — the bound port is reported in the run header and on
-``server.port``), ``--metrics_port`` on the distributed launcher (each
-rank binds ``port + rank``; 0 = ephemeral everywhere), and
-``FEDML_BENCH_METRICS_PORT`` on bench.py. With the port unset, no socket,
-no thread, nothing.
+``server.port``) and ``--metrics_port`` on the distributed launcher (each
+rank binds ``port + rank``; 0 = ephemeral everywhere). With the port
+unset, no socket, no thread, nothing.
 """
 
 from __future__ import annotations

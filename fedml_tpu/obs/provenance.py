@@ -1,6 +1,6 @@
-"""Provenance header for BENCH blobs — who/what/where a number came from.
+"""Provenance header for result blobs — who/what/where a number came from.
 
-Every bench writer (bench.py, bench_scaling.py, scripts/chaos_soak.py)
+Every blob writer (scripts/chaos_soak.py, scripts/fleet_campaign.py)
 stamps the same ``provenance`` block on its JSON blob so scripts/runstore.py
 can index and compare figures across commits:
 
@@ -9,10 +9,10 @@ can index and compare figures across commits:
                     "dataset_source": "synthetic", "date": "2026-08-07"}}
 
 Everything is best-effort and stdlib-only: git absent -> sha None; jax not
-imported -> device fields None (this module NEVER imports jax itself — the
-bench parent process must stay jax-free); the wall-clock ``date`` is
-PASSED IN by the caller (scripts layer), never read here, keeping the
-module importable from clock-disciplined code. Historical blobs without
+imported -> device fields None (this module NEVER imports jax itself — a
+parent that leaves the chip to its child must stay jax-free); the
+wall-clock ``date`` is PASSED IN by the caller (scripts layer), never read
+here, keeping the module importable from clock-disciplined code. Historical blobs without
 the block are tolerated everywhere (runstore indexes them headerless).
 """
 
@@ -53,8 +53,8 @@ def _dist_version(name: str) -> str | None:
 
 def _device_info() -> tuple[str | None, int | None]:
     """(device_kind, device_count) from an ALREADY-IMPORTED jax, else
-    (None, None). Reading sys.modules instead of importing keeps the
-    bench parent (which must never import jax) safe to stamp from."""
+    (None, None). Reading sys.modules instead of importing keeps a
+    parent process that must never import jax safe to stamp from."""
     jax_mod = sys.modules.get("jax")
     if jax_mod is None:
         return None, None
@@ -85,7 +85,7 @@ def provenance(date: str | None = None,
 
 def stamp(blob: dict, date: str | None = None,
           dataset_source: str | None = None) -> dict:
-    """Attach the provenance block to a BENCH blob in place (and return
+    """Attach the provenance block to a result blob in place (and return
     it). Never overwrites an existing block — code that re-emits a measured
     line must not clobber the measuring process's stamp."""
     if "provenance" not in blob:

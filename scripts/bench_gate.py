@@ -1,13 +1,14 @@
 #!/usr/bin/env python
-"""Bench regression gate — fail CI when a fresh BENCH blob regresses.
+"""Blob gate — fail CI when a summary blob made afresh breaks its contract.
 
-Every perf claim in this repo rides a BENCH-style JSON blob (bench.py,
-``report.py --bench-json``, chaos_soak's summary). Until now nothing
-*compared* blobs across PRs — the trajectory could drift 20% a release
-and stay green. This gate is the comparison:
+``scripts/ci.sh`` makes one-line JSON blobs from CPU smoke runs
+(``report.py --bench-json``, the streamed smoke, fleet_campaign's
+summaries) and this gate holds each to a committed tolerance file:
+structure, byte counts, accuracy floors. It says nothing about speed: that
+is ``benchmark/`` on the chip, and its record is ``PERF_LEDGER.jsonl``.
 
     python scripts/bench_gate.py fresh.json --gate scripts/ci_bench_gate.json
-    python scripts/bench_gate.py fresh.json --baseline BENCH_r05.json \
+    python scripts/bench_gate.py fresh.json --baseline earlier.json \
         --min-ratio 0.9
 
 Exit 0 = every gated metric within tolerance; exit 1 = regression (the
@@ -121,7 +122,7 @@ def run_gate(fresh: dict, gate: dict) -> tuple[list[str], list[str]]:
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser("bench_gate")
-    p.add_argument("fresh", help="fresh BENCH blob (bench.py / report.py "
+    p.add_argument("fresh", help="fresh summary blob (report.py "
                                  "--bench-json output)")
     p.add_argument("--gate", default=None, metavar="PATH",
                    help="committed gate file with per-metric tolerances "

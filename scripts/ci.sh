@@ -542,12 +542,6 @@ print(f"fused-aggregation smoke ok: ledger {len(led)} entries equal, "
       f"no host densify, fused×median ≡ stacked×median under 2-of-8 "
       f"sign-flip, stack bytes {modes2}")
 PY
-  # the committed FEDML_BENCH_FUSED A/B artifact must stay within spec
-  # (fused flush >= 2x stacked at fan-in 128 — plain AND the robust
-  # fused×median leg, bf16+bucketed >= 2x f32 rounds/s at 100k streamed
-  # clients, fused ingest RSS bounded)
-  python scripts/bench_gate.py BENCH_FUSED_r02.json \
-    --gate scripts/ci_fused_gate.json
   echo "== secure-aggregation + privacy smoke (masked == plain within tolerance; mid-run dropout recovers; fed_privacy_epsilon exported) =="
   # the masked secure-aggregation tier (docs/ROBUSTNESS.md §Secure
   # aggregation) must (a) match plain FedAvg within quantization on a
@@ -607,10 +601,6 @@ assert outcomes.get("outcome=recovered", 0) >= 1, outcomes
 print(f"secure-aggregation smoke ok: masked == plain, dropout recovered "
       f"(ledger {len(led)} entries), eps={block['eps']:.3f} exported")
 PY
-  # the committed FEDML_BENCH_DP epsilon-vs-accuracy artifact must stay
-  # within spec (accounting math + monotonicity + bounded accuracy cost)
-  python scripts/bench_gate.py BENCH_DP_r01.json \
-    --gate scripts/ci_dp_gate.json
   echo "== hierarchical masked secagg smoke (2 edges x 4 workers; seeded in-block dropout -> edge-local reveal; per-client eps family exported; report renders eps_cli) =="
   # the masked tier composed with the tree (docs/ROBUSTNESS.md
   # §Hierarchical secure aggregation) must (a) run a dp 2-tier masked
@@ -696,8 +686,7 @@ from fedml_tpu.obs import Telemetry
 
 d = sys.argv[1]
 N, DIM, ROUNDS = 100_000, 16, 12
-# the ONE shared fixture writer (also FEDML_BENCH_STREAM's): chunked, so
-# the writer's RSS stays flat too, and labels correlate with the rows
+# the one fixture writer: chunked, so the writer's RSS stays flat too, and labels correlate with the rows
 # actually written
 data_dir = synthetic_packed_population(os.path.join(d, "packed"), N,
                                        dim=DIM)
@@ -743,10 +732,6 @@ print(f"flat-memory streamed smoke ok: {N} clients, rss "
       f"buckets {sorted({p['bucket_B'] for p in packs})}")
 PY
   python scripts/bench_gate.py ./tmp/ci_stream_blob.json \
-    --gate scripts/ci_stream_gate.json
-  # the committed FEDML_BENCH_STREAM A/B artifact must stay within the
-  # same spec (streamed RSS flat AND below the materialized twin's)
-  python scripts/bench_gate.py BENCH_STREAM_r01.json \
     --gate scripts/ci_stream_gate.json
   python scripts/report.py "$STREAM_DIR/events.jsonl"
   echo "== hierarchical 2-tier smoke (1 root + 2 edges + 8 workers; tree == flat pairwise, bitwise; root fan-in == edges) =="

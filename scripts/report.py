@@ -8,9 +8,8 @@ and (optionally) a BENCH-compatible JSON summary.
         --bench-json summary.json --csv rounds.csv
 
 Input: the events.jsonl a Telemetry run writes (FedAvgAPI(telemetry=...),
-distributed_launch --telemetry-dir, or FEDML_BENCH_TELEMETRY_DIR on
-bench.py); rotated segments (events.jsonl.N) are folded back in
-automatically. Schema: docs/OBSERVABILITY.md.
+distributed_launch --telemetry-dir); rotated segments (events.jsonl.N) are
+folded back in automatically. Schema: docs/OBSERVABILITY.md.
 """
 
 from __future__ import annotations

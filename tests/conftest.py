@@ -63,10 +63,10 @@ _SMOKE_TESTS = {
     "test_distillation.py::test_feddf_learns",
     "test_distillation.py::test_feddf_hard_variant_runs",
     "test_fedseg.py::test_fedseg_learns_blobs",
-    "test_nas_affinity_condense.py::test_genotype_extraction",
-    "test_nas_affinity_condense.py::test_fednas_heldout_split_is_disjoint",
-    "test_nas_affinity_condense.py::test_fedcon_trains_on_condensed_union",
-    "test_nas_affinity_condense.py::test_affinity_matrix_properties",
+    "test_nas_derived.py::test_genotype_extraction",
+    "test_nas_darts_search.py::test_fednas_heldout_split_is_disjoint",
+    "test_affinity_condense.py::test_fedcon_trains_on_condensed_union",
+    "test_affinity_condense.py::test_affinity_matrix_properties",
     "test_augment_poison.py::test_backdoor_attack_and_clipping_defense",
     "test_augment_poison.py::test_edge_case_pickle_reader_southwest_format",
     # cross-process runtimes ≡ in-process engines
@@ -157,12 +157,13 @@ def pytest_collection_modifyitems(config, items):
         raise pytest.UsageError(
             "_SMOKE_TESTS entries match no collected test (renamed or "
             f"removed?): {sorted(stale)}")
-    # longest file first. Under `-n N --dist loadfile` a file is one work
-    # unit, handed out in collection order, and this one (DARTS supernets,
-    # ~12 min on one worker, a fifth of the suite's CPU time) sets the wall
-    # clock by when it starts: queued mid-alphabet behind other units it
-    # pushed a cold six-worker run to its 1470 s limit.
-    items.sort(key=lambda it: "test_nas_affinity_condense.py" not in it.nodeid)
+    # longest files first. Under `-n N --dist loadfile` a file is one work
+    # unit, handed out in collection order, and a long one queued
+    # mid-alphabet sets the wall clock by when it starts (the DARTS
+    # supernet tests, one 12-minute file until PR 31 split it, pushed a
+    # cold six-worker run to its 1470 s limit).
+    first = ("test_nas_darts_search.py", "test_nas_gdas_search.py")
+    items.sort(key=lambda it: not it.nodeid.split("::")[0].endswith(first))
 
 
 @pytest.fixture(scope="session")
@@ -173,3 +174,26 @@ def mesh8():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected 8 virtual cpu devices, got {len(devs)}"
     return Mesh(np.asarray(devs[:8]), ("clients",))
+
+
+@pytest.fixture
+def nas_setup():
+    """The FedNAS search tests' tiny job: ``nas_setup(seed=0, **api_kw)``
+    gives (data, FedNASAPI) on two clients of 12x12 images (shared by
+    test_nas_darts_search.py, test_nas_gdas_search.py, test_nas_derived.py)."""
+    from fedml_tpu.algorithms.fedavg import FedAvgConfig
+    from fedml_tpu.algorithms.fednas import FedNASAPI
+    from fedml_tpu.data.synthetic import synthetic_images
+
+    def make(seed=0, **api_kw):
+        data = synthetic_images(num_clients=2, image_shape=(12, 12, 3),
+                                num_classes=3, samples_per_client=16,
+                                test_samples=24, seed=seed,
+                                size_lognormal=False)
+        cfg = FedAvgConfig(comm_round=2, client_num_in_total=2,
+                           client_num_per_round=2, epochs=1, batch_size=4,
+                           lr=0.02, seed=seed)
+        return data, FedNASAPI(data, cfg, layers=2, init_filters=8,
+                               arch_lr=3e-3, **api_kw)
+
+    return make

@@ -136,7 +136,7 @@ def test_femnist_cnn_round_step_compiles_for_v5e(one_chip,
 def test_resnet56_local_fit_compiles_for_v5e(one_chip, no_persistent_cache,
                                              monkeypatch, path):
     """One silo's local fit of the cross-silo cell: ResNet-56, group norm,
-    CIFAR-10 shapes, 8 batches of 64 (bench_scaling's cifar_resnet56).
+    CIFAR-10 shapes, 8 batches of 64 (benchmark/'s cifar_resnet56).
     ``ops/packed_conv.py`` asks ``jax.default_backend()`` which path serves
     the 3x3 convolutions; told it is a TPU, the width-packed one is what the
     chip's compiler gets, as on the chip."""

@@ -178,6 +178,20 @@ def test_device_peak_table_substring_match():
     assert goodput.device_peak_flops("cpu") is None
 
 
+def test_package_peak_equals_the_benchmarks():
+    """The package holds one peak table (``utils/flops.PEAK_BF16``, read by
+    goodput and by chip_smoke.py) and the benchmark holds its own, which
+    the package must not import: for every device kind the benchmark knows
+    the two give the same bf16 peak."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "peaks.json")) as f:
+        peaks = {k: v for k, v in json.load(f).items()
+                 if not k.startswith("_")}
+    assert "TPU v5 lite" in peaks
+    for kind, row in peaks.items():
+        assert goodput.device_peak_flops(kind) == row["bf16_flops_per_s"]
+
+
 # --------------------------------------------------- family registration
 def test_goodput_families_preregister_at_zero():
     """Telemetry() pre-registers every new family: a clean run's export

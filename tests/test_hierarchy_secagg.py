@@ -194,15 +194,20 @@ def test_reveal_frames_survive_lossy_links_flat(lr_setup):
         {"fault": "crash", "ranks": [2], "rounds": [1, 3]},
         {"fault": "drop", "direction": "send", "src": [3], "dst": [0],
          "prob": 0.4, "rounds": [1, 3]}]})
+    # run 0 is a warm-up and is not compared: while the first jit of a
+    # process compiles, round 0 outlasts round_timeout_s, the watchdog
+    # re-broadcasts, and the link sequence numbers the seeded drops are
+    # keyed on shift. The schedule has to hang on the fault plan, not on
+    # compile time, so the two runs compared both find their programs built
     runs = []
-    for i in range(2):
+    for i in range(3):
         agg = ta.run_simulated(data, task, _cfg(rounds=3),
                                job_id=f"t-hsa-lossy-flat-{i}",
                                chaos_plan=chaos(), round_timeout_s=2.0)
         assert agg.history[-1]["round"] == 2
         runs.append((agg.net.params, agg.quarantine.canonical()))
-    assert runs[0][1] == runs[1][1]
-    _params_equal(runs[0][0], runs[1][0])
+    assert runs[1][1] == runs[2][1]
+    _params_equal(runs[1][0], runs[2][0])
 
 
 def test_reveal_frames_survive_lossy_links_tree(lr_setup):
@@ -218,16 +223,17 @@ def test_reveal_frames_survive_lossy_links_tree(lr_setup):
         {"fault": "crash", "ranks": [4], "rounds": [1, 3]},
         {"fault": "drop", "direction": "send", "src": [3], "dst": [1],
          "prob": 0.4, "rounds": [1, 3]}]})
+    # run 0 is a warm-up and is not compared (see the flat twin above)
     runs = []
-    for i in range(2):
+    for i in range(3):
         agg = ta.run_simulated(data, task, _cfg(rounds=3),
                                job_id=f"t-hsa-lossy-tree-{i}", edges=2,
                                chaos_plan=chaos(), round_timeout_s=2.0)
         assert agg.history[-1]["round"] == 2
         assert agg.fanin_history and len(agg.fanin_history) == 3
         runs.append((agg.net.params, agg.quarantine.canonical()))
-    assert runs[0][1] == runs[1][1]
-    _params_equal(runs[0][0], runs[1][0])
+    assert runs[1][1] == runs[2][1]
+    _params_equal(runs[1][0], runs[2][0])
 
 
 def test_client_reveal_cache_retransmits_verbatim(lr_setup):

@@ -62,8 +62,8 @@ def test_native_faster_at_scale():
                            num_classes=10, samples_per_client=100, seed=1)
     ids = np.arange(512)
 
-    # correctness at scale only; wall-clock comparisons are CI flakes —
-    # bench.py is where the native-vs-numpy timing story is measured
+    # correctness at scale only; wall-clock comparisons are CI flakes
+    # (native against numpy has no chip timing: ROADMAP D8)
     a = pack_clients(big, ids, batch_size=20, max_batches=30, use_native=False)
     b = pack_clients(big, ids, batch_size=20, max_batches=30, use_native=True)
     np.testing.assert_allclose(a.num_samples, b.num_samples)

@@ -195,7 +195,7 @@ def test_direct_run_round_spans_are_per_call_deltas(lr_setup):
     tel = Telemetry(registry=MetricsRegistry())
     api = FedAvgAPI(data, task, cfg, telemetry=tel)
     for r in range(3):
-        api.run_round(r)  # no next_round between calls, like bench.py
+        api.run_round(r)  # no next_round between calls
     recs = tel.events.sink.records
     spans = [r["spans"]["round"] for r in recs]
     assert all(s > 0 for s in spans)

@@ -6,10 +6,10 @@ byte accounting, and the async-waves composition that lifts the PR-8
 dense-uploads-only refusal.
 
 Oracles are numpy; end-to-end claims run the loopback cross-process stack
-at tiny shapes. The convergence-vs-bytes artifact lives in the
-FEDML_BENCH_CODEC A/B (bench.py); the byte-reduction floors (>= 8x int8,
->= 25x 1-bit vs dense f32) are asserted here on a model large enough that
-frame headers don't dilute the ratio.
+at tiny shapes. The byte-reduction floors (>= 8x int8, >= 25x 1-bit vs
+dense f32) are asserted here on a model large enough that frame headers
+don't dilute the ratio; convergence against bytes has no chip measurement
+(ROADMAP R11).
 """
 
 import threading
