@@ -37,6 +37,7 @@ from jax import lax
 from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from fedml_tpu.core import program_store
 from fedml_tpu.core.client_data import (
     ClientBatch,
     FederatedData,
@@ -1009,6 +1010,47 @@ class FedAvgAPI:
         return sample_for(self.cfg, round_idx, self._client_sizes)
 
     # ----------------------------------------------------------- round block
+    # the methods the single-device block program's trace runs through
+    _BLOCK_TRACE_PATH = ("_round_body", "_aggregate_and_update",
+                         "_update_from_aggregate", "_agg_weights")
+
+    def _block_trace_reads(self) -> dict:
+        """Every value the trace of the single-device ``block_fn`` reads
+        from ``self`` (its call arguments apart), by name, for the program
+        store's key. **Whoever makes that trace read another attribute
+        adds it here**: a value the key does not hold is a stale program.
+        What is not listed follows from what is: ``_needs_stacked`` from
+        ``_robust_agg`` and ``_sanitize_mult``; the task and the local
+        spec are what ``local_update`` closes over; ``_agg_reshard`` and
+        ``partitioner`` are None and ``_sharded`` False without a mesh.
+        A subclass that overrides a method of the trace's path may read
+        anything: it is unkeyable, and traces as ever."""
+        cls = type(self)
+        for name in self._BLOCK_TRACE_PATH:
+            if getattr(cls, name) is not getattr(FedAvgAPI, name):
+                raise program_store.Unkeyable(
+                    f"{cls.__qualname__} overrides {name}: what it reads "
+                    "from the engine is not known to the key")
+        return {
+            "engine": cls,
+            # the seed of the client keys; lr, wd and epochs are baked
+            # into local_update, which is keyed itself
+            "cfg": self.cfg,
+            "local_update": self.local_update,
+            "adversary": self._adversary,
+            "client_result_hook": self.client_result_hook,
+            "post_aggregate_hook": self.post_aggregate_hook,
+            "server_update": self.server_update,
+            "robust_agg": self._robust_agg,
+            "sanitize_mult": self._sanitize_mult,
+            "uniform_avg": self.uniform_avg,
+            "emit_stats": self._emit_stats,
+            "flags": {"donate": self.donate, "device_data": self.device_data,
+                      "block_working_set": self.block_working_set,
+                      "bucket_batches": self.bucket_batches,
+                      "sharded": self._sharded},
+        }
+
     def _build_block_fn(self):
         """R rounds as ONE compiled program: lax.scan over rounds, the whole
         block's index batches resident on device. Removes per-round host
@@ -1061,7 +1103,6 @@ class FedAvgAPI:
 
                 return step
 
-            @partial(jax.jit, donate_argnums=(0, 1, 2))
             def block_fn(rng, net, opt, dev_x, dev_y, idx, mask, nsamp, ids,
                          round_idxs):
                 rng, (khs, kps) = derive_hook_keys(rng, idx.shape[0])
@@ -1071,7 +1112,12 @@ class FedAvgAPI:
                 )
                 return rng, net, opt, ms
 
-            return block_fn
+            # jax.jit(block_fn) where no compile cache directory is set;
+            # with one, a jit that loads the program a former process
+            # exported in place of tracing it (core/program_store.py)
+            return program_store.stored_jit(
+                block_fn, donate_argnums=(0, 1, 2),
+                reads=self._block_trace_reads)
 
         mesh = self.mesh
         axis = mesh.axis_names[0]

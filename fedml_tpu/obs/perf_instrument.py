@@ -124,6 +124,10 @@ def _span_hist(name: str):
 # that paid them; :func:`setup_phases` is the read side.
 UNATTRIBUTED_VARIANT = "_other"
 INIT_VARIANT = "init"
+# the trace and lowering of a program on its way into the program store
+# (core/program_store.py): they run inside the stored jit's own trace,
+# whose time already holds them, so they are tagged apart
+EXPORT_VARIANT = "_export"
 # the jit functions algorithms/fedavg.py builds its round programs as
 ROUND_PROGRAMS = frozenset({"block_fn", "sharded_block_fn", "round_fn",
                             "robust_round_fn"})
@@ -205,15 +209,16 @@ def setup_phases() -> dict:
     ``compile_or_load_s`` are summed over the round-program variants,
     which is every variant but ``_other`` (what no engine dispatch paid
     for; the cache's hit, miss and retrieval events of an untagged block
-    dispatch too, which carry no function name) and ``init`` (already
-    inside ``init_s``). A jitted function
+    dispatch too, which carry no function name), ``init`` (already
+    inside ``init_s``) and ``_export`` (a program store miss, already
+    inside the stored jit's trace). A jitted function
     called while another is traced reports its own trace inside the outer
     one's, so ``trace_s`` can overcount (PERF.md section 5 has the gap)."""
     init = REGISTRY.histogram("fed_span_seconds", span="init").total
     out = {"init_s": init, "trace_s": 0.0, "lower_s": 0.0,
            "compile_or_load_s": 0.0}
     for variant, st in variant_compile_stats().items():
-        if variant in (UNATTRIBUTED_VARIANT, INIT_VARIANT):
+        if variant in (UNATTRIBUTED_VARIANT, INIT_VARIANT, EXPORT_VARIANT):
             continue
         out["trace_s"] += st.get("trace_seconds", 0.0)
         out["lower_s"] += st.get("lower_seconds", 0.0)
@@ -251,8 +256,8 @@ def _conv_sites(p: int, lays_out: str):
                             lays_out=lays_out)
 
 
-def record_conv_site(p: int, lays_out: str) -> None:
-    _conv_sites(p, lays_out).inc()
+def record_conv_site(p: int, lays_out: str, n: int = 1) -> None:
+    _conv_sites(p, lays_out).inc(n)
 
 
 def conv_sites() -> dict:
@@ -262,6 +267,83 @@ def conv_sites() -> dict:
     return {path: sum(v for labels, v in fam.items()
                       if f"path={path}" in labels.split(","))
             for path in ("packed", "plain")}
+
+
+def conv_site_counts() -> dict:
+    """{(p, lays_out): n}: the same sites by what ``record_conv_site`` was
+    handed. The program store diffs this around a trace and keeps the
+    difference with the program."""
+    fam = REGISTRY.snapshot().get("fed_conv_sites_total") or {}
+    out = {}
+    for label_s, n in fam.items():
+        labels = dict(kv.split("=", 1) for kv in label_s.split(","))
+        out[int(labels["p"]), labels["lays_out"]] = n
+    return out
+
+
+def replay_conv_sites(counted) -> None:
+    """Count again the sites ``[[p, lays_out, n], ...]`` that the trace of a
+    stored program counted: a program that is loaded traces nothing, and
+    ``conv_sites()`` reads as after a trace."""
+    for p, lays_out, n in counted:
+        record_conv_site(int(p), str(lays_out), n)
+
+
+# ------------------------------------------------------ the program store
+# core/program_store.py, docs/PERFORMANCE.md §Stored round programs:
+#
+#     fed_program_store_total{outcome}        first calls of a stored jit,
+#                                             once for each abstract
+#                                             signature: hit (the program
+#                                             was loaded), miss (traced
+#                                             once and stored), stale (a
+#                                             record was there and did not
+#                                             load: traced and written
+#                                             anew), unkeyable (the trace
+#                                             reads a value no rule keys,
+#                                             or code the key does not
+#                                             hold: traced as ever), error
+#                                             (jax refuses to export the
+#                                             program, or cannot call a
+#                                             stored one: traced as ever)
+#     fed_program_store_seconds_total{phase}  key (forming the key), load
+#                                             (reading a record and calling
+#                                             it), export (trace, lower,
+#                                             serialize and write on a miss)
+PROGRAM_STORE_OUTCOMES = ("hit", "miss", "unkeyable", "stale", "error")
+PROGRAM_STORE_PHASES = ("key", "load", "export")
+
+
+@lru_cache(maxsize=8)
+def _program_store(outcome: str):
+    return REGISTRY.counter("fed_program_store_total", outcome=outcome)
+
+
+@lru_cache(maxsize=4)
+def _program_store_seconds(phase: str):
+    return REGISTRY.counter("fed_program_store_seconds_total", phase=phase)
+
+
+def record_program_store(outcome: str) -> None:
+    _program_store(outcome).inc()
+
+
+def record_program_store_seconds(phase: str, seconds: float) -> None:
+    _program_store_seconds(phase).inc(seconds)
+
+
+def program_store_counts() -> dict:
+    """{outcome: n} from the live registry."""
+    return {o: _program_store(o).value for o in PROGRAM_STORE_OUTCOMES}
+
+
+def ensure_program_store_families() -> None:
+    """Pre-register every outcome and phase at zero: a run in which the
+    store never engaged reads as that, not as a metric that is missing."""
+    for outcome in PROGRAM_STORE_OUTCOMES:
+        _program_store(outcome)
+    for phase in PROGRAM_STORE_PHASES:
+        _program_store_seconds(phase)
 
 
 # ------------------------------------------------------ compile accounting

@@ -154,6 +154,7 @@ class Telemetry:
 
         _goodput.ensure_goodput_families()
         _perf_instr.ensure_compile_attr_families()
+        _perf_instr.ensure_program_store_families()
         self._header_emitted = False
         self._last_comm = comm_counters(REGISTRY)
 

@@ -11,7 +11,8 @@ change itself against another checkout's.
     # anywhere:
     python3 scripts/chip_model_leaves.py compare <a.npz> <b.npz>
 
-``compare`` prints one JSON line: ``compared`` (the cell's own ``dparam`` and
+``dump`` also prints the first block's losses, the program store's counters
+and the set-up split. ``compare`` prints one JSON line: ``compared`` (the cell's own ``dparam`` and
 ``dparam_med`` with b in the reference's place), ``leaf_rel`` (for each leaf
 the norm of a's model minus b's over the norm of b's change from the shared
 weights: worst leaf, its name, the median leaf), the five losses' relative
@@ -45,7 +46,18 @@ def dump(workload: str, seed: int, out: str) -> None:
                    check._leaves(prog["models"][max(prog["models"])])})
     os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
     np.savez(out, **arrays)
-    print(json.dumps({"out": out, "losses": prog["losses"]}), flush=True)
+    # how the block program came to be (PERF.md section 3): the program
+    # store's outcomes and seconds from the registry's export (empty in a
+    # checkout from before the store), and the set-up split
+    from fedml_tpu.obs import perf_instrument as perf
+    from fedml_tpu.obs.metrics import REGISTRY
+
+    store = {k: v for k, v in REGISTRY.snapshot().items()
+             if k.startswith("fed_program_store")}
+    print(json.dumps({"out": out, "losses": prog["losses"],
+                      "program_store": store,
+                      "setup_phases": perf.setup_phases(),
+                      "conv_sites": perf.conv_sites()}), flush=True)
 
 
 def compare(path_a: str, path_b: str) -> dict:
@@ -68,6 +80,8 @@ def compare(path_a: str, path_b: str) -> dict:
     return {
         "same_init": all(np.array_equal(v, a[f"init{k}"])
                          for k, v in init.items()),
+        "leaves_equal_bit_for_bit": sum(
+            np.array_equal(a[f"model{k}"], b[f"model{k}"]) for k in model_b),
         "compared": {"dparam": worst, "dparam_med": med},
         "leaf_rel": {"worst": rel[name], "worst_leaf": name,
                      "median": float(np.median(list(rel.values()))),
