@@ -52,7 +52,8 @@ from fedml_tpu.core.client_source import (
     ClientDataSource,
     pack_clients_source,
 )
-from fedml_tpu.core.local import LocalSpec, Task, make_eval_fn, make_local_update
+from fedml_tpu.core.local import (LocalSpec, Task, handing_off, make_eval_fn,
+                                  make_local_update)
 from fedml_tpu.core.partition_rules import tree_bytes as _tree_bytes
 from fedml_tpu.core.pipeline import (
     InflightRing,
@@ -174,6 +175,28 @@ def _mesh_drift_stats(net_params, avg_params, nsamp, axis) -> dict:
     }
 
 
+def _device_bytes_limit() -> int | None:
+    """What the default device says it holds (None where it does not say:
+    the CPU)."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def _refuse_cohort_beyond_device(params, clients: int) -> None:
+    """A vmapped cohort keeps ``clients`` copies of the weights and of
+    their gradients side by side. Where those alone pass the device's
+    memory the round could only end in the compiler's out-of-memory error:
+    say at construction what serves such a model."""
+    limit = _device_bytes_limit()
+    need = 2 * clients * _tree_bytes(params)
+    if limit is not None and need > limit:
+        raise ValueError(
+            f"client_fold='vmap' fits {clients} clients side by side: their "
+            f"weights and gradients alone take {need / 2**30:.1f} GiB of "
+            f"the device's {limit / 2**30:.1f}. Fold them one after another "
+            "(FedAvgConfig.client_fold='scan', docs/PERFORMANCE.md §Folded "
+            "silos) or sample fewer clients a round")
+
+
 @jax.named_scope("fed_aggregate")
 def _shard_aggregate(nets, metrics, nsamp, axis):
     """Per-shard weighted aggregation under shard_map: weighted psum of the
@@ -279,6 +302,14 @@ class FedAvgConfig:
     # fleet's NORMAL state, not a failure. Recorded in the run header via
     # asdict like every other flag, so a run replays from its header.
     churn_trace: object | None = None
+    # how the single-device scanned block runs a round's cohort
+    # (docs/PERFORMANCE.md §Folded silos): 'vmap' (default) fits the
+    # clients side by side, K copies of weights and gradients alive at
+    # once; 'scan' folds them one after another into a running weighted
+    # sum (core/client_fold.py), one copy alive at a time, for a model
+    # whose weights do not fit beside their own cohort. 'scan' serves
+    # run_rounds with device_data=True and refuses the rest at construction.
+    client_fold: str = "vmap"
 
 
 def resolve_local_spec(local_spec: LocalSpec | None,
@@ -604,7 +635,19 @@ class FedAvgAPI:
                 self.server_opt_state = self.partitioner.shard(
                     self.server_opt_state)
 
-            self.round_fn = self._build_round_fn()
+            if config.client_fold == "vmap":
+                if mesh is None:
+                    _refuse_cohort_beyond_device(self.net.params,
+                                                 config.client_num_per_round)
+                self.round_fn = self._build_round_fn()
+            elif config.client_fold == "scan":
+                from fedml_tpu.core import client_fold
+
+                client_fold.check(self, FedAvgAPI)
+                self.round_fn = client_fold.refused_round_fn
+            else:
+                raise ValueError(f"client_fold={config.client_fold!r} (one "
+                                 "of 'vmap', 'scan')")
             self._test_cache = None
             self.history: list[dict] = []
             # per-round pack/bucket accounting (docs/PERFORMANCE.md §Streaming
@@ -1045,6 +1088,7 @@ class FedAvgAPI:
             "sanitize_mult": self._sanitize_mult,
             "uniform_avg": self.uniform_avg,
             "emit_stats": self._emit_stats,
+            "client_fold": self.cfg.client_fold,
             "flags": {"donate": self.donate, "device_data": self.device_data,
                       "block_working_set": self.block_working_set,
                       "bucket_batches": self.bucket_batches,
@@ -1103,6 +1147,13 @@ class FedAvgAPI:
 
                 return step
 
+            if self.cfg.client_fold == "scan":
+                # the cohort one silo after another (core/client_fold.py)
+                from fedml_tpu.core import client_fold
+
+                make_step = client_fold.make_step(self, client_keys,
+                                                  _gather_rows)
+
             def block_fn(rng, net, opt, dev_x, dev_y, idx, mask, nsamp, ids,
                          round_idxs):
                 rng, (khs, kps) = derive_hook_keys(rng, idx.shape[0])
@@ -1115,9 +1166,9 @@ class FedAvgAPI:
             # jax.jit(block_fn) where no compile cache directory is set;
             # with one, a jit that loads the program a former process
             # exported in place of tracing it (core/program_store.py)
-            return program_store.stored_jit(
+            return handing_off(program_store.stored_jit(
                 block_fn, donate_argnums=(0, 1, 2),
-                reads=self._block_trace_reads)
+                reads=self._block_trace_reads), self.task)
 
         mesh = self.mesh
         axis = mesh.axis_names[0]
@@ -1159,7 +1210,7 @@ class FedAvgAPI:
                     (idx, mask, nsamp, ids, round_idxs, khs, kps))
                 return rng, net, opt, ms
 
-            return sharded_block_fn
+            return handing_off(sharded_block_fn, self.task)
 
         def shard_block(net, opt, dev_x, dev_y, idx, mask, nsamp, ids, rounds,
                         khs, kps):
@@ -1220,7 +1271,7 @@ class FedAvgAPI:
                                          khs, kps)
             return rng, net, opt, ms
 
-        return block_fn
+        return handing_off(block_fn, self.task)
 
     def run_rounds(self, start_round: int, num_rounds: int):
         """Run ``num_rounds`` rounds as one device-side program (requires

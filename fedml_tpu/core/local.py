@@ -50,6 +50,40 @@ class Task(NamedTuple):
     predict: Callable
     # (params, extra, x, y, mask) -> metrics dict with 'loss_sum','correct','count'
     eval_batch: Callable
+    # (prefix, sink) or None: the training metrics whose name starts with
+    # prefix are no round record's; a block program's caller hands them to
+    # sink({name less prefix: device array}) unread (``handing_off``)
+    handoff: tuple | None = None
+
+
+class _HandingOff:
+    """A block program whose returned metrics leave what the task declared
+    (``Task.handoff``) with the task's sink, as device arrays: nothing
+    waits on the device here."""
+
+    def __init__(self, program, prefix: str, sink: Callable):
+        self._program, self._prefix, self._sink = program, prefix, sink
+        self.lower = program.lower
+        # the jit's own: what it wraps is the traced function (or the store's)
+        self.__wrapped__ = getattr(program, "__wrapped__", program)
+
+    def __call__(self, *args):
+        *state, ms = self._program(*args)
+        n = len(self._prefix)
+        handed = {k[n:]: v for k, v in ms.items()
+                  if k.startswith(self._prefix)}
+        if handed:
+            self._sink(handed)
+            ms = {k: v for k, v in ms.items()
+                  if not k.startswith(self._prefix)}
+        return (*state, ms)
+
+
+def handing_off(program, task):
+    """``program`` (a jitted block: ``(...) -> (*state, metrics)``) as it
+    is, or where ``task`` declares a hand-off, wrapped to make it."""
+    handoff = getattr(task, "handoff", None)
+    return program if handoff is None else _HandingOff(program, *handoff)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,6 +261,10 @@ def make_local_update(task: Task, spec: LocalSpec):
             "correct": jnp.sum(metrs["correct"]),
             "count": jnp.sum(metrs["count"]),
         }
+        # what else a task counts (an expert layer's routing counts,
+        # core/tasks.py ``stats``) keeps its own shape past the two loops
+        for name in sorted(metrs.keys() - metrics.keys()):
+            metrics[name] = jnp.sum(metrs[name], axis=(0, 1))
         return NetState(params, extra), metrics
 
     return local_update

@@ -453,8 +453,8 @@ def read_record(path: str):
 def _export(fun, leaves, in_tree):
     """Trace ``fun`` once over flat leaves (``Exported.serialize`` refuses
     a tree of unregistered node types; a ``PyTreeDef`` itself pickles).
-    Returns (serialized program, pickled output tree, the convolution
-    sites the trace counted). The export's own trace and lowering happen
+    Returns (serialized program, pickled output tree, what the trace
+    counted: ``perf_instrument.traced_since``). The export's own trace and lowering happen
     inside the stored jit's trace, which reports them: they are tagged
     apart (``EXPORT_VARIANT``), not counted twice."""
     out_trees = []
@@ -469,14 +469,11 @@ def _export(fun, leaves, in_tree):
     specs = [jax.ShapeDtypeStruct(a.shape, a.dtype,
                                   weak_type=getattr(a, "weak_type", False))
              for a in map(jax.typeof, leaves)]
-    sites0 = _perf.conv_site_counts()
+    before = _perf.traced_counts()
     with _perf.attribute_compiles(_perf.EXPORT_VARIANT):
         exported = jax.export.export(jax.jit(flat))(*specs)
-    counted = [[p, lays_out, n - sites0.get((p, lays_out), 0)]
-               for (p, lays_out), n in sorted(_perf.conv_site_counts().items())
-               if n > sites0.get((p, lays_out), 0)]
     return (bytes(exported.serialize()), pickle.dumps(out_trees[-1]),
-            counted)
+            _perf.traced_since(before))
 
 
 @functools.lru_cache(maxsize=1)
@@ -564,7 +561,7 @@ def _run(fun, args, donate_argnums, reads):
             record = read_record(path)
             if record is not None:
                 out = _call_record(record, leaves)
-                _perf.replay_conv_sites(record[0].get("conv_sites", ()))
+                _perf.replay_traced(record[0])
                 _perf.record_program_store("hit")
                 with contextlib.suppress(OSError):
                     os.utime(path)  # loaded now: among the newest again
@@ -576,8 +573,8 @@ def _run(fun, args, donate_argnums, reads):
 
     with _timed("export"):
         try:
-            exported, out_tree, sites = _export(fun, leaves, in_tree)
-            header = dict(ingr, conv_sites=sites, reads_in_clear=clear)
+            exported, out_tree, counted = _export(fun, leaves, in_tree)
+            header = dict(ingr, **counted, reads_in_clear=clear)
             # a miss runs what it stored: the executable this process
             # compiles is the one a later process's hit finds in the
             # compile cache

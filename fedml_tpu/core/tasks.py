@@ -98,7 +98,9 @@ def classification_task(module) -> Task:
 
 
 def sequence_task(module, pad_id: int = 0, count_pad_in_acc: bool = False,
-                  seq_axis: str | None = None) -> Task:
+                  seq_axis: str | None = None,
+                  stats: str | None = None,
+                  jit_init: bool = False) -> Task:
     """Next-token prediction: module maps tokens [bs, T] -> logits [bs, T, V];
     labels are the inputs shifted by the module itself or provided as y
     [bs, T]. Tokens equal to ``pad_id`` are masked out of loss and accuracy
@@ -111,12 +113,38 @@ def sequence_task(module, pad_id: int = 0, count_pad_in_acc: bool = False,
     collective is needed: differentiating this psum-ed loss w.r.t.
     seq-invariant params makes shard_map's vma-aware transpose insert the
     gradient psum itself (see the NOTE in core/local.py), so the gradient
-    equals the unsharded gradient exactly."""
+    equals the unsharded gradient exactly.
+
+    stats: a collection the module sows sums into while it trains (an
+    expert layer's routing counts). Each value joins the training metrics
+    as ``<stats>_<name>`` and is summed with them over batches and clients;
+    the collection is no part of the model's state.
+
+    jit_init: ``init`` as one jitted program, for a model too large to
+    initialise one op at a time."""
 
     def init(rng, x_sample):
         p_rng, d_rng = jax.random.split(rng)
-        variables = module.init({"params": p_rng, "dropout": d_rng}, x_sample, train=False)
+
+        def make(p_rng, d_rng, x_sample):
+            return module.init({"params": p_rng, "dropout": d_rng}, x_sample,
+                               train=False)
+
+        variables = dict((jax.jit(make) if jit_init else make)(
+            p_rng, d_rng, x_sample))
+        variables.pop(stats, None)
         return _split_variables(variables)
+
+    def _train_logits(params, extra, x, rng):
+        """(logits, new_extra, sown sums) of a training forward."""
+        if stats is None:
+            return (*_apply_train(module, params, extra, x, rng), {})
+        logits, mutated = module.apply(
+            {"params": params, **extra}, x, train=True,
+            mutable=[*extra, stats], rngs={"dropout": rng})
+        sown = {f"{stats}_{k}": v[-1]
+                for k, v in mutated.pop(stats, {}).items()}
+        return logits, {**extra, **mutated}, sown
 
     def _tok_mask(y, mask):
         tm = (y != pad_id).astype(jnp.float32)
@@ -126,8 +154,9 @@ def sequence_task(module, pad_id: int = 0, count_pad_in_acc: bool = False,
         return jax.lax.psum(v, seq_axis) if seq_axis is not None else v
 
     def loss(params, extra, x, y, mask, rng, train):
+        sown = {}
         if train:
-            logits, new_extra = _apply_train(module, params, extra, x, rng)
+            logits, new_extra, sown = _train_logits(params, extra, x, rng)
         else:
             logits, new_extra = _apply_eval(module, params, extra, x), extra
         per_tok = optax.softmax_cross_entropy_with_integer_labels(logits, y)
@@ -136,7 +165,8 @@ def sequence_task(module, pad_id: int = 0, count_pad_in_acc: bool = False,
         l = _seq_sum(jnp.sum(per_tok * tm)) / n
         correct = _seq_sum(jnp.sum((jnp.argmax(logits, -1) == y) * tm))
         metrics = {"loss_sum": _seq_sum(jnp.sum(per_tok * tm)),
-                   "correct": correct, "count": _seq_sum(jnp.sum(tm))}
+                   "correct": correct, "count": _seq_sum(jnp.sum(tm)),
+                   **sown}
         return l, new_extra, metrics
 
     def predict(params, extra, x):
@@ -153,6 +183,20 @@ def sequence_task(module, pad_id: int = 0, count_pad_in_acc: bool = False,
         }
 
     return Task(init, loss, predict, eval_batch)
+
+
+def routed_sequence_task(module) -> Task:
+    """``sequence_task`` for a model with an expert layer
+    (``models/lfm2_moe.py``): the routing counts it sows into ``moe_stats``
+    ride out of the local fit with the loss sums, and the task declares
+    them handed off (``Task.handoff``): a block program's caller gives them
+    to the ``fed_moe_*`` counters as device arrays
+    (``perf_instrument.note_moe_stats``) and they reach no round record."""
+    from fedml_tpu.obs import perf_instrument
+
+    task = sequence_task(module, stats="moe_stats", jit_init=True)
+    return task._replace(
+        handoff=("moe_stats_", perf_instrument.note_moe_stats))
 
 
 def segmentation_task(

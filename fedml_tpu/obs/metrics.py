@@ -152,6 +152,9 @@ class MetricsRegistry:
         self._lock = threading.Lock()
         # name -> (kind, {label_key: metric})
         self._families: dict[str, tuple[str, dict]] = {}
+        # called before every read: a producer that keeps device arrays
+        # unread until somebody asks folds them into its counters here
+        self._collectors: list = []
 
     def _child(self, kind: str, factory, name: str, labels: dict):
         key = _label_key(labels)
@@ -195,10 +198,24 @@ class MetricsRegistry:
             return fam[1].pop(key, None) is not None
 
     # ------------------------------------------------------------- export
+    def add_collector(self, prefix: str, fn) -> None:
+        """``fn()`` runs before each ``snapshot`` and ``to_prometheus``, and
+        before a ``total`` of a family whose name starts with ``prefix``,
+        and may write metrics; outside the registry's lock, once however
+        often it is added."""
+        if (prefix, fn) not in self._collectors:
+            self._collectors.append((prefix, fn))
+
+    def _collect(self, name: str | None = None) -> None:
+        for prefix, fn in list(self._collectors):
+            if name is None or name.startswith(prefix):
+                fn()
+
     def snapshot(self) -> dict:
         """{name: {labels-as-sorted-tuple-str: value | histogram summary}}.
         Scalars for counters/gauges; ``Histogram.summary()`` dicts for
         histograms. Keys are stable strings so the snapshot is jsonable."""
+        self._collect()
         with self._lock:
             fams = {n: (k, dict(c)) for n, (k, c) in self._families.items()}
         out: dict = {}
@@ -214,6 +231,7 @@ class MetricsRegistry:
     def total(self, name: str) -> float:
         """Sum of a counter/gauge family over all label sets (0.0 when the
         family does not exist — callers diff totals between rounds)."""
+        self._collect(name)
         with self._lock:
             fam = self._families.get(name)
             children = list(fam[1].values()) if fam else []
@@ -222,6 +240,7 @@ class MetricsRegistry:
     def to_prometheus(self) -> str:
         """Prometheus text exposition (counters/gauges as-is; histograms as
         _count/_sum plus quantile gauges — the summary-metric convention)."""
+        self._collect()
         with self._lock:
             fams = {n: (k, dict(c)) for n, (k, c) in self._families.items()}
         lines = []

@@ -289,6 +289,160 @@ def replay_conv_sites(counted) -> None:
         record_conv_site(int(p), str(lays_out), n)
 
 
+# --------------------------------------------------------- folded silos
+# core/client_fold.py, docs/PERFORMANCE.md §Folded silos. Fed at TRACE time
+# by the fold's step, once for each trace of a block program that folds its
+# cohort (the vmapped default is left as it was and counts nothing); a
+# program the store loads counts again what its trace counted:
+#
+#     fed_client_fold_total{mode}       mode=scan
+@lru_cache(maxsize=4)
+def _client_fold(mode: str):
+    return REGISTRY.counter("fed_client_fold_total", mode=mode)
+
+
+def record_client_fold(mode: str, n: int = 1) -> None:
+    _client_fold(mode).inc(n)
+
+
+def client_fold_counts() -> dict:
+    """{mode: n}: block programs traced (or loaded) so far, by fold."""
+    fam = REGISTRY.snapshot().get("fed_client_fold_total") or {}
+    return {label_s.split("=", 1)[1]: n for label_s, n in fam.items()}
+
+
+def traced_counts() -> dict:
+    """What traces have counted so far, for the program store to diff
+    around a trace and keep with the program."""
+    return {"conv_sites": conv_site_counts(),
+            "client_fold": client_fold_counts()}
+
+
+def traced_since(before: dict) -> dict:
+    """The part of ``traced_counts()`` counted since ``before``, as lists a
+    record's JSON header holds."""
+    now = traced_counts()
+    sites = [[p, lays_out, n - before["conv_sites"].get((p, lays_out), 0)]
+             for (p, lays_out), n in sorted(now["conv_sites"].items())
+             if n > before["conv_sites"].get((p, lays_out), 0)]
+    folds = [[mode, n - before["client_fold"].get(mode, 0)]
+             for mode, n in sorted(now["client_fold"].items())
+             if n > before["client_fold"].get(mode, 0)]
+    return {"conv_sites": sites, "client_fold": folds}
+
+
+def replay_traced(header: dict) -> None:
+    """Count again what the trace of a stored program counted."""
+    replay_conv_sites(header.get("conv_sites", ()))
+    for mode, n in header.get("client_fold", ()):
+        record_client_fold(str(mode), n)
+
+
+# ------------------------------------------------------- the expert layer
+# models/lfm2_moe.py, docs/OBSERVABILITY.md. The model counts on the device
+# and the counts ride out of the block program in its metrics
+# (``moe_stats_*``); the block's caller hands them over as device arrays
+# (``Task.handoff``, ``note_moe_stats``), one jitted add folds them into a
+# running sum that stays on the device, and the sum is read when somebody
+# asks the registry for a snapshot, an export or a ``fed_moe`` total:
+# nothing waits on the device where a block is dispatched.
+#
+#     fed_moe_rows_total{kind}          rows of the grouped products:
+#                                       kind=real held an assignment,
+#                                       kind=dispatched were computed (the
+#                                       row budget, or held * tokens where
+#                                       a step took the full-size path)
+#     fed_moe_fallback_steps_total      layer-steps whose tiles did not fit
+#                                       the budget and took the exact path
+#                                       of full size
+#     fed_moe_expert_tokens_total{layer,expert}
+#                                       assignments of each held expert,
+#                                       summed over silos: layer counts the
+#                                       expert layers from 0, expert the
+#                                       held experts from experts_held[0].
+#                                       Fed where the round keeps the
+#                                       counts' [layer, expert] shape (the
+#                                       fold); the vmapped and mesh rounds
+#                                       sum a metric over all its axes
+_moe_lock = threading.Lock()
+# {name: (high, low)}: the counts handed over and not yet read, as two
+# uint32 limbs on the device (no 64-bit integers there, and a float32 sum
+# stops counting in ones at 2**24)
+_moe_sum: dict | None = None
+
+
+@lru_cache(maxsize=1)
+def _moe_adder():
+    import jax
+    import jax.numpy as jnp
+
+    def add(total, stats):
+        out = {}
+        for name, (high, low) in total.items():
+            block = jnp.sum(stats[name].astype(jnp.uint32), axis=0)
+            new = low + block  # wraps
+            out[name] = (high + (new < low).astype(jnp.uint32), new)
+        return out
+
+    return jax.jit(add)
+
+
+def _moe_shapes(total: dict) -> dict:
+    return {name: low.shape for name, (_, low) in total.items()}
+
+
+def note_moe_stats(stats: dict) -> None:
+    """Fold one block's counts (device arrays, a leading axis of rounds)
+    into the running sum, on the device."""
+    import numpy as np
+
+    global _moe_sum
+    shapes = {name: v.shape[1:] for name, v in stats.items()}
+    with _moe_lock:
+        if _moe_sum is not None and _moe_shapes(_moe_sum) != shapes:
+            _read_moe_sum()  # another model's counts: read, start anew
+        if _moe_sum is None:
+            _moe_sum = {name: (np.zeros(shape, np.uint32),) * 2
+                        for name, shape in shapes.items()}
+        _moe_sum = _moe_adder()(_moe_sum, stats)
+
+
+def _read_moe_sum() -> None:
+    """The running sum into the counters (under ``_moe_lock``)."""
+    import numpy as np
+
+    global _moe_sum
+    total, _moe_sum = _moe_sum, None
+    stats = {name: np.asarray(high, np.float64) * 2.0 ** 32 + np.asarray(low)
+             for name, (high, low) in (total or {}).items()}
+    for kind in ("real", "dispatched"):
+        if f"rows_{kind}" in stats:
+            REGISTRY.counter("fed_moe_rows_total", kind=kind).inc(
+                float(stats[f"rows_{kind}"].sum()))
+    if "fallback_steps" in stats:
+        REGISTRY.counter("fed_moe_fallback_steps_total").inc(
+            float(stats["fallback_steps"].sum()))
+    if stats.get("expert_tokens", np.zeros(())).ndim == 2:
+        for (layer, expert), n in np.ndenumerate(stats["expert_tokens"]):
+            REGISTRY.counter("fed_moe_expert_tokens_total", layer=layer,
+                             expert=expert).inc(float(n))
+
+
+def _drain_moe() -> None:
+    with _moe_lock:
+        _read_moe_sum()
+
+
+REGISTRY.add_collector("fed_moe", _drain_moe)
+
+
+def moe_rows() -> dict:
+    """{"real": n, "dispatched": n} of ``fed_moe_rows_total``."""
+    fam = REGISTRY.snapshot().get("fed_moe_rows_total") or {}
+    return {kind: fam.get(f"kind={kind}", 0.0)
+            for kind in ("real", "dispatched")}
+
+
 # ------------------------------------------------------ the program store
 # core/program_store.py, docs/PERFORMANCE.md §Stored round programs:
 #

@@ -160,6 +160,68 @@ def test_resnet56_local_fit_compiles_for_v5e(one_chip, no_persistent_cache,
     assert ("fed_conv_packed" in compiled.as_text()) == (path == "packed")
 
 
+def test_lfm2_moe_folded_round_compiles_for_v5e(one_chip,
+                                                no_persistent_cache):
+    """One round of the cross-silo language-model cell's fold
+    (benchmark/'s lfm2_24b_a2b_ep8: 4 silos one after another, 2 batches of
+    8 x 2048 tokens, float32 at ``highest``) at the cut's widths, from
+    ``jax.eval_shape`` weights. One layer of each kind is kept (the dense
+    short convolution, then attention with its expert layer): the whole cut
+    of five layers compiles in 107 s on this host and was compiled by hand
+    (12.6 GB of arguments and temporaries, CHANGES.md PR 33). The fold's four copies of the weights and a step's
+    temporaries fit the chip, and the scopes that ``chip_scopes.py`` reads
+    reach the compiled program."""
+    import json
+    import os
+    import types
+
+    import optax
+
+    from fedml_tpu.algorithms.fedavg import _gather_rows, _make_client_keys
+    from fedml_tpu.core import client_fold
+    from fedml_tpu.core.local import LocalSpec, make_local_update
+    from fedml_tpu.core.tasks import routed_sequence_task
+    from fedml_tpu.models.lfm2_moe import Lfm2MoeLM
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2_24b_a2b_ep8.json")) as f:
+        sizes = json.load(f)["model"]["kwargs"]
+    sizes = dict(sizes, layer_types=["conv", "full_attention"])
+    task = routed_sequence_task(Lfm2MoeLM(**sizes))
+    engine = types.SimpleNamespace(
+        local_update=make_local_update(
+            task, LocalSpec(optimizer=optax.sgd(0.001))),
+        client_result_hook=None, _agg_weights=lambda n: n,
+        _update_from_aggregate=lambda net, avg, opt, key: (avg, opt))
+    make_step = client_fold.make_step(engine, _make_client_keys(17),
+                                      _gather_rows)
+
+    def one_round(net, dev_x, dev_y, idx, mask, nsamp, ids, keys):
+        carry, ms = make_step(dev_x, dev_y)(
+            (net, ()), (idx, mask, nsamp, ids, jnp.int32(0), keys, keys))
+        return carry[0], ms
+
+    silos, batches, bs, t = 4, 2, 8, 2048
+    net = jax.eval_shape(task.init, jax.random.PRNGKey(0),
+                         jnp.zeros((bs, t), jnp.int32))
+    args = (net, jnp.zeros((256, t), jnp.int32), jnp.zeros((256, t), jnp.int32),
+            jnp.zeros((silos, batches, bs), jnp.int32),
+            jnp.ones((silos, batches, bs), jnp.float32),
+            jnp.ones((silos,), jnp.float32), jnp.zeros((silos,), jnp.int32),
+            jax.random.PRNGKey(0))
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(one_round, donate_argnums=(0,)).lower(
+            *_on_chip(args, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert 0 < mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 << 30
+    text = compiled.as_text()
+    for scope in ("fed_client_fold", "fed_moe_route", "fed_moe_experts",
+                  "fed_short_conv", "fed_attention", "fed_gather"):
+        assert scope in text, scope
+    assert "tpu_custom_call" not in text  # XLA ops only
+
+
 @pytest.mark.parametrize("lays_out,fused", [("x", True), ("dy", False)])
 def test_norm_backward_fuses_into_packed_gradient_convs_for_v5e(
         one_chip, no_persistent_cache, monkeypatch, lays_out, fused):
