@@ -39,8 +39,16 @@ only; the tile loop, the dispatch and the combine carry their own backward
 passes so that all four row movements are gathers and the experts'
 gradients accumulate in place.
 
-Each block is rematerialised (``nn.remat``): a step keeps one hidden state
-a layer. Attention takes its queries in blocks of ``attention_query_block``
+Each block is rematerialised (``nn.remat``) and keeps, beside its input, the
+outputs of its matrix products as far as a byte budget allows
+(``kept_names``, ``KEPT_BYTES``): the backward pass reads them and does not
+run those products again. Norms, gates, the short convolution's taps, rotary
+angles, softmax and the routing plan are made again; they cost no product.
+At the benchmark cell's step (16,384 tokens, float32) a kept output weighs
+134 MB at width 2048, 403 MB at 6144, 772 MB at 11776, an expert layer's
+rows (12,288) 101 MB at width 2048 and 75.5 MB at 1536; a step too large
+for any of them keeps one hidden state a layer, as before the rule.
+Attention takes its queries in blocks of ``attention_query_block``
 against the keys up to the block's end, so that a step's scores never stand
 whole. ``moe_stats`` (collection, sown once a call where it is mutable) holds
 the held experts' token counts by layer, the rows that held an assignment,
@@ -58,6 +66,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from fedml_tpu.obs import perf_instrument
 
 STATS = "moe_stats"
 
@@ -104,12 +115,12 @@ def rope(x, theta: float):
 # ------------------------------------------------------------ short conv
 @jax.named_scope("fed_short_conv")
 def short_conv(u, in_proj, conv_kernel, out_proj):
-    b, c, x = jnp.split(u @ in_proj, 3, axis=-1)
+    b, c, x = jnp.split(checkpoint_name(u @ in_proj, "conv_in"), 3, axis=-1)
     bx = b * x
     taps, t = conv_kernel.shape[0], bx.shape[1]
     padded = jnp.pad(bx, ((0, 0), (taps - 1, 0), (0, 0)))
     z = sum(conv_kernel[j] * padded[:, j:j + t] for j in range(taps))
-    return (c * z) @ out_proj
+    return checkpoint_name((c * z) @ out_proj, "conv_out")
 
 
 # ------------------------------------------------------------- attention
@@ -130,17 +141,18 @@ def _attend_block(q, k, v, first):
 def attention(u, p, sz: Sizes):
     bsz, t, _ = u.shape
     nq, nkv, hd = sz.num_attention_heads, sz.num_key_value_heads, sz.head_dim
-    q = (u @ p["q_proj"]).reshape(bsz, t, nq, hd)
-    k = (u @ p["k_proj"]).reshape(bsz, t, nkv, hd)
-    v = (u @ p["v_proj"]).reshape(bsz, t, nkv, hd)
+    q = checkpoint_name(u @ p["q_proj"], "attn_q").reshape(bsz, t, nq, hd)
+    k = checkpoint_name(u @ p["k_proj"], "attn_k").reshape(bsz, t, nkv, hd)
+    v = checkpoint_name(u @ p["v_proj"], "attn_v").reshape(bsz, t, nkv, hd)
     q = rope(rms(q, p["q_norm"], sz.norm_eps), sz.rope_theta)
     k = rope(rms(k, p["k_norm"], sz.norm_eps), sz.rope_theta)
     q = q.reshape(bsz, t, nkv, nq // nkv, hd)  # head h reads kv head h // R
     qb = min(sz.attention_query_block, t)
-    out = jnp.concatenate(
+    out = checkpoint_name(jnp.concatenate(
         [_attend_block(q[:, i:i + qb], k[:, :i + qb], v[:, :i + qb], i)
-         for i in range(0, t, qb)], axis=1)
-    return out.reshape(bsz, t, nq * hd) @ p["o_proj"]
+         for i in range(0, t, qb)], axis=1), "attn_context")
+    return checkpoint_name(out.reshape(bsz, t, nq * hd) @ p["o_proj"],
+                           "attn_out")
 
 
 # ----------------------------------------------------------- expert layer
@@ -157,6 +169,11 @@ def route(m, router, expert_bias, k: int, scale: float):
                                precision=lax.Precision.HIGHEST))
     bias = lax.stop_gradient(expert_bias.astype(jnp.float32))
     _, chosen = lax.top_k(s + bias, k)
+    # kept with the tiles' outputs, whose rows lie where THIS choice put
+    # them: a backward pass that chose again from hidden states made again
+    # can break a near tie the other way, and every later row of that
+    # expert's group would then meet another token's kept output
+    chosen = checkpoint_name(chosen, "moe_chosen")
     picked = jnp.take_along_axis(s, chosen, axis=-1)
     w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6) * scale
     return chosen, w
@@ -282,7 +299,10 @@ def _tiled_fwd(x, w1, w3, w2, tile_expert):
         return None, ((jax.nn.silu(h1) * h3) @ _pick(w2, e), h1, h3)
 
     _, (y, h1, h3) = lax.scan(one, None, (tiles, tile_expert))
-    return y.reshape(x.shape), (tiles, h1, h3, w1, w3, w2, tile_expert)
+    tiles = checkpoint_name(tiles, "moe_tiles")
+    h1, h3 = checkpoint_name(h1, "moe_h1"), checkpoint_name(h3, "moe_h3")
+    y = checkpoint_name(y.reshape(x.shape), "moe_y")
+    return y, (tiles, h1, h3, w1, w3, w2, tile_expert)
 
 
 def _tiled_bwd(res, dy):
@@ -333,12 +353,17 @@ def _full_size(lo: int, m, chosen, w, w1, w3, w2, plan):
     return f
 
 
+def _budget_rows(sz: Sizes, n: int) -> int:
+    tile = sz.moe_tile_rows
+    return max(math.ceil(sz.moe_row_budget * n / tile), 1) * tile
+
+
 def expert_layer(m, p, sz: Sizes):
     """(f [N, D], stats) for m [N, D]: the held experts' part of the
     routed sum."""
     lo, hi = sz.experts_held
     n, tile = m.shape[0], sz.moe_tile_rows
-    rows = max(math.ceil(sz.moe_row_budget * n / tile), 1) * tile
+    rows = _budget_rows(sz, n)
     chosen, w = route(m, p["router"], p["expert_bias"],
                       sz.num_experts_per_tok, sz.routed_scaling_factor)
     with jax.named_scope("fed_moe_experts"):
@@ -354,6 +379,89 @@ def expert_layer(m, p, sz: Sizes):
                                           float((hi - lo) * n)),
              "fallback_steps": 1.0 - fits.astype(jnp.float32)}
     return f, lax.stop_gradient(stats)
+
+
+# ------------------------------------------- what a block keeps of its pass
+# The bytes of named outputs that one step's blocks may keep for their
+# backward passes, set from chip readings (one v5e, PERF.md section 6, PR
+# 34) so that the benchmark cell's peak stays at least 1 GiB under the
+# allocator's limit (``bytes_limit`` 16,909,336,064): at 16,384 tokens a step
+# the rule then keeps 5,168,431,104 bytes by the shapes, every named output
+# but the expert layers' dispatched rows, and the allocator's peak reads
+# 15,283,312,128 (1.51 GiB under; 11,082,651,136 with nothing kept). The
+# model cannot ask the device; an engine that can has only to hand its own
+# figure to ``kept_names``. Under ``vmap`` over K clients the shapes seen
+# here are one client's and K times the bytes are kept: fold the clients.
+KEPT_BYTES = 5_200_000_000
+
+
+def block_outputs(sz: Sizes, kind: str, dense: bool, batch: int,
+                  seq_len: int, itemsize: int):
+    """What a block's matrix products leave that its backward pass reads,
+    as [(names, bytes, operations)]: the bytes the named outputs of one step
+    weigh, and the forward operations that are not run again where they are
+    kept. Each entry stands alone (keeping one saves its own product
+    whatever else is kept), which is why the expert tiles' three products
+    are one entry: one loop makes them, and it runs again whole if any of
+    the three is missing. The experts chosen (int32) are kept with them:
+    they say which token a kept row belongs to."""
+    n, d = batch * seq_len, sz.hidden_size
+    nq, nkv, hd = sz.num_attention_heads, sz.num_key_value_heads, sz.head_dim
+
+    def product(name, inner, width):
+        return (name,), n * width * itemsize, 2 * n * inner * width
+
+    if kind == "conv":
+        out = [product("conv_in", d, 3 * d), product("conv_out", d, d)]
+    else:
+        qb = min(sz.attention_query_block, seq_len)
+        # query-key pairs of a sequence: each block against the keys up to
+        # its end; scores and values are two products over them
+        pairs = sum((min(i + qb, seq_len) - i) * min(i + qb, seq_len)
+                    for i in range(0, seq_len, qb))
+        out = [product("attn_q", d, nq * hd), product("attn_k", d, nkv * hd),
+               product("attn_v", d, nkv * hd),
+               (("attn_context",), n * nq * hd * itemsize,
+                4 * batch * pairs * nq * hd),
+               product("attn_out", nq * hd, d)]
+    if dense:
+        f = sz.intermediate_size
+        return out + [product("mlp_h1", d, f), product("mlp_h3", d, f)]
+    f, rows = sz.moe_intermediate_size, _budget_rows(sz, n)
+    return out + [
+        (("moe_chosen", "moe_h1", "moe_h3", "moe_y"),
+         rows * (2 * f + d) * itemsize + n * sz.num_experts_per_tok * 4,
+         6 * rows * d * f),
+        (("moe_tiles",), rows * d * itemsize, 0)]  # a gather, no product
+
+
+def kept_names(sz: Sizes, layer_types, num_dense_layers: int, batch: int,
+               seq_len: int, itemsize: int, budget: float):
+    """Which named outputs each block keeps for its backward pass, from the
+    step's static shapes alone: ({name: kept} by layer, bytes kept).
+
+    The entries of ``block_outputs`` over all layers are ranked by the
+    operations saved a byte kept (twice the product's inner width over the
+    item size, so ties are common: the lighter first, then the earlier
+    layer) and taken in that order while their bytes stay inside
+    ``budget``; the first that does not fit ends it. At a given sequence
+    length the order is the same for every batch and every entry's bytes
+    grow with the batch, so a larger batch keeps a subset of what a smaller
+    one keeps, and in the end nothing: the block then keeps its input alone.
+    (Longer sequences move one entry up, attention's context, whose
+    products grow with the keys seen.)"""
+    ranked = []
+    for layer, kind in enumerate(layer_types):
+        for names, nbytes, ops in block_outputs(
+                sz, kind, layer < num_dense_layers, batch, seq_len, itemsize):
+            ranked.append((-ops / nbytes, nbytes, layer, names))
+    plan = [{} for _ in layer_types]
+    total, fits = 0, True
+    for _, nbytes, layer, names in sorted(ranked):
+        fits = fits and total + nbytes <= budget
+        total += nbytes if fits else 0
+        plan[layer].update(dict.fromkeys(names, fits))
+    return plan, total
 
 
 # ------------------------------------------------------------ the modules
@@ -393,10 +501,14 @@ class Lfm2Block(nn.Module):
             h = h + attention(u, p, sz)
         m = rms(h, par("ffn_norm", ones, d), sz.norm_eps)
         if self.dense:
+            # gated_mlp written out: its two outputs are named here and not
+            # in the function, which the expert layer's full-size path runs
+            # for every held expert
             f = sz.intermediate_size
-            return h + gated_mlp(m, par("w1", _matrix(), d, f),
-                                 par("w3", _matrix(), d, f),
-                                 par("w2", _matrix(), f, d)), None
+            h1 = checkpoint_name(m @ par("w1", _matrix(), d, f), "mlp_h1")
+            h3 = checkpoint_name(m @ par("w3", _matrix(), d, f), "mlp_h3")
+            return h + (jax.nn.silu(h1) * h3) @ par("w2", _matrix(), f, d), \
+                None
         f, held = sz.moe_intermediate_size, sz.experts_held[1] - sz.experts_held[0]
         stacked = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                                batch_axis=(0,))
@@ -462,11 +574,16 @@ class Lfm2MoeLM(nn.Module):
                                (self.vocab_size, self.hidden_size),
                                jnp.float32)
         h = jnp.take(embedding, tokens, axis=0)
-        block = nn.remat(Lfm2Block)
+        plan, kept_bytes = kept_names(
+            sz, self.layer_types, self.num_dense_layers, *tokens.shape,
+            h.dtype.itemsize, KEPT_BYTES)
+        perf_instrument.record_remat(plan, kept_bytes)
         stats = []
         for i, kind in enumerate(self.layer_types):
-            h, st = block(sz, kind, i < self.num_dense_layers,
-                          name=f"layer_{i}")(h)
+            keep = jax.checkpoint_policies.save_only_these_names(
+                *(name for name, kept in plan[i].items() if kept))
+            h, st = nn.remat(Lfm2Block, policy=keep)(
+                sz, kind, i < self.num_dense_layers, name=f"layer_{i}")(h)
             if st is not None:
                 stats.append(st)
         if stats and self.is_mutable_collection(STATS):

@@ -175,16 +175,16 @@ class MetricsRegistry:
                 fam[1][key] = child
             return child
 
-    def counter(self, name: str, **labels) -> Counter:
+    def counter(self, name: str, /, **labels) -> Counter:
         return self._child("counter", Counter, name, labels)
 
-    def gauge(self, name: str, **labels) -> Gauge:
+    def gauge(self, name: str, /, **labels) -> Gauge:
         return self._child("gauge", Gauge, name, labels)
 
-    def histogram(self, name: str, **labels) -> Histogram:
+    def histogram(self, name: str, /, **labels) -> Histogram:
         return self._child("histogram", Histogram, name, labels)
 
-    def remove(self, name: str, **labels) -> bool:
+    def remove(self, name: str, /, **labels) -> bool:
         """Drop one child from a family (True when it existed). The
         cardinality-maintenance escape hatch for per-rank gauges on
         fleet-sized cohorts (obs/comm_instrument heartbeat cap) — callers

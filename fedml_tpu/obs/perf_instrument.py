@@ -311,11 +311,49 @@ def client_fold_counts() -> dict:
     return {label_s.split("=", 1)[1]: n for label_s, n in fam.items()}
 
 
+# ------------------------------------------- what a block keeps of its pass
+# models/lfm2_moe.py ``kept_names``, docs/PERFORMANCE.md §Folded silos. Fed
+# at TRACE time by the model, once for each call of it that is traced (its
+# ``init`` too); a program the store loads counts again what its trace
+# counted:
+#
+#     fed_remat_sites_total{name,kept}  named outputs of the rematerialised
+#                                       blocks' matrix products, one count a
+#                                       block that has the name: kept=yes is
+#                                       kept for the backward pass, kept=no
+#                                       is made again there
+#     fed_remat_kept_bytes_total        bytes one step keeps so, summed over
+#                                       the traced calls
+@lru_cache(maxsize=32)
+def _remat_sites(name: str, kept: str):
+    return REGISTRY.counter("fed_remat_sites_total", name=name, kept=kept)
+
+
+def record_remat(plan, kept_bytes: float) -> None:
+    """``plan``: for each block ``{name: kept}``."""
+    for block in plan:
+        for name, kept in block.items():
+            _remat_sites(name, "yes" if kept else "no").inc()
+    _counter("fed_remat_kept_bytes_total").inc(kept_bytes)
+
+
+def remat_counts() -> dict:
+    """{"sites": {(name, kept): n}, "kept_bytes": n} so far."""
+    fam = REGISTRY.snapshot().get("fed_remat_sites_total") or {}
+    sites = {}
+    for label_s, n in fam.items():
+        labels = dict(kv.split("=", 1) for kv in label_s.split(","))
+        sites[labels["name"], labels["kept"]] = n
+    return {"sites": sites,
+            "kept_bytes": REGISTRY.total("fed_remat_kept_bytes_total")}
+
+
 def traced_counts() -> dict:
     """What traces have counted so far, for the program store to diff
     around a trace and keep with the program."""
     return {"conv_sites": conv_site_counts(),
-            "client_fold": client_fold_counts()}
+            "client_fold": client_fold_counts(),
+            "remat": remat_counts()}
 
 
 def traced_since(before: dict) -> dict:
@@ -328,7 +366,13 @@ def traced_since(before: dict) -> dict:
     folds = [[mode, n - before["client_fold"].get(mode, 0)]
              for mode, n in sorted(now["client_fold"].items())
              if n > before["client_fold"].get(mode, 0)]
-    return {"conv_sites": sites, "client_fold": folds}
+    was = before["remat"]
+    remat = {"sites": [[name, kept, n - was["sites"].get((name, kept), 0)]
+                       for (name, kept), n in sorted(now["remat"]["sites"]
+                                                     .items())
+                       if n > was["sites"].get((name, kept), 0)],
+             "kept_bytes": now["remat"]["kept_bytes"] - was["kept_bytes"]}
+    return {"conv_sites": sites, "client_fold": folds, "remat": remat}
 
 
 def replay_traced(header: dict) -> None:
@@ -336,6 +380,10 @@ def replay_traced(header: dict) -> None:
     replay_conv_sites(header.get("conv_sites", ()))
     for mode, n in header.get("client_fold", ()):
         record_client_fold(str(mode), n)
+    remat = header.get("remat", {})
+    for name, kept, n in remat.get("sites", ()):
+        _remat_sites(str(name), str(kept)).inc(n)
+    _counter("fed_remat_kept_bytes_total").inc(remat.get("kept_bytes", 0))
 
 
 # ------------------------------------------------------- the expert layer

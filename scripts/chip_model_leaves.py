@@ -11,8 +11,10 @@ change itself against another checkout's.
     # anywhere:
     python3 scripts/chip_model_leaves.py compare <a.npz> <b.npz>
 
-``dump`` also prints the first block's losses, the program store's counters
-and the set-up split. ``compare`` prints one JSON line: ``compared`` (the cell's own ``dparam`` and
+``dump`` also prints the first block's losses, the program store's counters,
+the set-up split, what the rematerialised blocks keep (``fed_remat_*``) and
+the allocator's limit and peaks. A language model's leaves are gigabytes:
+give ``--out`` a path outside ``chiprun_out/`` and compare on the chip. ``compare`` prints one JSON line: ``compared`` (the cell's own ``dparam`` and
 ``dparam_med`` with b in the reference's place), ``leaf_rel`` (for each leaf
 the norm of a's model minus b's over the norm of b's change from the shared
 weights: worst leaf, its name, the median leaf), the five losses' relative
@@ -32,6 +34,8 @@ sys.path.insert(0, os.getcwd())
 
 
 def dump(workload: str, seed: int, out: str) -> None:
+    import jax
+
     from benchmark import cells, check, run
 
     cell = cells.load_cell(cells.load_benchmark(), workload)
@@ -52,12 +56,23 @@ def dump(workload: str, seed: int, out: str) -> None:
     from fedml_tpu.obs import perf_instrument as perf
     from fedml_tpu.obs.metrics import REGISTRY
 
-    store = {k: v for k, v in REGISTRY.snapshot().items()
+    snap = REGISTRY.snapshot()
+    store = {k: v for k, v in snap.items()
              if k.startswith("fed_program_store")}
+    # what the rematerialised blocks keep (models/lfm2_moe.py kept_names)
+    # beside what the allocator says of the room: empty in a checkout from
+    # before the rule, and for a model without such blocks
+    remat = {k: v for k, v in snap.items() if k.startswith("fed_remat")}
+    mem = jax.devices()[0].memory_stats() or {}
     print(json.dumps({"out": out, "losses": prog["losses"],
                       "program_store": store,
                       "setup_phases": perf.setup_phases(),
-                      "conv_sites": perf.conv_sites()}), flush=True)
+                      "conv_sites": perf.conv_sites(), "remat": remat,
+                      "memory": {k: mem.get(k) for k in (
+                          "bytes_limit", "peak_bytes_in_use",
+                          "largest_alloc_size", "bytes_reserved",
+                          "peak_bytes_reserved")}}),
+          flush=True)
 
 
 def compare(path_a: str, path_b: str) -> dict:

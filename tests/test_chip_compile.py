@@ -167,10 +167,16 @@ def test_lfm2_moe_folded_round_compiles_for_v5e(one_chip,
     8 x 2048 tokens, float32 at ``highest``) at the cut's widths, from
     ``jax.eval_shape`` weights. One layer of each kind is kept (the dense
     short convolution, then attention with its expert layer): the whole cut
-    of five layers compiles in 107 s on this host and was compiled by hand
-    (12.6 GB of arguments and temporaries, CHANGES.md PR 33). The fold's four copies of the weights and a step's
-    temporaries fit the chip, and the scopes that ``chip_scopes.py`` reads
-    reach the compiled program."""
+    of five layers compiles in 70 to 107 s on this host and was compiled by
+    hand. With the blocks keeping their input alone it reads 1.88 GB of
+    arguments + 10.72 GB of temporaries = 12.60 GB (CHANGES.md PR 33; the
+    chip's allocator then read 11.08 GB); with the rule of
+    ``lfm2_moe.kept_names`` on at ``KEPT_BYTES`` (every product's output
+    kept, 5.17 GB by the shapes) 1.88 + 16.40 = 18.29 GB, where the chip's
+    own compile fits the step in a peak of 15.28 GB (CHANGES.md PR 34: this
+    analysis counts the kept outputs about twice). The fold's four copies
+    of the weights and a step's temporaries fit the chip, and the scopes
+    that ``chip_scopes.py`` reads reach the compiled program."""
     import json
     import os
     import types

@@ -192,6 +192,57 @@ def test_client_fold_moves_the_key_and_a_hit_replays_the_count(
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_kept_outputs_are_counted_at_trace_and_a_hit_replays_them(
+        sequences, cache_dir):
+    """``fed_remat_sites_total`` and ``fed_remat_kept_bytes_total``
+    (models/lfm2_moe.py ``kept_names``): the block's trace counts each
+    named output of each block and the bytes the rule keeps, the store
+    keeps them in the record's header, and a hit counts them again."""
+    from fedml_tpu.models import lfm2_moe
+
+    sizes = dict(SMALL, moe_row_budget=4.0)
+    model = Lfm2MoeLM(**sizes)
+    task = routed_sequence_task(model)
+    cfg = _cfg(client_num_in_total=3, batch_size=2, wd=0.0,
+               client_fold="scan")
+    plan, step_bytes = lfm2_moe.kept_names(
+        model.sizes(), model.layer_types, model.num_dense_layers, 2, SEQ_LEN,
+        4, lfm2_moe.KEPT_BYTES)
+    assert step_bytes > 0 and all(all(layer.values()) for layer in plan)
+
+    def counted(run):
+        c0 = perf.remat_counts()
+        run()
+        c1 = perf.remat_counts()
+        return ({k: n - c0["sites"].get(k, 0)
+                 for k, n in c1["sites"].items()
+                 if n > c0["sites"].get(k, 0)},
+                c1["kept_bytes"] - c0["kept_bytes"])
+
+    api = FedAvgAPI(sequences, task, cfg, device_data=True)
+    s0 = perf.program_store_counts()
+    sites, nbytes = counted(
+        lambda: jax.block_until_ready(api.run_rounds(0, 2)))
+    assert _delta(s0) == {"miss": 1.0}
+    # every name is kept at this size; a name counts once a block that has
+    # it, in each trace of the model, and the bytes are the rule's own
+    traces = sites["attn_q", "yes"] / SMALL["layer_types"].count(
+        "full_attention")
+    assert traces >= 1 and nbytes == traces * step_bytes
+    assert sites == {(name, "yes"): traces * sum(name in layer
+                                                 for layer in plan)
+                     for layer in plan for name in layer}
+    again = FedAvgAPI(sequences, task, cfg, device_data=True)
+    replayed = counted(
+        lambda: jax.block_until_ready(again.run_rounds(0, 2)))
+    assert _delta(s0) == {"miss": 1.0, "hit": 1.0}
+    assert replayed == (sites, nbytes)
+    # a record from before the counters has no such key
+    before = perf.remat_counts()
+    perf.replay_traced({"conv_sites": [], "client_fold": [["scan", 1]]})
+    assert perf.remat_counts() == before
+
+
 class _Overrides(FedAvgAPI):
     def _round_body(self, *a, **kw):
         return super()._round_body(*a, **kw)

@@ -4,6 +4,8 @@ seeded weights, float32. The expert layer is told which experts it holds;
 the shares add up to the uncut layer, and the exact path of full size
 equals the budgeted one."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -191,3 +193,206 @@ def test_stats_are_sown_once_a_call():
     # and nothing where the collection is not asked for
     assert Lfm2MoeLM(**SMALL).apply({"params": params}, _tokens()).shape \
         == (3, T, SMALL["vocab_size"])
+
+
+# ---------------------------------------------- what a block keeps of its pass
+# one short convolution with the dense layer, one attention layer with
+# experts, on the budgeted path
+TWO = dict(SMALL, layer_types=["conv", "full_attention"], num_dense_layers=1,
+           **ROOMY)
+BUDGETS = {"zero": 0, "the_constant": lfm2_moe.KEPT_BYTES,
+           "unbounded": float("inf")}
+
+
+def _grads(sizes, monkeypatch, budget):
+    monkeypatch.setattr(lfm2_moe, "KEPT_BYTES", budget)
+    model, tokens = Lfm2MoeLM(**sizes), _tokens()
+    params = ref.make(sizes)[0](jax.random.PRNGKey(3))
+    return jax.jit(jax.value_and_grad(lambda p: _loss(
+        model.apply({"params": p}, tokens), tokens)))(params)
+
+
+@pytest.mark.parametrize("budget", BUDGETS)
+def test_kept_outputs_leave_loss_and_gradients_as_they_were(budget,
+                                                            monkeypatch):
+    """A kept output and one made again are the same numbers: whatever the
+    budget keeps, the loss and every gradient leaf are those of the block
+    that keeps its input alone, and the reference's."""
+    (_, grad_r), _ = _both(TWO, _tokens())[::-1]
+    loss_0, grad_0 = _grads(TWO, monkeypatch, 0)
+    loss, grad = _grads(TWO, monkeypatch, BUDGETS[budget])
+    assert abs(float(loss) - float(loss_0)) < 1e-6
+    assert _worst_leaf(grad, grad_0) < 1e-6
+    assert _worst_leaf(grad, grad_r) < 1e-5
+
+
+def _block(kind, dense, sizes=TWO, batch=3):
+    """One block, its weights and an input; the names of its outputs with
+    the shapes they have there."""
+    sz = Lfm2MoeLM(**sizes).sizes()
+    h = jax.random.normal(jax.random.PRNGKey(0), (batch, T, sz.hidden_size))
+    block = lfm2_moe.Lfm2Block(sz, kind, dense)
+    d, hd, f = sz.hidden_size, sz.head_dim, sz.moe_intermediate_size
+    nq, nkv = sz.num_attention_heads, sz.num_key_value_heads
+    rows, tile = lfm2_moe._budget_rows(sz, batch * T), sz.moe_tile_rows
+    shapes = {
+        "conv_in": (batch, T, 3 * d), "conv_out": (batch, T, d),
+        "attn_q": (batch, T, nq * hd), "attn_k": (batch, T, nkv * hd),
+        "attn_v": (batch, T, nkv * hd), "attn_out": (batch, T, d),
+        "attn_context": (batch, T, nkv, nq // nkv, hd),
+        "mlp_h1": (batch, T, sz.intermediate_size),
+        "mlp_h3": (batch, T, sz.intermediate_size),
+        "moe_chosen": (batch * T, sz.num_experts_per_tok),
+        "moe_tiles": (rows // tile, tile, d), "moe_h1": (rows // tile, tile, f),
+        "moe_h3": (rows // tile, tile, f), "moe_y": (rows, d)}
+    entries = lfm2_moe.block_outputs(sz, kind, dense, batch, T, 4)
+    return block, block.init(jax.random.PRNGKey(1), h), h, entries, shapes
+
+
+def _residuals(block, params, h, policy):
+    """(shapes kept that are no argument of the block, the gradient)."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    fn = jax.checkpoint(lambda p, x: jnp.sum(jnp.sin(block.apply(p, x)[0])),
+                        policy=policy)
+    # integers that jax derives from a kept one (``take_along_axis``'s
+    # indices from the experts chosen) ride along unnamed: not counted
+    kept = [aval.shape for aval, why in saved_residuals(fn, params, h)
+            if "from the argument" not in why and "constant" not in why
+            and (jnp.issubdtype(aval.dtype, jnp.floating) or "named" in why)]
+    return sorted(kept), jax.jit(jax.grad(fn))(params, h)
+
+
+KINDS = {"conv_dense": ("conv", True), "attention_experts":
+         ("full_attention", False)}
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_an_empty_budget_keeps_the_blocks_input_alone(kind):
+    """No name kept: the residuals are the block's arguments, as under a
+    ``jax.checkpoint`` with no policy, and the gradient is that one's bit
+    for bit."""
+    block, params, h, _, _ = _block(*KINDS[kind])
+    kept_0, grad_0 = _residuals(
+        block, params, h, jax.checkpoint_policies.save_only_these_names())
+    kept_none, grad_none = _residuals(block, params, h, None)
+    assert kept_0 == kept_none == []
+    for a, b in zip(jax.tree.leaves(grad_0), jax.tree.leaves(grad_none)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_an_empty_budget_traces_the_model_without_a_policy(monkeypatch):
+    """The whole model at budget zero against ``nn.remat`` with no policy
+    (the call before the rule): bit for bit."""
+    loss_0, grad_0 = _grads(TWO, monkeypatch, 0)
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+    loss_none, grad_none = _grads(TWO, monkeypatch, 0)
+    assert float(loss_0) == float(loss_none)
+    for a, b in zip(jax.tree.leaves(grad_0), jax.tree.leaves(grad_none)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_block_keeps_each_kept_name_and_none_of_the_others(kind):
+    """Entry by entry of ``block_outputs``: with the first n kept, what the
+    block saves beside its arguments has their shapes and no other; an
+    entry's bytes are its names' shapes."""
+    block, params, h, entries, shapes = _block(*KINDS[kind])
+    for names, nbytes, _ in entries:
+        assert nbytes == 4 * sum(math.prod(shapes[n]) for n in names)
+    for n in range(len(entries) + 1):
+        names = [name for e in entries[:n] for name in e[0]]
+        kept, _ = _residuals(
+            block, params, h,
+            jax.checkpoint_policies.save_only_these_names(*names))
+        assert kept == sorted(shapes[name] for name in names), names
+
+
+def test_the_full_size_path_keeps_nothing_stacked_over_the_experts():
+    """``gated_mlp`` is the full-size path's body too, run once for each
+    held expert: a name inside it would keep its outputs ``held`` times
+    over, on the path not taken as well. An expert block whose budget its
+    tiles overflow, every name kept: no residual is stacked over the held
+    experts."""
+    sizes = dict(TWO, moe_row_budget=0.01)
+    block, params, h, entries, shapes = _block("conv", False, sizes)
+    held = sizes["experts_held"][1] - sizes["experts_held"][0]
+    _, stats = block.apply(params, h)
+    assert float(stats["fallback_steps"]) == 1.0
+    every = [name for e in entries for name in e[0]]
+    kept, _ = _residuals(block, params, h,
+                         jax.checkpoint_policies.save_only_these_names(
+                             *every, "mlp_h1", "mlp_h3"))
+    assert kept == sorted(shapes[name] for name in every)
+    assert not [s for s in kept if s[:2] == (held, h.shape[0] * T)]
+
+
+def _cell_sizes():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2_24b_a2b_ep8.json")) as f:
+        return json.load(f)["model"]["kwargs"]
+
+
+def _kept(model, batch, seq_len, budget=None):
+    plan, nbytes = lfm2_moe.kept_names(
+        model.sizes(), model.layer_types, model.num_dense_layers, batch,
+        seq_len, 4, lfm2_moe.KEPT_BYTES if budget is None else budget)
+    return {(i, name) for i, layer in enumerate(plan)
+            for name, kept in layer.items() if kept}, nbytes
+
+
+@pytest.mark.parametrize("seq_len", [512, 2048, 8192])
+def test_kept_names_a_larger_step_keeps_no_more(seq_len):
+    """At the cell's widths, batch after batch: what a larger step keeps a
+    smaller one kept, its bytes are inside the constant, a small step
+    keeps every name and a large one none."""
+    model = Lfm2MoeLM(**_cell_sizes())
+    every, _ = _kept(model, 1, seq_len, float("inf"))
+    assert len(every) == 2 * 4 + 5 + 2 + 4 * 5
+    was = every
+    for batch in (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 64, 256, 1 << 14):
+        now, nbytes = _kept(model, batch, seq_len)
+        assert now <= was and nbytes <= lfm2_moe.KEPT_BYTES
+        was = now
+    assert _kept(model, 1, 512)[0] == every and not was
+
+
+def test_kept_names_at_the_cells_step():
+    """16,384 tokens a step: every product's output is kept, the expert
+    layers' dispatched rows (a gather, no product: the last in rank) are
+    not, and longer sequences at the cell's batch keep no more."""
+    model = Lfm2MoeLM(**_cell_sizes())
+    kept, nbytes = _kept(model, 8, 2048)
+    assert nbytes == 5_168_431_104 <= lfm2_moe.KEPT_BYTES
+    every, _ = _kept(model, 8, 2048, float("inf"))
+    assert every - kept == {(i, "moe_tiles") for i in (1, 2, 3, 4)}
+    was = _kept(model, 8, 256)[0]
+    for seq_len in (512, 1024, 1536, 2048, 3072, 4096, 8192, 32768):
+        now = _kept(model, 8, seq_len)[0]
+        assert now <= was, seq_len
+        was = now
+
+
+def test_kept_tile_outputs_keep_the_choice_that_laid_them_out():
+    """The rows of a kept ``moe_h1`` lie where the forward pass's top-k put
+    them. A backward pass that chose again (from hidden states made again,
+    which a compiler may round otherwise) could break a near tie the other
+    way and shift every later row of that expert's group: with the tiles'
+    outputs kept the experts chosen are kept too and top-k runs once; with
+    nothing kept the whole block, its choice included, is made again."""
+    block, params, h, entries, _ = _block("full_attention", False)
+    group = next(names for names, _, _ in entries if "moe_h1" in names)
+    assert "moe_chosen" in group
+
+    def top_ks(names):
+        fn = jax.checkpoint(
+            lambda p, x: jnp.sum(jnp.sin(block.apply(p, x)[0])),
+            policy=jax.checkpoint_policies.save_only_these_names(*names))
+        return str(jax.make_jaxpr(jax.grad(fn))(params, h)).count("top_k")
+
+    assert top_ks(()) == 2 and top_ks(group) == 1

@@ -220,6 +220,7 @@ def test_loaded_program_is_accounted_to_block_fn(images, lr_task, cache_dir):
     """On a hit the round program's compile events are the stored jit's
     own: a small trace, a lowering and a compile or load, all under
     ``block_fn``, and nothing under ``_export``."""
+    before = perf.variant_compile_stats()  # whatever shared this process
     jax.block_until_ready(_api(images, lr_task).run_rounds(0, 2))
     st0 = perf.variant_compile_stats()
     p0 = perf.setup_phases()
@@ -233,9 +234,12 @@ def test_loaded_program_is_accounted_to_block_fn(images, lr_task, cache_dir):
     assert p["trace_s"] + p["lower_s"] > p0["trace_s"] + p0["lower_s"]
     # what a miss traced on its way into the store is inside the stored
     # jit's own trace time, and is not summed a second time
-    export = st0[perf.EXPORT_VARIANT]
-    assert export["trace_seconds"] > 0 and export["lower_seconds"] > 0
-    assert export["trace_seconds"] <= st0["block_fn"]["trace_seconds"]
+    def since(variant, key):
+        return st0[variant][key] - before.get(variant, {}).get(key, 0.0)
+
+    assert since(perf.EXPORT_VARIANT, "lower_seconds") > 0
+    assert 0 < since(perf.EXPORT_VARIANT, "trace_seconds") \
+        <= since("block_fn", "trace_seconds")
 
 
 def test_stored_jit_lowers_with_the_scopes(images, lr_task, cache_dir):
@@ -290,8 +294,10 @@ def test_key_changes_with(images, lr_task, cache_dir, monkeypatch, what):
         a = b = _api(images, lr_task)
     ka = _key(a)
     if what == "matmul_precision":
+        # another test file of this process may have left it at highest
         old = jax.config.jax_default_matmul_precision
-        jax.config.update("jax_default_matmul_precision", "highest")
+        jax.config.update("jax_default_matmul_precision",
+                          "default" if old == "highest" else "highest")
         try:
             kb = _key(b)
         finally:
