@@ -1165,10 +1165,12 @@ class FedAvgAPI:
 
             # jax.jit(block_fn) where no compile cache directory is set;
             # with one, a jit that loads the program a former process
-            # exported in place of tracing it (core/program_store.py)
+            # exported in place of tracing it (core/program_store.py); a
+            # block runs as many rounds as it is given round_idxs
             return handing_off(program_store.stored_jit(
                 block_fn, donate_argnums=(0, 1, 2),
-                reads=self._block_trace_reads), self.task)
+                reads=self._block_trace_reads,
+                rounds=lambda *args: args[-1].shape[0]), self.task)
 
         mesh = self.mesh
         axis = mesh.axis_names[0]
@@ -1397,8 +1399,7 @@ class FedAvgAPI:
             spans=spans or {},
             **({"pipeline": pipeline} if pipeline else {}))
         # the block's wall/spans/wait are amortized over its R rounds so
-        # each per-round record carries a comparable goodput block (the
-        # block variant's cost analysis covers R rounds -> cost_rounds=R)
+        # each per-round record carries a comparable goodput block
         R = max(int(num_rounds), 1)
         per_spans = {k: v / R for k, v in (spans or {}).items()}
         for i in range(num_rounds):
@@ -1730,8 +1731,8 @@ class FedAvgAPI:
                        block_rounds: int | None = None) -> dict:
         """The ``goodput`` block one round record carries (obs/goodput.py):
         exclusive duty-cycle buckets of this round's wall plus FLOPs/s and
-        MFU when the dispatched variant's cost analysis is cached. {} when
-        the wall was not measured."""
+        MFU when the round programs' matrix work is counted. {} when the
+        wall was not measured."""
         if wall_s is None:
             return {}
         B = ((pack_extra or {}).get("pack") or {}).get("bucket_B")
@@ -1741,7 +1742,6 @@ class FedAvgAPI:
             compute_wait_s=compute_wait_s)
         return {"goodput": _goodput.round_goodput(
             wall_s, buckets, variant=variant,
-            cost_rounds=block_rounds or 1,
             n_devices=(self.mesh.size if self.mesh is not None else 1))}
 
     def _goodput_interval(self) -> float:

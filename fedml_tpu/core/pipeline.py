@@ -270,15 +270,9 @@ def compile_concurrently(lowered: dict, max_workers: int | None = None) -> dict:
     vacuously).
 
     Each variant compiles inside ``_perf.attribute_compiles(name)`` (the
-    compile observatory's per-jit-name attribution) and its executable's
-    XLA cost analysis is cached under the same name
-    (``goodput.record_variant_cost``) — which is how a warmed-up engine's
-    round records later carry FLOPs/s without re-deriving cost at
-    dispatch time.
+    compile observatory's per-jit-name attribution).
     """
     from concurrent.futures import ThreadPoolExecutor
-
-    from fedml_tpu.obs import goodput as _goodput
 
     instrumented = _perf.install()
     c0, h0, m0, r0 = (_perf.compiles_total(), _perf.cache_hits_total(),
@@ -293,7 +287,6 @@ def compile_concurrently(lowered: dict, max_workers: int | None = None) -> dict:
         with _perf.attribute_compiles(n):
             exe = lowered[n].compile()
         per_variant[n] = {"seconds": time.perf_counter() - tv}
-        _goodput.record_variant_cost(n, exe)
         return exe
 
     if names:

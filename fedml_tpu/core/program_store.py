@@ -27,7 +27,10 @@ traces as it always has. No ``id()`` and no ``hash()`` enter the key: it is
 equal across processes.
 
 Outcomes are counted in ``fed_program_store_total{outcome}`` and timed in
-``fed_program_store_seconds_total{phase}`` (obs/perf_instrument.py).
+``fed_program_store_seconds_total{phase}`` (obs/perf_instrument.py). A miss
+also walks the trace it exports for the program's matrix work
+(``obs/work.py``) and keeps the counts in the record's header, which a hit
+counts again (``fed_matmul_flops_total``, ``perf_instrument.matmul_ops``).
 """
 
 from __future__ import annotations
@@ -53,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedml_tpu.obs import perf_instrument as _perf
+from fedml_tpu.obs import work
 
 log = logging.getLogger("fedml_tpu.core.program_store")
 
@@ -450,13 +454,16 @@ def read_record(path: str):
     return header, rest[:n_exp], rest[n_exp:]
 
 
-def _export(fun, leaves, in_tree):
+def _export(fun, leaves, in_tree, rounds: int = 1):
     """Trace ``fun`` once over flat leaves (``Exported.serialize`` refuses
     a tree of unregistered node types; a ``PyTreeDef`` itself pickles).
     Returns (serialized program, pickled output tree, what the trace
-    counted: ``perf_instrument.traced_since``). The export's own trace and lowering happen
-    inside the stored jit's trace, which reports them: they are tagged
-    apart (``EXPORT_VARIANT``), not counted twice."""
+    counted: ``perf_instrument.traced_since``). The export's own trace and
+    lowering happen inside the stored jit's trace, which reports them: they
+    are tagged apart (``EXPORT_VARIANT``), not counted twice. The export
+    takes the jit's trace from jit's own cache, so the model is traced once,
+    and that trace's jaxpr is walked for its matrix work (``obs/work.py``),
+    ``rounds`` rounds of it."""
     out_trees = []
 
     def flat(*flat_args):
@@ -470,8 +477,12 @@ def _export(fun, leaves, in_tree):
                                   weak_type=getattr(a, "weak_type", False))
              for a in map(jax.typeof, leaves)]
     before = _perf.traced_counts()
+    jitted = jax.jit(flat)
     with _perf.attribute_compiles(_perf.EXPORT_VARIANT):
-        exported = jax.export.export(jax.jit(flat))(*specs)
+        traced = jitted.trace(*specs)
+        exported = jax.export.export(jitted)(*specs)
+    with _timed("walk"):
+        _perf.record_matmul(work.matmul_work(traced.jaxpr, rounds))
     return (bytes(exported.serialize()), pickle.dumps(out_trees[-1]),
             _perf.traced_since(before))
 
@@ -534,7 +545,7 @@ def _timed(phase: str):
         _perf.record_program_store_seconds(phase, time.perf_counter() - t0)
 
 
-def _run(fun, args, donate_argnums, reads):
+def _run(fun, args, donate_argnums, reads, rounds):
     """The body of the stored jit: runs while jit traces it, once for each
     abstract signature."""
     directory = store_dir()
@@ -573,7 +584,8 @@ def _run(fun, args, donate_argnums, reads):
 
     with _timed("export"):
         try:
-            exported, out_tree, counted = _export(fun, leaves, in_tree)
+            exported, out_tree, counted = _export(
+                fun, leaves, in_tree, rounds(*args) if rounds else 1)
             header = dict(ingr, **counted, reads_in_clear=clear)
             # a miss runs what it stored: the executable this process
             # compiles is the one a later process's hit finds in the
@@ -592,22 +604,26 @@ def _run(fun, args, donate_argnums, reads):
     return out
 
 
-def stored_jit(fun, *, donate_argnums=(), reads):
+def stored_jit(fun, *, donate_argnums=(), reads, rounds=None):
     """``jax.jit(fun, donate_argnums=...)`` where no compile cache
     directory is set; else a jit of the same name, donation and interface
     (``.lower``) that loads ``fun``'s program from the store in place of
     tracing it (module docstring). ``reads()`` gives, by name, every value
     ``fun``'s trace reads that is not one of its arguments, or raises
-    :class:`Unkeyable`; it is called when the first call is traced."""
+    :class:`Unkeyable`; it is called when the first call is traced.
+    ``rounds(*args)`` gives the rounds one call runs, by which the matrix
+    work a miss counts is divided (one where it is None). The plain jit
+    counts no matrix work."""
     if store_dir() is None:
         return jax.jit(fun, donate_argnums=donate_argnums)
     _perf.ensure_program_store_families()
+    _perf.ensure_matmul_families()
     if _stored_program_p() is None:  # said in the log; and counted, here
         _perf.record_program_store("error")
         return jax.jit(fun, donate_argnums=donate_argnums)
 
     def program(*args):
-        return _run(fun, args, donate_argnums, reads)
+        return _run(fun, args, donate_argnums, reads, rounds)
 
     # perf_instrument.ROUND_PROGRAMS attributes compile events by this name
     program.__name__ = program.__qualname__ = fun.__name__

@@ -1,8 +1,8 @@
 """Round economics — goodput & duty-cycle accounting.
 
 Decomposes each round's wall-clock into EXCLUSIVE buckets and, when the
-compiled round variant's XLA cost analysis is known, turns the wall into
-useful-FLOPs/s, bytes/s and an MFU-style utilization figure. Three metric
+round programs' matrix work is counted, turns the wall into useful-FLOPs/s,
+bytes/s and an MFU-style utilization figure. Three metric
 families land in the process-wide ``metrics.REGISTRY``:
 
     fed_duty_cycle{bucket}            (gauge) fraction of the last round's
@@ -10,9 +10,9 @@ families land in the process-wide ``metrics.REGISTRY``:
                                       six buckets are exclusive and sum to
                                       1.0 by construction
     fed_goodput_flops_per_sec         (gauge) useful device FLOPs/s of the
-                                      last round (0 until a variant's cost
-                                      analysis is known)
-    fed_goodput_bytes_per_sec         (gauge) bytes-accessed/s, same caveat
+                                      last round (0 until a round program's
+                                      matrix work is counted)
+    fed_goodput_bytes_per_sec         (gauge) bytes/s of the same products
     fed_goodput_mfu                   (gauge) flops_per_sec / (per-chip
                                       peak x participating devices); 0
                                       when the device kind is unknown —
@@ -48,23 +48,27 @@ order above and each is capped at the wall-clock remaining, so overlapping
 or over-reported spans can never make the sum exceed the wall (the
 injected-clock oracle in tests/test_goodput.py pins sum == wall).
 
-**Cost model**: ``record_variant_cost(name, executable)`` caches
-``executable.cost_analysis()`` per jit variant name (``round_bf16_b8``,
-``block_bf16_r10_b8`` — the same names ``warmup()`` compiles under).
-Backends that don't report cost analysis yield ``None`` and goodput
-degrades to duty-cycle-only — graceful, never raising. Everything here is
-host-side and allocation-light; nothing is traced, so telemetry-off runs
-stay bit-identical (test-enforced).
+**Work model**: the round programs' own count of their matrix products
+a round (``perf_instrument.matmul_ops()``, walked where the program store
+exports a round program: obs/work.py). The useful FLOPs are the forward
+and gradient products; what a rematerialised region makes again
+(``run="recompute"``) is work the step does to save memory, not progress,
+and is left out. The bytes are those of every product. Where nothing was
+counted (the plain jit, a mesh) goodput is duty-cycle-only. The count is
+the process's: where it traced or loaded several round programs (blocks of
+two lengths) it is their sum. Everything here is host-side and
+allocation-light; nothing is traced, so telemetry-off runs stay
+bit-identical (test-enforced).
 """
 
 from __future__ import annotations
 
 import logging
 import sys
-import threading
 from functools import lru_cache
 
 from fedml_tpu.obs.metrics import REGISTRY
+from fedml_tpu.obs.perf_instrument import matmul_ops
 from fedml_tpu.utils.flops import peak_for_kind
 
 log = logging.getLogger("fedml_tpu.obs.goodput")
@@ -91,55 +95,6 @@ def device_peak_flops(device_kind: str | None = None) -> float | None:
                       exc_info=True)
             return None
     return peak_for_kind(device_kind)
-
-
-# ------------------------------------------------------- cost-model cache
-_cost_lock = threading.Lock()
-_COSTS: dict[str, dict | None] = {}
-
-
-def record_variant_cost(name: str, executable) -> dict | None:
-    """Cache ``executable.cost_analysis()`` under the jit variant ``name``.
-
-    Returns ``{"flops": float|None, "bytes": float|None}`` or None when the
-    backend doesn't report a cost model (CPU builds without it, mocked
-    executables, ...) — callers never see an exception. Called by
-    ``compile_concurrently`` for every AOT-compiled variant, so any engine
-    that warms up gets per-variant cost for free."""
-    ent = None
-    try:
-        ca = executable.cost_analysis()
-        # older jax returns [dict] per device program; current returns dict
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else None
-        if isinstance(ca, dict):
-            flops = ca.get("flops")
-            nbytes = ca.get("bytes accessed")
-            if flops is not None or nbytes is not None:
-                ent = {
-                    "flops": float(flops) if flops is not None else None,
-                    "bytes": float(nbytes) if nbytes is not None else None,
-                }
-    except Exception:  # noqa: BLE001 — cost model is best-effort
-        log.debug("cost_analysis unavailable for %s", name, exc_info=True)
-    with _cost_lock:
-        _COSTS[name] = ent
-    return ent
-
-
-def variant_cost(name: str | None) -> dict | None:
-    """The cached cost entry for a variant name; None when the variant was
-    never AOT-compiled or its backend reports no cost model."""
-    if name is None:
-        return None
-    with _cost_lock:
-        return _COSTS.get(name)
-
-
-def clear_variant_costs() -> None:
-    """Test hook: drop the per-process cost cache."""
-    with _cost_lock:
-        _COSTS.clear()
 
 
 # -------------------------------------------------- bucket decomposition
@@ -226,17 +181,16 @@ def ensure_goodput_families() -> None:
 
 # ------------------------------------------------------ per-round record
 def round_goodput(wall_s: float, buckets: dict, *, variant: str | None = None,
-                  cost_rounds: int = 1, n_devices: int = 1,
-                  peak_flops: float | None = None,
+                  n_devices: int = 1, peak_flops: float | None = None,
                   device_kind: str | None = None) -> dict:
     """Build the ``goodput`` block one round record carries and feed the
     metric families.
 
     ``buckets`` is a :func:`decompose` result for this round's wall.
-    ``cost_rounds`` normalizes a scanned block variant's cost analysis
-    (which covers R rounds per dispatch) to per-round figures. ``wall_s``
-    must already be per-round. When the variant's cost is unknown the
-    block carries duty cycles only (relative goodput)."""
+    ``wall_s`` must already be per-round. The work is the round programs'
+    matrix products a round (``perf_instrument.matmul_ops()``); when
+    nothing was counted the block carries duty cycles only (relative
+    goodput)."""
     wall = max(float(wall_s), 1e-12)
     duty = {b: buckets.get(b, 0.0) / wall for b in BUCKETS}
     blk: dict = {
@@ -250,21 +204,21 @@ def round_goodput(wall_s: float, buckets: dict, *, variant: str | None = None,
         _duty_gauge(b).set(duty[b])
     _gp_counter("fed_goodput_rounds_total").inc()
 
-    cost = variant_cost(variant)
-    if cost is not None:
-        rounds = max(int(cost_rounds), 1)
-        if cost.get("flops"):
-            fps = cost["flops"] / rounds / wall
-            blk["flops_per_s"] = fps
-            _gp_gauge("fed_goodput_flops_per_sec").set(fps)
-            peak = (peak_flops if peak_flops is not None
-                    else device_peak_flops(device_kind))
-            if peak:
-                mfu = fps / (peak * max(int(n_devices), 1))
-                blk["mfu"] = round(mfu, 6)
-                _gp_gauge("fed_goodput_mfu").set(mfu)
-        if cost.get("bytes"):
-            bps = cost["bytes"] / rounds / wall
-            blk["bytes_per_s"] = bps
-            _gp_gauge("fed_goodput_bytes_per_sec").set(bps)
+    ops = matmul_ops()
+    useful = sum(flops for _, run, flops, _ in ops if run != "recompute")
+    nbytes = sum(b for *_, b in ops)
+    if useful:
+        fps = useful / wall
+        blk["flops_per_s"] = fps
+        _gp_gauge("fed_goodput_flops_per_sec").set(fps)
+        peak = (peak_flops if peak_flops is not None
+                else device_peak_flops(device_kind))
+        if peak:
+            mfu = fps / (peak * max(int(n_devices), 1))
+            blk["mfu"] = round(mfu, 6)
+            _gp_gauge("fed_goodput_mfu").set(mfu)
+    if nbytes:
+        bps = nbytes / wall
+        blk["bytes_per_s"] = bps
+        _gp_gauge("fed_goodput_bytes_per_sec").set(bps)
     return blk

@@ -348,12 +348,70 @@ def remat_counts() -> dict:
             "kept_bytes": REGISTRY.total("fed_remat_kept_bytes_total")}
 
 
+# --------------------------------------- the round programs' matrix work
+# obs/work.py, docs/OBSERVABILITY.md. Counted where core/program_store.py
+# exports a round program (a store miss: the walk of the trace it exports),
+# per round of that program; a program the store loads counts again what its
+# export counted. The plain jit (no compile cache directory, a mesh) counts
+# nothing. Every dot_general and conv_general_dilated, by the innermost fed_*
+# scope and the pass it belongs to (run=forward|recompute|gradient):
+#
+#     fed_matmul_flops_total{scope,run}  FLOPs a round of the shapes as issued
+#     fed_matmul_bytes_total{scope,run}  their operands' and results' bytes
+#
+# summed over the round programs traced or loaded. matmul_ops() is the read
+# side: the list a roofline needs, each shape at the larger of its bounds.
+_matmul_lock = threading.Lock()
+# {(scope, run, flops, bytes): ops a round}
+_matmul: dict = {}
+
+
+def record_matmul(ops: dict) -> None:
+    """Count ``ops``, ``work.matmul_work``'s {(scope, run, flops, bytes):
+    ops a round} of one round program."""
+    with _matmul_lock:
+        for key, n in ops.items():
+            _matmul[key] = _matmul.get(key, 0.0) + n
+    for (scope, run, flops, nbytes), n in ops.items():
+        REGISTRY.counter("fed_matmul_flops_total", scope=scope,
+                         run=run).inc(flops * n)
+        REGISTRY.counter("fed_matmul_bytes_total", scope=scope,
+                         run=run).inc(nbytes * n)
+
+
+def matmul_op_counts() -> dict:
+    """{(scope, run, flops, bytes): ops a round} counted so far."""
+    with _matmul_lock:
+        return dict(_matmul)
+
+
+def matmul_ops() -> list:
+    """[(scope, run, flops, bytes)] a round of the round programs traced or
+    loaded so far, the products of one shape merged into one entry: a
+    reader takes each entry at the larger of its two bounds, which merging
+    equal shapes leaves as it was."""
+    counted = sorted(matmul_op_counts().items())
+    return [(scope, run, flops * n, nbytes * n)
+            for (scope, run, flops, nbytes), n in counted]
+
+
+def ensure_matmul_families() -> None:
+    """Pre-register every ``run`` at zero (under ``scope="outside"``): a
+    process that counted no product reads as that."""
+    from fedml_tpu.obs import work
+
+    for run in work.RUNS:
+        REGISTRY.counter("fed_matmul_flops_total", scope=work.OUTSIDE, run=run)
+        REGISTRY.counter("fed_matmul_bytes_total", scope=work.OUTSIDE, run=run)
+
+
 def traced_counts() -> dict:
     """What traces have counted so far, for the program store to diff
     around a trace and keep with the program."""
     return {"conv_sites": conv_site_counts(),
             "client_fold": client_fold_counts(),
-            "remat": remat_counts()}
+            "remat": remat_counts(),
+            "matmul": matmul_op_counts()}
 
 
 def traced_since(before: dict) -> dict:
@@ -372,7 +430,11 @@ def traced_since(before: dict) -> dict:
                                                      .items())
                        if n > was["sites"].get((name, kept), 0)],
              "kept_bytes": now["remat"]["kept_bytes"] - was["kept_bytes"]}
-    return {"conv_sites": sites, "client_fold": folds, "remat": remat}
+    matmul = [[*key, n - before["matmul"].get(key, 0.0)]
+              for key, n in sorted(now["matmul"].items())
+              if n > before["matmul"].get(key, 0.0)]
+    return {"conv_sites": sites, "client_fold": folds, "remat": remat,
+            "matmul": matmul}
 
 
 def replay_traced(header: dict) -> None:
@@ -384,6 +446,9 @@ def replay_traced(header: dict) -> None:
     for name, kept, n in remat.get("sites", ()):
         _remat_sites(str(name), str(kept)).inc(n)
     _counter("fed_remat_kept_bytes_total").inc(remat.get("kept_bytes", 0))
+    record_matmul({(str(scope), str(run), flops, nbytes): n
+                   for scope, run, flops, nbytes, n in header.get("matmul",
+                                                                  ())})
 
 
 # ------------------------------------------------------- the expert layer
@@ -511,9 +576,12 @@ def moe_rows() -> dict:
 #     fed_program_store_seconds_total{phase}  key (forming the key), load
 #                                             (reading a record and calling
 #                                             it), export (trace, lower,
-#                                             serialize and write on a miss)
+#                                             serialize and write on a miss),
+#                                             walk (the walk of the exported
+#                                             trace's matrix work, inside
+#                                             export)
 PROGRAM_STORE_OUTCOMES = ("hit", "miss", "unkeyable", "stale", "error")
-PROGRAM_STORE_PHASES = ("key", "load", "export")
+PROGRAM_STORE_PHASES = ("key", "load", "export", "walk")
 
 
 @lru_cache(maxsize=8)

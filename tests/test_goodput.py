@@ -11,8 +11,9 @@ Pins (docs/PERFORMANCE.md §Round economics, docs/OBSERVABILITY.md):
   overlapped on the prefetch thread);
 - a seeded chaos straggle on the loopback wire moves exactly the
   wire_wait bucket — the forensic attribution the run-store diff names;
-- cost-analysis absence is graceful (duty-cycle-only blocks, never a
-  raise); MFU appears only when the device kind resolves a peak;
+- a process with no round program counted is graceful (duty-cycle-only
+  blocks, never a raise); MFU appears only when the device kind resolves
+  a peak, and counts the program's forward and gradient products;
 - instrumentation OFF is bitwise identical: model bits (standalone +
   pipelined) and wire bytes (loopback sim) match a telemetry-on twin;
 - every new family pre-registers at zero (fed_duty_cycle{bucket},
@@ -39,7 +40,7 @@ from fedml_tpu.data.synthetic import synthetic_lr
 from fedml_tpu.models.linear import LogisticRegression
 from fedml_tpu.obs import goodput
 from fedml_tpu.obs import perf_instrument as perf
-from fedml_tpu.obs.metrics import REGISTRY
+from fedml_tpu.obs.metrics import REGISTRY, MetricsRegistry
 from fedml_tpu.obs.provenance import provenance, stamp
 from fedml_tpu.obs.telemetry import Telemetry
 
@@ -112,62 +113,48 @@ def test_buckets_from_spans_sync_vs_pipelined():
     assert goodput.buckets_from_spans(1.0, None)["drain"] == 1.0
 
 
-# ------------------------------------------------------------ cost model
-class _Exe:
-    def __init__(self, ca):
-        self._ca = ca
-
-    def cost_analysis(self):
-        if isinstance(self._ca, Exception):
-            raise self._ca
-        return self._ca
-
-
-def test_cost_analysis_graceful_absence():
-    goodput.clear_variant_costs()
-    try:
-        assert goodput.record_variant_cost(
-            "v_raise", _Exe(RuntimeError("no cost model"))) is None
-        assert goodput.record_variant_cost("v_none", _Exe(None)) is None
-        assert goodput.record_variant_cost("v_empty", _Exe([])) is None
-        ent = goodput.record_variant_cost(
-            "v_list", _Exe([{"flops": 10.0, "bytes accessed": 4.0}]))
-        assert ent == {"flops": 10.0, "bytes": 4.0}
-        ent = goodput.record_variant_cost("v_dict", _Exe({"flops": 6.0}))
-        assert ent == {"flops": 6.0, "bytes": None}
-        assert goodput.variant_cost("v_raise") is None
-        assert goodput.variant_cost("never_compiled") is None
-        assert goodput.variant_cost(None) is None
-        # an unknown-cost variant yields a duty-only block — no raise
-        buckets = goodput.decompose(1.0, compute=0.5)
-        blk = goodput.round_goodput(1.0, buckets, variant="v_raise")
-        assert "flops_per_s" not in blk and "mfu" not in blk
-        assert blk["duty"]["compute"] == pytest.approx(0.5)
-    finally:
-        goodput.clear_variant_costs()
+# ------------------------------------------------------------ work model
+def test_cost_analysis_graceful_absence(monkeypatch):
+    """No round program counted (the plain jit, a mesh, a backend the store
+    does not serve): a duty-only block, never a raise; nor does a program
+    whose only products are made again count as useful work."""
+    monkeypatch.setattr(perf, "_matmul", {})
+    monkeypatch.setattr(perf, "REGISTRY", MetricsRegistry())
+    buckets = goodput.decompose(1.0, compute=0.5)
+    blk = goodput.round_goodput(1.0, buckets, variant="v")
+    assert "flops_per_s" not in blk and "mfu" not in blk
+    assert "bytes_per_s" not in blk
+    assert blk["duty"]["compute"] == pytest.approx(0.5)
+    assert blk["variant"] == "v"
+    perf.record_matmul({("fed_x", "recompute", 5e8, 4.0): 1.0})
+    blk = goodput.round_goodput(1.0, buckets, peak_flops=1e9)
+    assert "flops_per_s" not in blk and "mfu" not in blk
+    assert blk["bytes_per_s"] == pytest.approx(4.0)
 
 
-def test_round_goodput_flops_mfu_and_block_normalization():
-    goodput.clear_variant_costs()
-    try:
-        goodput.record_variant_cost(
-            "blk", _Exe({"flops": 4e9, "bytes accessed": 2e9}))
-        buckets = goodput.decompose(0.5, compute=0.5)
-        # a scanned 4-round block's cost covers 4 rounds -> normalize
-        blk = goodput.round_goodput(0.5, buckets, variant="blk",
-                                    cost_rounds=4, n_devices=2,
-                                    peak_flops=1e9)
-        assert blk["flops_per_s"] == pytest.approx(2e9)
-        assert blk["bytes_per_s"] == pytest.approx(1e9)
-        assert blk["mfu"] == pytest.approx(1.0)
-        assert sum(blk["duty"].values()) == pytest.approx(1.0, abs=1e-3)
-        # unknown device kind -> relative-only (no mfu key)
-        blk2 = goodput.round_goodput(0.5, buckets, variant="blk",
-                                     cost_rounds=4,
-                                     device_kind="who knows")
-        assert "flops_per_s" in blk2 and "mfu" not in blk2
-    finally:
-        goodput.clear_variant_costs()
+def test_round_goodput_flops_mfu_and_block_normalization(monkeypatch):
+    """The useful FLOPs are the round programs' forward and gradient
+    products a round, as ``perf_instrument.matmul_ops()`` gives them: a
+    scanned block's count is already a round's, and what a
+    rematerialised region makes again is left out."""
+    monkeypatch.setattr(perf, "_matmul", {})
+    monkeypatch.setattr(perf, "REGISTRY", MetricsRegistry())
+    perf.record_matmul({
+        ("fed_a", "forward", 2e8, 1e8): 1.0,
+        ("fed_a", "gradient", 2e8, 1e8): 2.0,
+        ("fed_a", "recompute", 2e8, 1e8): 1.0,
+        ("outside", "forward", 1e8, 5e7): 4.0})
+    buckets = goodput.decompose(0.5, compute=0.5)
+    blk = goodput.round_goodput(0.5, buckets, variant="blk", n_devices=2,
+                                peak_flops=1e9)
+    assert blk["flops_per_s"] == pytest.approx(2e9)  # 1e9 useful / 0.5 s
+    assert blk["bytes_per_s"] == pytest.approx(1.2e9)  # every run's bytes
+    assert blk["mfu"] == pytest.approx(1.0)
+    assert sum(blk["duty"].values()) == pytest.approx(1.0, abs=1e-3)
+    # unknown device kind -> relative-only (no mfu key)
+    blk2 = goodput.round_goodput(0.5, buckets, variant="blk",
+                                 device_kind="who knows")
+    assert "flops_per_s" in blk2 and "mfu" not in blk2
 
 
 def test_device_peak_table_substring_match():

@@ -26,6 +26,7 @@ from fedml_tpu.data.synthetic import synthetic_images
 from fedml_tpu.models import create_model
 from fedml_tpu.models.resnet import ResNetCIFAR
 from fedml_tpu.obs import perf_instrument as perf
+from fedml_tpu.obs.metrics import MetricsRegistry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -563,6 +564,68 @@ def test_hit_replays_the_convolution_sites_the_miss_counted(
             if v != s1.get(k, 0)} == miss
     total = perf.conv_sites()
     assert total["packed"] + total["plain"] == sum(s2.values())
+
+
+@pytest.fixture
+def fresh_counts(monkeypatch):
+    """Gives the process a registry and a matrix-work count of their own,
+    as a new process starts with them, each time it is called."""
+    def fresh():
+        monkeypatch.setattr(perf, "REGISTRY", MetricsRegistry())
+        monkeypatch.setattr(perf, "_matmul", {})
+
+    return fresh
+
+
+def _matmul_families():
+    snap = perf.REGISTRY.snapshot()
+    return {f: snap.get(f) for f in ("fed_matmul_flops_total",
+                                     "fed_matmul_bytes_total")}
+
+
+def test_hit_counts_the_matrix_work_the_miss_walked(
+        images, lr_task, cache_dir, fresh_counts, monkeypatch):
+    """A miss traces the block program once (the body the export runs,
+    counted inside it) and walks that trace for its products a round; a
+    hit, in a registry of its own, walks nothing and counts the same; a
+    block of another length is another program with the same work a
+    round."""
+    from fedml_tpu.obs import work
+
+    bodies = []
+    export = ps._export
+
+    def counting(fun, *rest):
+        def body(*args):
+            bodies.append(fun.__name__)
+            return fun(*args)
+
+        body.__name__ = fun.__name__
+        return export(body, *rest)
+
+    monkeypatch.setattr(ps, "_export", counting)
+    fresh_counts()
+    c0 = perf.program_store_counts()
+    jax.block_until_ready(_api(images, lr_task).run_rounds(0, 2))
+    assert _delta(c0) == {"miss": 1.0} and bodies == ["block_fn"]
+    miss, families = perf.matmul_ops(), _matmul_families()
+    runs = {run for _, run, _, _ in miss}
+    assert miss and runs == {"forward", "gradient"}
+    fresh_counts()
+
+    def no_walk(*a, **k):
+        raise AssertionError("a hit walks nothing")
+
+    monkeypatch.setattr(work, "matmul_work", no_walk)
+    jax.block_until_ready(_api(images, lr_task).run_rounds(0, 2))
+    assert _delta(c0) == {"miss": 1.0, "hit": 1.0}
+    assert perf.matmul_ops() == miss and _matmul_families() == families
+    monkeypatch.undo()
+    monkeypatch.setattr(ps, "_export", counting)
+    fresh_counts()
+    jax.block_until_ready(_api(images, lr_task).run_rounds(0, 3))
+    assert _delta(c0) == {"miss": 2.0, "hit": 1.0} and len(bodies) == 2
+    assert perf.matmul_ops() == miss
 
 
 # ------------------------------------------------ where it stays out
