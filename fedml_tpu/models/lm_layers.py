@@ -21,17 +21,22 @@ Work follows the assignments held: they are sorted by expert into tiles of
 ``moe_tile_rows`` rows, each tile of one expert, inside a static budget of
 ``moe_row_budget`` rows a token (the expectation is ``k * held /
 num_experts``; the worst case ``k``), and a loop over the tiles multiplies
-each by its own expert's matrices. A step whose tiles do not fit the budget
-takes, behind a ``lax.cond``, the exact path of full size: every held
-expert for every token, weighed by zero where the token chose another.
-(Under ``vmap`` a ``cond`` is a ``select`` and both paths run: fold the
-clients, ``FedAvgConfig.client_fold="scan"``.) XLA ops only; the tile loop,
-the dispatch and the combine carry their own backward passes so that all
-four row movements are gathers and the experts' gradients accumulate in
-place. The layer returns, beside its output, the held experts' token
-counts, the rows that held an assignment, the rows computed and whether
-the step took the full path: a model sows them into ``moe_stats`` once a
-call.
+each by its own expert's matrices. The groups fill the budget from the
+front, so the loop runs the tiles that hold rows and gives the tiles past
+the last group zeros behind a ``lax.cond``, with no product (a zero row's
+outputs and gradients are exact zeros); it stays a ``scan`` of the
+budget's static length, and ``obs/work.py`` counts it at the budget. A
+step whose tiles do not fit the budget takes, behind a ``lax.cond``, the
+exact path of full size: every held expert for every token, weighed by
+zero where the token chose another. (Under ``vmap`` a ``cond`` is a
+``select`` and both paths run: fold the clients,
+``FedAvgConfig.client_fold="scan"``.) XLA ops only; the tile loop, the
+dispatch and the combine carry their own backward passes so that all four
+row movements are gathers and the experts' gradients accumulate in place.
+The layer returns, beside its output, the held experts' token counts, the
+rows that held an assignment, the rows computed (the used tiles' rows, or
+held x tokens on the full path) and whether the step took the full path:
+a model sows them into ``moe_stats`` once a call.
 
 **What a rematerialised block keeps** (``kept_names``). Each model lists,
 for a layer, the named outputs of its products that the block's backward
@@ -284,34 +289,44 @@ def _add_at(acc, e, g):
     return lax.dynamic_update_index_in_dim(acc, _pick(acc, e) + g, e, 0)
 
 
+def _zeros_of(fn, *args):
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                        jax.eval_shape(fn, *args))
+
+
 @jax.custom_vjp
-def tiled_experts(x, w1, w3, w2, tile_expert):
-    """y [rows, D]: each tile of ``rows / len(tile_expert)`` rows through
-    the gated MLP of its own expert."""
-    return _tiled_fwd(x, w1, w3, w2, tile_expert)[0]
+def tiled_experts(x, w1, w3, w2, tile_expert, n_used):
+    """y [rows, D]: each of the first ``n_used`` tiles of ``rows /
+    len(tile_expert)`` rows through the gated MLP of its own expert; the
+    tiles past them hold padding alone and give zeros, with no product."""
+    return _tiled_fwd(x, w1, w3, w2, tile_expert, n_used)[0]
 
 
-def _tiled_fwd(x, w1, w3, w2, tile_expert):
+def _tiled_fwd(x, w1, w3, w2, tile_expert, n_used):
     tiles = x.reshape(tile_expert.shape[0], -1, x.shape[-1])
 
-    def one(_, inp):
-        xt, e = inp
+    def products(xt, e):
         h1, h3 = xt @ _pick(w1, e), xt @ _pick(w3, e)
-        return None, ((jax.nn.silu(h1) * h3) @ _pick(w2, e), h1, h3)
+        return (jax.nn.silu(h1) * h3) @ _pick(w2, e), h1, h3
 
-    _, (y, h1, h3) = lax.scan(one, None, (tiles, tile_expert))
+    def one(_, inp):
+        i, xt, e = inp
+        return None, lax.cond(i < n_used, products,
+                              functools.partial(_zeros_of, products), xt, e)
+
+    index = jnp.arange(tile_expert.shape[0], dtype=jnp.int32)
+    _, (y, h1, h3) = lax.scan(one, None, (index, tiles, tile_expert))
     tiles = checkpoint_name(tiles, "moe_tiles")
     h1, h3 = checkpoint_name(h1, "moe_h1"), checkpoint_name(h3, "moe_h3")
     y = checkpoint_name(y.reshape(x.shape), "moe_y")
-    return y, (tiles, h1, h3, w1, w3, w2, tile_expert)
+    return y, (tiles, h1, h3, w1, w3, w2, tile_expert, n_used)
 
 
 def _tiled_bwd(res, dy):
-    tiles, h1, h3, w1, w3, w2, tile_expert = res
+    tiles, h1, h3, w1, w3, w2, tile_expert, n_used = res
 
-    def one(acc, inp):
+    def products(acc, xt, h1t, h3t, dyt, e):
         dw1, dw3, dw2 = acc
-        xt, h1t, h3t, dyt, e = inp
         sig = jax.nn.sigmoid(h1t)
         act = h1t * sig
         dw2 = _add_at(dw2, e, (act * h3t).T @ dyt)
@@ -323,18 +338,30 @@ def _tiled_bwd(res, dy):
         dx = dh1 @ _pick(w1, e).T + dh3 @ _pick(w3, e).T
         return (dw1, dw3, dw2), dx
 
+    # the accumulators go through the cond, updated in place by one branch
+    # and passed through by the other: the compiled loop copies neither
+    def nothing(acc, *inp):
+        return acc, _zeros_of(products, acc, *inp)[1]
+
+    def one(acc, inp):
+        i, *inp = inp
+        return lax.cond(i < n_used, products, nothing, acc, *inp)
+
     zeros = tuple(jnp.zeros_like(w) for w in (w1, w3, w2))
+    index = jnp.arange(tile_expert.shape[0], dtype=jnp.int32)
     (dw1, dw3, dw2), dx = lax.scan(
-        one, zeros, (tiles, h1, h3, dy.reshape(tiles.shape), tile_expert))
-    return dx.reshape(dy.shape), dw1, dw3, dw2, None
+        one, zeros,
+        (index, tiles, h1, h3, dy.reshape(tiles.shape), tile_expert))
+    return dx.reshape(dy.shape), dw1, dw3, dw2, None, None
 
 
 tiled_experts.defvjp(_tiled_fwd, _tiled_bwd)
 
 
 def _budgeted(m, chosen, w, w1, w3, w2, plan):
-    dest, row_src, tile_expert = plan
-    y = tiled_experts(dispatch(m, dest, row_src), w1, w3, w2, tile_expert)
+    dest, row_src, tile_expert, n_used = plan
+    y = tiled_experts(dispatch(m, dest, row_src), w1, w3, w2, tile_expert,
+                      n_used)
     return combine(y, w, dest, row_src)
 
 
@@ -389,10 +416,11 @@ def expert_layer(m, p, sz, scoring: str = "sigmoid"):
         fits = padded <= rows
         f = lax.cond(fits, _budgeted, functools.partial(_full_size, lo),
                      m, chosen, w, p["experts_w1"], p["experts_w3"],
-                     p["experts_w2"], (dest, row_src, tile_expert))
+                     p["experts_w2"],
+                     (dest, row_src, tile_expert, padded // tile))
     counts = counts.astype(jnp.float32)
     stats = {"expert_tokens": counts, "rows_real": jnp.sum(counts),
-             "rows_dispatched": jnp.where(fits, float(rows),
+             "rows_dispatched": jnp.where(fits, padded.astype(jnp.float32),
                                           float((hi - lo) * n)),
              "fallback_steps": 1.0 - fits.astype(jnp.float32)}
     return f, lax.stop_gradient(stats)

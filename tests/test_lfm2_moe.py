@@ -195,6 +195,152 @@ def test_stats_are_sown_once_a_call():
         == (3, T, SMALL["vocab_size"])
 
 
+# ------------------------------------- the tiles past the last expert group
+@jax.custom_vjp
+def _every_tile(x, w1, w3, w2, tile_expert, n_used):
+    """The tile loop as it was before it left the tiles past the last
+    group out: every tile of the budget through its expert's products."""
+    return _every_tile_fwd(x, w1, w3, w2, tile_expert, n_used)[0]
+
+
+def _every_tile_fwd(x, w1, w3, w2, tile_expert, n_used):
+    tiles = x.reshape(tile_expert.shape[0], -1, x.shape[-1])
+    pick = lm_layers._pick
+
+    def one(_, inp):
+        xt, e = inp
+        h1, h3 = xt @ pick(w1, e), xt @ pick(w3, e)
+        return None, ((jax.nn.silu(h1) * h3) @ pick(w2, e), h1, h3)
+
+    _, (y, h1, h3) = jax.lax.scan(one, None, (tiles, tile_expert))
+    return y.reshape(x.shape), (tiles, h1, h3, w1, w3, w2, tile_expert)
+
+
+def _every_tile_bwd(res, dy):
+    tiles, h1, h3, w1, w3, w2, tile_expert = res
+    pick, add_at = lm_layers._pick, lm_layers._add_at
+
+    def one(acc, inp):
+        dw1, dw3, dw2 = acc
+        xt, h1t, h3t, dyt, e = inp
+        sig = jax.nn.sigmoid(h1t)
+        act = h1t * sig
+        dw2 = add_at(dw2, e, (act * h3t).T @ dyt)
+        dg = dyt @ pick(w2, e).T
+        dh1 = dg * h3t * (sig * (1 + h1t * (1 - sig)))
+        dh3 = dg * act
+        dw1 = add_at(dw1, e, xt.T @ dh1)
+        dw3 = add_at(dw3, e, xt.T @ dh3)
+        dx = dh1 @ pick(w1, e).T + dh3 @ pick(w3, e).T
+        return (dw1, dw3, dw2), dx
+
+    zeros = tuple(jnp.zeros_like(w) for w in (w1, w3, w2))
+    (dw1, dw3, dw2), dx = jax.lax.scan(
+        one, zeros, (tiles, h1, h3, dy.reshape(tiles.shape), tile_expert))
+    return dx.reshape(dy.shape), dw1, dw3, dw2, None, None
+
+
+_every_tile.defvjp(_every_tile_fwd, _every_tile_bwd)
+
+
+def _budgeted_every_tile(m, chosen, w, w1, w3, w2, plan):
+    """``expert_layer``'s budgeted branch over ``_every_tile``: a function
+    of its own, since ``lax.cond`` keeps a branch's trace by the branch."""
+    dest, row_src, tile_expert, n_used = plan
+    y = _every_tile(lm_layers.dispatch(m, dest, row_src), w1, w3, w2,
+                    tile_expert, n_used)
+    return lm_layers.combine(y, w, dest, row_src)
+
+HELD = (0, 4)
+
+
+def _routed(routing, seed=5):
+    """The expert layer's sizes, held weights and hidden states under a
+    budget that leaves tiles past the last group (``tail_empty``) or one
+    whose tiles the groups fill to the last (``every_tile_used``); the
+    rows the groups take, and the budget's."""
+    sz, _, cut, m = _layer(SMALL, HELD, 4.0, seed)
+    chosen, _ = lm_layers.route(m, cut["router"], cut["expert_bias"],
+                                sz.num_experts_per_tok,
+                                sz.routed_scaling_factor)
+    padded = int(lm_layers.plan_rows(chosen, *HELD, 4096,
+                                     sz.moe_tile_rows)[-1])
+    if routing == "every_tile_used":
+        sz = _layer(SMALL, HELD, (padded - 0.5) / m.shape[0], seed)[0]
+    return sz, cut, m, padded, lm_layers.budget_rows(sz, m.shape[0])
+
+
+def _layer_and_grads(sz, fold):
+    def fn(m, cut):
+        f, stats = lm_layers.expert_layer(m, cut, sz)
+        return jnp.sum(f * jnp.cos(f)), (f, stats)
+
+    run = jax.value_and_grad(fn, argnums=(0, 1), has_aux=True)
+    return jax.jit(jax.vmap(run, in_axes=(0, None)) if fold == "vmap"
+                   else run)
+
+
+@pytest.mark.parametrize("fold", ["jit", "vmap"])
+@pytest.mark.parametrize("routing", ["tail_empty", "every_tile_used"])
+def test_tiles_past_the_groups_change_no_bit(routing, fold, monkeypatch):
+    """The loop runs the tiles that hold rows and gives the rest zeros:
+    the layer's output and every gradient are the loop over every tile's,
+    bit for bit, under ``jit`` and under ``vmap`` over two clients (where
+    the ``cond`` is a ``select`` and both branches run)."""
+    sz, cut, m, padded, rows = _routed(routing)
+    assert (padded < rows) == (routing == "tail_empty")
+    if fold == "vmap":
+        m = jnp.stack([m, _routed(routing, seed=6)[2]])
+    (_, (f, stats)), grads = _layer_and_grads(sz, fold)(m, cut)
+    monkeypatch.setattr(lm_layers, "_budgeted", _budgeted_every_tile)
+    (_, (f_all, stats_all)), grads_all = _layer_and_grads(sz, fold)(m, cut)
+    assert float(jnp.max(stats["fallback_steps"])) == 0.0
+    assert np.array_equal(f, f_all)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_all)):
+        assert np.array_equal(got, want)
+    assert np.array_equal(stats["rows_real"], stats_all["rows_real"])
+
+
+@pytest.mark.parametrize("routing", ["tail_empty", "every_tile_used"])
+def test_rows_dispatched_are_the_rows_the_groups_take(routing):
+    """On the budgeted path the counter reads the rows the loop computes,
+    ``padded_rows`` of the plan: under the budget where tiles are left
+    empty, the budget where the groups fill it."""
+    sz, cut, m, padded, rows = _routed(routing)
+    stats = jax.jit(lambda m: lm_layers.expert_layer(m, cut, sz)[1])(m)
+    assert float(stats["fallback_steps"]) == 0.0
+    assert float(stats["rows_dispatched"]) == padded
+    assert (padded < rows) == (routing == "tail_empty")
+
+
+def test_tile_loop_updates_the_expert_gradients_in_place():
+    """The backward loop's ``cond`` updates the experts' gradient
+    accumulators in one branch and passes them through in the other: the
+    compiled loop makes no copy of an ``[E, D, F]`` buffer inside any
+    computation but the entry (CPU; the chip's compiler, read for a
+    described v5e at the cells' shapes, makes none either)."""
+    e, d, f, tile, n = 4, 40, 24, 8, 6
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    w1, w3 = (jax.random.normal(k, (e, d, f)) for k in ks[:2])
+    w2, x = jax.random.normal(ks[2], (e, f, d)), jax.random.normal(
+        ks[3], (n * tile, d))
+    tile_expert = jnp.minimum(jnp.arange(n), e - 1).astype(jnp.int32)
+    grad = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(
+        lm_layers.tiled_experts(*a, tile_expert, jnp.int32(4)))),
+        (0, 1, 2, 3)))
+    text = grad.lower(x, w1, w3, w2).compile().as_text()
+    assert " conditional(" in text
+    computation, copies = None, []
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            computation = line.split()[0]
+        elif (" copy(" in line and computation != "ENTRY"
+              and any(f"f32[{s}]" in line.split("copy(")[0]
+                      for s in (f"{e},{d},{f}", f"{e},{f},{d}"))):
+            copies.append(line.strip())
+    assert not copies
+
+
 # ---------------------------------------------- what a block keeps of its pass
 # one short convolution with the dense layer, one attention layer with
 # experts, on the budgeted path

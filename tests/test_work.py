@@ -181,3 +181,24 @@ def test_kept_outputs_take_their_operations_off_the_recompute(kind):
     entries = qwen3_next.block_outputs(model.sizes(), kind, 2, seq_len, 4)
     assert a["recompute"] - b["recompute"] == sum(o for _, _, o in entries)
     assert (a["forward"], a["gradient"]) == (b["forward"], b["gradient"])
+
+
+def test_tile_products_count_at_the_budget():
+    """The tile loop runs the tiles that hold rows behind a ``cond`` whose
+    last branch is the products: the walk counts the tiles' three forward
+    and six gradient products over every tile of the row budget, as it did
+    when the loop ran every tile, and makes none of them again."""
+    from fedml_tpu.models import lm_layers
+
+    grad, model, sizes = _gradient("lfm2_moe")
+    sz = model.sizes()
+    experts = len(sizes["layer_types"]) - sizes["num_dense_layers"]
+    rows = lm_layers.budget_rows(sz, 2 * _lm("lfm2_moe")[-1])
+    assert rows > sz.moe_tile_rows
+    one = 2 * rows * sz.hidden_size * sz.moe_intermediate_size
+    runs = dict.fromkeys(work.RUNS, 0.0)
+    for (scope, run, flops, _), n in work.matmul_work(grad).items():
+        if scope == "fed_moe_experts":
+            runs[run] += flops * n
+    assert runs == {"forward": experts * 3 * one, "recompute": 0.0,
+                    "gradient": experts * 6 * one}
